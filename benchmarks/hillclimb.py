@@ -16,9 +16,6 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-# device count must be set before jax loads (this module is run directly)
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
-
 EXPERIMENTS = [
     # (arch, shape, variant-name, strategy, overrides, hypothesis)
     ("yi-34b", "train_4k", "baseline-tp", "tp", {},
@@ -53,7 +50,9 @@ EXPERIMENTS = [
 
 
 def main():
-    from repro.launch.dryrun import run_cell
+    from repro.launch.dryrun import HOST_DEVICES_FLAG, run_cell
+    # the device count must be set before JAX creates its first backend
+    os.environ.setdefault("XLA_FLAGS", HOST_DEVICES_FLAG)
     from benchmarks.roofline import terms
 
     out = []

@@ -23,14 +23,18 @@ import json
 import os
 from typing import Any, Dict
 
-PEAK_FLOPS = 197e12      # bf16 / chip (TPU v5e)
-HBM_BW = 819e9           # bytes/s / chip
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud TPU v5e documentation (bf16 FLOP/s, HBM bytes/s).
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"peak_flops": 197e12, "mem_bw": 819e9},
+}
+PEAK_FLOPS = DEVICE_PEAKS["TPU v5 lite"]["peak_flops"]
+HBM_BW = DEVICE_PEAKS["TPU v5 lite"]["mem_bw"]
 LINK_BW = 50e9           # bytes/s / link (ICI)
 
-# host-CPU roofline for the live-telemetry path (repro.core.streams
-# Dispatcher.telemetry()): a conservative per-core AVX2 FMA peak and
-# one DDR channel's worth of bandwidth, scaled by visible cores —
-# override per machine via from_telemetry(peak_flops=..., mem_bw=...)
+# host-CPU roofline, used only when the platform is the CPU: a
+# conservative per-core AVX2 FMA peak and one DDR channel's worth of
+# bandwidth, scaled by visible cores
 CPU_CORE_FLOPS = 32e9    # FLOP/s/core (8-lane f32 FMA @ ~2 GHz)
 CPU_CORE_BW = 12e9       # bytes/s/core (shared-bus share)
 
@@ -44,17 +48,34 @@ def cpu_peaks() -> Dict[str, float]:
             "mem_bw": CPU_CORE_BW * cores}
 
 
+def device_peaks(device=None) -> Dict[str, float]:
+    """Peaks of ``device`` (default: JAX's first device): the CPU table
+    on the CPU platform, else the published entry for its device kind.
+    A device kind missing from :data:`DEVICE_PEAKS` is an error, never
+    a default."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return cpu_peaks()
+    try:
+        return DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device.device_kind!r}") from None
+
+
 def from_telemetry(rows, peak_flops: float = None,
                    mem_bw: float = None) -> list:
     """Roofline rows from the dispatcher's *live* counters
     (``cox.get_dispatcher().telemetry()``) instead of dry-run JSON:
-    each stage-key row's op/mem estimates against the host peaks give
-    t_compute/t_memory, the dominant term, and — where the row carries
-    measured wall time — the achieved fraction of the dominant roof.
-    The returned dicts keep the telemetry fields (kernel, backend,
-    warp_exec, chunk, launches, gflops) so the bench JSON can embed
-    them verbatim."""
-    peaks = cpu_peaks()
+    each stage-key row's op/mem estimates against the peaks of the
+    device JAX runs on (:func:`device_peaks`) give t_compute/t_memory,
+    the dominant term, and — where the row carries measured wall time —
+    the achieved fraction of the dominant roof.  The returned dicts
+    keep the telemetry fields (kernel, backend, warp_exec, chunk,
+    launches, gflops) so the bench JSON can embed them verbatim."""
+    peaks = device_peaks()
     pf = peak_flops if peak_flops is not None else peaks["peak_flops"]
     bw = mem_bw if mem_bw is not None else peaks["mem_bw"]
     out = []

@@ -56,6 +56,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from repro.core import cox  # noqa: E402
 from repro.core.flat import FlatUnsupported, supports_flat  # noqa: E402
 from repro.core.types import CoxUnsupported  # noqa: E402
+from repro.launch import compile_cache  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 # timing knobs (--smoke turns both down) and the JSON collectors
 WARMUP = 2
@@ -67,6 +69,7 @@ STREAM_RESULTS = []  # structured streams-overlap cells
 GRAPH_RESULTS = []   # structured graph-replay cells
 PLACEMENT_RESULTS = []  # structured multi-device placement cells
 AUTOTUNE_RESULTS = []   # structured heuristic-vs-tuned cells
+FAILED = []             # sections whose child process failed
 GRID_STRIDE_RESULTS = []  # structured oversubscribed-schedule cells
 
 # device-pool sizes every placement run must cover — module-level so the
@@ -282,7 +285,7 @@ def backend_sweep():
     backends = ["scan", "vmap"]
     mesh = None
     if not SMOKE and len(jax.devices()) > 1:
-        mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+        mesh = make_mesh((len(jax.devices()),), ("data",))
         backends.append("sharded")
 
     picks = SWEEP_SMOKE_PICKS if SMOKE else SWEEP_FULL_PICKS
@@ -543,25 +546,29 @@ def graph_replay():
 # ---------------------------------------------------------------------------
 
 
-def placement():
-    """Multi-device stream placement: the same 4-stream program over
-    1/2/4/8-device pools (8-dev subprocess — the device count must be
-    set before jax initializes).  The worker asserts bitwise equality
-    against the 1-device pool and reports the throughput ratio; each
-    entry records the host's core count because XLA host devices
-    time-share physical cores (the scaling gate is cpus-conditional)."""
-    worker = os.path.join(os.path.dirname(__file__), "placement_worker.py")
+def _host_device_worker(section: str, script: str, *args: str) -> str:
+    """Run ``benchmarks/<script>`` in a child interpreter with 8 forced
+    CPU host devices (the device count must be set before jax
+    initializes) and return its stdout, re-emitting its CSV rows.
+
+    Only on the CPU platform: a child started after this process has
+    touched an accelerator cannot get it.  A child that fails is
+    recorded as a ``<section>.FAILED`` row and makes :func:`main` exit
+    non-zero."""
+    import jax
+    platform = jax.devices()[0].platform
+    if platform != "cpu":
+        raise SystemExit(
+            f"{section}: runs a child process on forced CPU host devices, "
+            f"which cannot share the {platform} device this process holds; "
+            f"run this section with JAX_PLATFORMS=cpu")
+    worker = os.path.join(os.path.dirname(__file__), script)
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    iters = 3 if SMOKE else max(ITERS, 5)
-    r = subprocess.run([sys.executable, worker, "--iters", str(iters)],
-                       capture_output=True, text=True, env=env, timeout=1200)
+    r = subprocess.run([sys.executable, worker, *args], capture_output=True,
+                       text=True, env=env, timeout=1200)
     for line in r.stdout.splitlines():
-        if line.startswith("PLACEMENT_JSON "):
-            PLACEMENT_RESULTS.extend(
-                json.loads(line[len("PLACEMENT_JSON "):]))
-            continue
         # re-emit through _row so the worker's rows reach --json too
         parts = line.split(",", 2)
         if len(parts) == 3:
@@ -570,9 +577,28 @@ def placement():
                 continue
             except ValueError:
                 pass
-        print(line, flush=True)
+        if not line.startswith("PLACEMENT_JSON "):
+            print(line, flush=True)
     if r.returncode != 0:
-        _row("placement.FAILED", 0.0, r.stderr[-200:].replace("\n", ";"))
+        _row(f"{section}.FAILED", 0.0, r.stderr[-200:].replace("\n", ";"))
+        FAILED.append(section)
+    return r.stdout
+
+
+def placement():
+    """Multi-device stream placement: the same 4-stream program over
+    1/2/4/8-device pools (8-dev subprocess).  The worker asserts
+    bitwise equality against the 1-device pool and reports the
+    throughput ratio; each entry records the host's core count because
+    XLA host devices time-share physical cores (the scaling gate is
+    cpus-conditional)."""
+    iters = 3 if SMOKE else max(ITERS, 5)
+    out = _host_device_worker("placement", "placement_worker.py",
+                              "--iters", str(iters))
+    for line in out.splitlines():
+        if line.startswith("PLACEMENT_JSON "):
+            PLACEMENT_RESULTS.extend(
+                json.loads(line[len("PLACEMENT_JSON "):]))
 
 
 # ---------------------------------------------------------------------------
@@ -788,26 +814,9 @@ def grid_stride():
 
 
 def scalability():
-    """Fig. 14: multi-block kernels across host devices (8-dev subprocess
-    — device count must be set before jax initializes)."""
-    worker = os.path.join(os.path.dirname(__file__), "scalability_worker.py")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    r = subprocess.run([sys.executable, worker], capture_output=True,
-                       text=True, env=env, timeout=1200)
-    for line in r.stdout.splitlines():
-        # re-emit through _row so the worker's rows reach --json too
-        parts = line.split(",", 2)
-        if len(parts) == 3:
-            try:
-                _row(parts[0], float(parts[1]), parts[2])
-                continue
-            except ValueError:
-                pass
-        print(line, flush=True)
-    if r.returncode != 0:
-        _row("scalability.FAILED", 0.0, r.stderr[-200:].replace("\n", ";"))
+    """Fig. 14: multi-block kernels across host devices (8-dev
+    subprocess)."""
+    _host_device_worker("scalability", "scalability_worker.py")
 
 
 # ---------------------------------------------------------------------------
@@ -855,6 +864,7 @@ def main(argv=None) -> None:
                    help="CI smoke: 1 timing iteration, flagship kernels "
                         "only (pair with --sections backend_sweep)")
     args = p.parse_args(argv)
+    compile_cache.enable()
     if args.smoke:
         WARMUP, ITERS, SMOKE = 1, 1, True
     names = (list(SECTIONS) if args.sections is None
@@ -895,6 +905,8 @@ def main(argv=None) -> None:
             f.write("\n")
         print(f"wrote {args.json} ({len(RESULTS)} rows, "
               f"{len(SWEEP_RESULTS)} sweep entries)", flush=True)
+    if FAILED:
+        raise SystemExit(f"child process failed in: {', '.join(FAILED)}")
 
 
 if __name__ == "__main__":

@@ -10,6 +10,7 @@ import jax
 import numpy as np
 
 from repro.core import cox
+from repro.launch.mesh import make_mesh
 
 RNG = np.random.default_rng(3)
 
@@ -35,7 +36,7 @@ def main():
     for d in (1, 2, 4, 8):
         if d > ndev:
             break
-        mesh = jax.make_mesh((d,), ("data",))
+        mesh = make_mesh((d,), ("data",))
 
         def run():
             return saxpy_heavy.launch(grid=64, block=256,

@@ -23,7 +23,7 @@ Contract with the resolver (``runtime.ResolvedLaunch``):
 
 Cache keying and robustness: entries are keyed like the launch cache
 (compile token + geometry + knob tunability + arg-shape signature)
-plus a CPU fingerprint, the file is version-stamped
+plus a host and device-kind fingerprint, the file is version-stamped
 (``AUTOTUNE_VERSION`` — stale stamps invalidate wholesale), writes are
 atomic (temp file + ``os.replace``, with a read-merge so concurrent
 writers union instead of clobber), and a corrupt/truncated file is
@@ -89,16 +89,17 @@ def cache_path() -> Optional[str]:
 
 
 def cpu_fingerprint() -> str:
-    """Keys winners to the host class: knobs tuned on one machine shape
-    transfer within a homogeneous fleet and re-measure elsewhere."""
-    try:
-        import jax
-        backend = jax.default_backend()
-        ndev = jax.local_device_count()
-    except Exception:           # pragma: no cover - jax always importable
-        backend, ndev = "cpu", 1
-    return "%s-%s-%dcpu-%s-x%d" % (platform.machine(), platform.system(),
-                                   os.cpu_count() or 1, backend, ndev)
+    """Keys winners to the host and device class: knobs tuned on one
+    machine shape transfer within a homogeneous fleet and re-measure
+    elsewhere.  The device kind (``TPU v5 lite``, ``cpu``...) is part
+    of the key, so a winner measured on one chip is never served to
+    another."""
+    import jax
+    dev = jax.devices()[0]
+    return "%s-%s-%dcpu-%s-%s-x%d" % (
+        platform.machine(), platform.system(), os.cpu_count() or 1,
+        dev.platform, dev.device_kind.replace(" ", "_"),
+        jax.local_device_count())
 
 
 def stats() -> Dict[str, int]:

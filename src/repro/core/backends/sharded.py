@@ -17,7 +17,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..execute import make_block_fn
 from . import merge
 from .block_vmap import run_chunked, run_phase_wave, run_strided
@@ -53,9 +52,9 @@ def build_fn(plan: LaunchPlan, mesh=None, axis: str = "data"):
                                        scalars, fold_deltas=False)
         return merge.cross_device_merge(g0, g, masks, deltas, axis)
 
-    fn = shard_map(device_fn, mesh=mesh,
-                   in_specs=(P(axis), P(), P()), out_specs=P(),
-                   check_vma=False)
+    fn = jax.shard_map(device_fn, mesh=mesh,
+                       in_specs=(P(axis), P(), P()), out_specs=P(),
+                       check_vma=False)
 
     def run(globals_, scalars):
         return fn(bid_table, globals_, scalars)
@@ -82,9 +81,9 @@ def _build_strided_fn(plan: LaunchPlan, mesh, axis: str, block_fn):
                                        total=per)
         return merge.cross_device_merge(g0, g, masks, deltas, axis)
 
-    fn = shard_map(device_fn, mesh=mesh,
-                   in_specs=(P(), P()), out_specs=P(),
-                   check_vma=False)
+    fn = jax.shard_map(device_fn, mesh=mesh,
+                       in_specs=(P(), P()), out_specs=P(),
+                       check_vma=False)
 
     def run(globals_, scalars):
         return fn(globals_, scalars)
@@ -130,9 +129,9 @@ def _build_phased_fn(plan: LaunchPlan, mesh, axis: str):
             g = merge.cross_device_merge(g, g2, wrote, dsum, axis)
         return g
 
-    fn = shard_map(device_fn, mesh=mesh,
-                   in_specs=(P(axis), P(), P()), out_specs=P(),
-                   check_vma=False)
+    fn = jax.shard_map(device_fn, mesh=mesh,
+                       in_specs=(P(axis), P(), P()), out_specs=P(),
+                       check_vma=False)
 
     def run(globals_, scalars):
         return fn(bid_table, globals_, scalars)
@@ -185,9 +184,9 @@ def _build_phased_strided_fn(plan: LaunchPlan, mesh, axis: str):
             g = merge.cross_device_merge(g, g2, masks, deltas, axis)
         return g
 
-    fn = shard_map(device_fn, mesh=mesh,
-                   in_specs=(P(), P()), out_specs=P(),
-                   check_vma=False)
+    fn = jax.shard_map(device_fn, mesh=mesh,
+                       in_specs=(P(), P()), out_specs=P(),
+                       check_vma=False)
 
     def run(globals_, scalars):
         return fn(globals_, scalars)

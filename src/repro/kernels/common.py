@@ -1,31 +1,6 @@
 """Shared Pallas utilities."""
 from __future__ import annotations
 
-try:  # TPU-specific namespace (present in jax 0.8)
-    import jax.experimental.pallas.tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
-
-
-def compiler_params(dimension_semantics):
-    """Best-effort TPU compiler params (ignored in interpret mode)."""
-    if pltpu is None:
-        return None
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            try:
-                return cls(dimension_semantics=tuple(dimension_semantics))
-            except TypeError:
-                continue
-    return None
-
-
-def vmem_scratch(shape, dtype):
-    if pltpu is None:
-        raise RuntimeError("pallas tpu namespace unavailable")
-    return pltpu.VMEM(shape, dtype)
-
 
 def cdiv(a: int, b: int) -> int:
     return -(-a // b)

@@ -16,8 +16,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .common import NEG_INF, compiler_params, vmem_scratch
+from .common import NEG_INF
 
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
@@ -71,7 +72,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     scale=None, bq: int = DEFAULT_BQ, bk: int = DEFAULT_BK,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q: (S, H, D); k/v: (S, Hkv, D) -> (S, H, D)."""
     S, H, D = q.shape
     Hkv = k.shape[1]
@@ -97,10 +98,11 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda h, iq, ik: (h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((H, S, D), q.dtype),
-        scratch_shapes=[vmem_scratch((bq,), jnp.float32),
-                        vmem_scratch((bq,), jnp.float32),
-                        vmem_scratch((bq, D), jnp.float32)],
-        compiler_params=compiler_params(("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((bq,), jnp.float32),
+                        pltpu.VMEM((bq,), jnp.float32),
+                        pltpu.VMEM((bq, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt)
     return out.transpose(1, 0, 2)
@@ -148,7 +150,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
 
 def flash_decode(q, k_cache, v_cache, kv_len, *, scale=None,
-                 bk: int = 512, interpret: bool = True):
+                 bk: int = 512, interpret: bool = False):
     """q: (H, D); caches: (S, Hkv, D); kv_len: () int32 -> (H, D)."""
     H, D = q.shape
     S, Hkv, _ = k_cache.shape
@@ -163,21 +165,26 @@ def flash_decode(q, k_cache, v_cache, kv_len, *, scale=None,
     vt = v_cache.transpose(1, 0, 2)
     kv_len = jnp.asarray(kv_len, jnp.int32).reshape(1)
 
+    # kv_len rides in SMEM as a prefetched scalar: the skip predicate
+    # below needs it as a scalar, and a (1,)-shaped VMEM block breaks
+    # the TPU's (8, 128) block rule once the serve step vmaps the call
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, bk=bk, nk=nk),
-        grid=(Hkv, nk),
-        in_specs=[
-            pl.BlockSpec((1,), lambda h, ik: (0,)),
-            pl.BlockSpec((1, g, D), lambda h, ik: (h, 0, 0)),
-            pl.BlockSpec((1, bk, D), lambda h, ik: (h, ik, 0)),
-            pl.BlockSpec((1, bk, D), lambda h, ik: (h, ik, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, g, D), lambda h, ik: (h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Hkv, nk),
+            in_specs=[
+                pl.BlockSpec((1, g, D), lambda h, ik, n: (h, 0, 0)),
+                pl.BlockSpec((1, bk, D), lambda h, ik, n: (h, ik, 0)),
+                pl.BlockSpec((1, bk, D), lambda h, ik, n: (h, ik, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, g, D), lambda h, ik, n: (h, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((g,), jnp.float32),
+                            pltpu.VMEM((g,), jnp.float32),
+                            pltpu.VMEM((g, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((Hkv, g, D), q.dtype),
-        scratch_shapes=[vmem_scratch((g,), jnp.float32),
-                        vmem_scratch((g,), jnp.float32),
-                        vmem_scratch((g, D), jnp.float32)],
-        compiler_params=compiler_params(("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(kv_len, qg, kt, vt)
     return out.reshape(H, D)

@@ -10,8 +10,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .common import cdiv, compiler_params
+from .common import cdiv
 
 ROWS_PER_TILE = 8
 
@@ -23,7 +24,7 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
     o_ref[...] = y.astype(o_ref.dtype)
 
 
-def rmsnorm(x, w, *, eps: float = 1e-6, interpret: bool = True):
+def rmsnorm(x, w, *, eps: float = 1e-6, interpret: bool = False):
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     rows, cols = x2.shape
@@ -35,7 +36,8 @@ def rmsnorm(x, w, *, eps: float = 1e-6, interpret: bool = True):
                   pl.BlockSpec((1, cols), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((rt, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), x.dtype),
-        compiler_params=compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2, w.reshape(1, -1))
     return out.reshape(shape)
@@ -50,7 +52,7 @@ def _layernorm_kernel(x_ref, w_ref, b_ref, o_ref, *, eps: float):
     o_ref[...] = y.astype(o_ref.dtype)
 
 
-def layernorm(x, w, b, *, eps: float = 1e-6, interpret: bool = True):
+def layernorm(x, w, b, *, eps: float = 1e-6, interpret: bool = False):
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     rows, cols = x2.shape
@@ -63,7 +65,8 @@ def layernorm(x, w, b, *, eps: float = 1e-6, interpret: bool = True):
                   pl.BlockSpec((1, cols), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((rt, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), x.dtype),
-        compiler_params=compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2, w.reshape(1, -1), b.reshape(1, -1))
     return out.reshape(shape)

@@ -7,12 +7,10 @@ Policy:
 * ``backend="xla"``     — the pure-jnp reference math (used by the model
   stack for CPU dry-runs: identical numerics, compact HLO);
 * ``backend="auto"``    — pallas on TPU, xla elsewhere.
-
-This is the hook the §Perf iterations toggle per-op.
 """
 from __future__ import annotations
 
-import os
+import functools
 
 import jax
 
@@ -23,69 +21,68 @@ from . import softmax as _sm
 from . import ssd_scan as _ssd
 from . import warp_reduce as _wr
 
-_DEFAULT = os.environ.get("REPRO_KERNEL_BACKEND", "auto")
-
 
 def resolve(backend: str = "auto") -> str:
-    if backend == "auto":
-        backend = _DEFAULT
     if backend == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "xla"
     return backend
 
 
-def softmax(x, backend: str = "auto"):
+def _dispatch(backend: str, kernel, reference, *args):
+    """``reference(*args)`` on the xla path; otherwise the Pallas
+    ``kernel`` forward with ``reference``'s gradient.  A Pallas call has
+    no reverse-mode rule, so training differentiates through the
+    reference math of the same function instead."""
     b = resolve(backend)
     if b == "xla":
-        return _ref.softmax(x)
-    return _sm.softmax(x, interpret=(b == "interpret"))
+        return reference(*args)
+    run = functools.partial(kernel, interpret=(b == "interpret"))
+
+    @jax.custom_vjp
+    def op(*a):
+        return run(*a)
+
+    op.defvjp(lambda *a: (run(*a), a),
+              lambda a, g: jax.vjp(reference, *a)[1](g))
+    return op(*args)
+
+
+def softmax(x, backend: str = "auto"):
+    return _dispatch(backend, _sm.softmax, _ref.softmax, x)
 
 
 def rmsnorm(x, w, eps: float = 1e-6, backend: str = "auto"):
-    b = resolve(backend)
-    if b == "xla":
-        return _ref.rmsnorm(x, w, eps)
-    return _norms.rmsnorm(x, w, eps=eps, interpret=(b == "interpret"))
+    return _dispatch(backend, functools.partial(_norms.rmsnorm, eps=eps),
+                     functools.partial(_ref.rmsnorm, eps=eps), x, w)
 
 
 def layernorm(x, w, bias, eps: float = 1e-6, backend: str = "auto"):
-    b = resolve(backend)
-    if b == "xla":
-        return _ref.layernorm(x, w, bias, eps)
-    return _norms.layernorm(x, w, bias, eps=eps, interpret=(b == "interpret"))
+    return _dispatch(backend, functools.partial(_norms.layernorm, eps=eps),
+                     functools.partial(_ref.layernorm, eps=eps), x, w, bias)
 
 
 def row_reduce(x, op: str = "sum", backend: str = "auto"):
-    b = resolve(backend)
-    if b == "xla":
-        return _ref.row_reduce(x, op)
-    return _wr.row_reduce(x, op, interpret=(b == "interpret"))
+    return _dispatch(backend, functools.partial(_wr.row_reduce, op=op),
+                     functools.partial(_ref.row_reduce, op=op), x)
 
 
 def attention(q, k, v, causal: bool = True, window: int = 0,
               backend: str = "auto"):
-    b = resolve(backend)
-    if b == "xla":
-        return _ref.attention(q, k, v, causal=causal, window=window)
-    return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                               interpret=(b == "interpret"))
+    kw = dict(causal=causal, window=window)
+    return _dispatch(backend, functools.partial(_fa.flash_attention, **kw),
+                     functools.partial(_ref.attention, **kw), q, k, v)
 
 
 def decode_attention(q, k_cache, v_cache, kv_len, backend: str = "auto"):
-    b = resolve(backend)
-    if b == "xla":
-        return _ref.decode_attention(q, k_cache, v_cache, kv_len)
-    return _fa.flash_decode(q, k_cache, v_cache, kv_len,
-                            interpret=(b == "interpret"))
+    return _dispatch(backend, _fa.flash_decode, _ref.decode_attention,
+                     q, k_cache, v_cache, kv_len)
 
 
 def ssd_scan(x, a, bmat, cmat, chunk: int = 128, backend: str = "auto"):
-    b = resolve(backend)
-    if b == "xla":
-        # chunked dual form: same math, production XLA path
-        return _ref.ssd_scan_chunked(x, a, bmat, cmat, chunk=chunk)
-    return _ssd.ssd_scan(x, a, bmat, cmat, chunk=chunk,
-                         interpret=(b == "interpret"))
+    # xla: the chunked dual form — same math, production XLA path
+    return _dispatch(backend, functools.partial(_ssd.ssd_scan, chunk=chunk),
+                     functools.partial(_ref.ssd_scan_chunked, chunk=chunk),
+                     x, a, bmat, cmat)
 
 
 def topk_gate(logits, k: int):

@@ -9,8 +9,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .common import cdiv, compiler_params
+from .common import cdiv
 
 ROWS_PER_TILE = 8
 
@@ -23,7 +24,7 @@ def _softmax_kernel(x_ref, o_ref):
     o_ref[...] = (e / s).astype(o_ref.dtype)
 
 
-def softmax(x: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
+def softmax(x: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
     orig_shape = x.shape
     x2 = x.reshape(-1, orig_shape[-1])
     rows, cols = x2.shape
@@ -34,7 +35,8 @@ def softmax(x: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
         in_specs=[pl.BlockSpec((rt, cols), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rt, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), x.dtype),
-        compiler_params=compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2)
     return out.reshape(orig_shape)

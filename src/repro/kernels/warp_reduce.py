@@ -19,8 +19,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .common import cdiv, compiler_params
+from .common import cdiv
 
 ROWS_PER_TILE = 8
 COL_TILE = 2048
@@ -44,7 +45,7 @@ def _reduce_kernel(x_ref, o_ref, *, op: str, n_col_tiles: int, cols: int):
 
 
 def row_reduce(x: jnp.ndarray, op: str = "sum", *,
-               interpret: bool = True) -> jnp.ndarray:
+               interpret: bool = False) -> jnp.ndarray:
     """(rows, cols) -> (rows,). op in {sum, max, absmax}."""
     rows, cols = x.shape
     rt = min(ROWS_PER_TILE, rows)
@@ -56,7 +57,8 @@ def row_reduce(x: jnp.ndarray, op: str = "sum", *,
         in_specs=[pl.BlockSpec((rt, cols), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rt, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, 1), jnp.float32),
-        compiler_params=compiler_params(("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x)
     return out[:, 0].astype(x.dtype if op != "sum" else jnp.float32)

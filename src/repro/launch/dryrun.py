@@ -20,13 +20,14 @@ import time
 import traceback
 from typing import Any, Dict, Optional
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^^ MUST precede any jax import: jax locks device count at first init.
+from ..configs import registry
+from ..configs.base import LONG_CONTEXT_OK, SHAPES
+from ..parallel import steps as steps_mod
+from .mesh import make_production_mesh
 
-from ..configs import registry  # noqa: E402
-from ..configs.base import LONG_CONTEXT_OK, SHAPES  # noqa: E402
-from ..parallel import steps as steps_mod  # noqa: E402
-from .mesh import make_production_mesh  # noqa: E402
+# 512 forced CPU host devices for the production meshes: set by the
+# command-line entry points only, before JAX creates its first backend
+HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count=512"
 
 # ---------------------------------------------------------------------------
 # HLO collective-byte accounting
@@ -172,6 +173,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
 
 def main(argv=None):
+    os.environ["XLA_FLAGS"] = HOST_DEVICES_FLAG
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

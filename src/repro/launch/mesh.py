@@ -1,19 +1,30 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-A function (not a module-level constant) so importing this module never
-touches jax device state.  Single-pod: 16×16 = 256 chips (v5e pod);
+Functions (not module-level constants) so importing this module never
+touches jax device state.  Every mesh in the repo is built by
+:func:`make_mesh`: its axes are ``Auto``, which the model layers'
+``with_sharding_constraint`` calls and the embedding gather on a
+model-sharded table assume.  Single-pod: 16×16 = 256 chips (v5e pod);
 multi-pod: 2×16×16 = 512 chips with a leading "pod" axis for cross-pod
 data parallelism (hierarchical DP: fast in-pod ICI, slow DCN across).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -21,7 +32,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     data = min(data, n)
     model = max(1, min(model, n // max(data, 1)))
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def device_pool(n: int | None = None, *, mesh=None):

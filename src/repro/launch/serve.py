@@ -32,6 +32,7 @@ from ..core import cox
 from ..models.params import init_params
 from ..parallel import steps as steps_mod
 from .mesh import make_host_mesh
+from . import compile_cache
 from . import specs as S
 
 
@@ -295,6 +296,8 @@ class BatchedServer:
                 self.tokens[i] = t
                 if eos is not None and t == eos:
                     self.active[i] = False
+                if len(self.outputs[i]) >= max_tokens:
+                    self.active[i] = False
                 if self.pos[i] >= self.ctx - 1:
                     self.active[i] = False
             for p in pipelines or ():
@@ -434,6 +437,7 @@ def main():
                          "autotune cache; a warm cache issues zero "
                          "measurement launches)")
     args = ap.parse_args()
+    compile_cache.enable()
     if args.autotune:
         import os
         os.environ.setdefault("COX_AUTOTUNE", "1")

@@ -21,6 +21,7 @@ from ..ft.watchdog import FailureInjector, StepWatchdog, retry_loop
 from ..models.params import init_params
 from ..optim import adamw
 from ..parallel import steps as steps_mod
+from . import compile_cache
 from .mesh import make_host_mesh
 
 
@@ -109,6 +110,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
     out = train(args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
                 ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
                 seed=args.seed)

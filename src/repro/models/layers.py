@@ -318,8 +318,7 @@ def moe_apply(p, x, *, cfg, rules=None):
         y = jax.lax.psum(y, "model")
         return y.reshape(Bl, Sl, d).astype(xb.dtype)
 
-    from repro.core.compat import shard_map
-    y = shard_map(
+    y = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, router_spec, ew_spec, ew_spec, ew_spec),
         out_specs=x_spec, check_vma=False,

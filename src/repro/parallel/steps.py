@@ -67,7 +67,7 @@ def _with_tp_pad(cfg: ModelConfig, mesh: Mesh) -> ModelConfig:
 def make_train_step(cfg: ModelConfig, mesh: Mesh,
                     opt_cfg: Optional[adamw.AdamWConfig] = None,
                     rules: Optional[AxisRules] = None,
-                    backend: str = "xla", strategy: str = "tp"):
+                    backend: str = "auto", strategy: str = "tp"):
     """Returns (step_fn, bundle) where step_fn(params, opt_state, batch)
     -> (params, opt_state, metrics), fully sharded and donated."""
     cfg = _with_tp_pad(cfg, mesh)
@@ -106,7 +106,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh,
 
 def jit_train_step(cfg, mesh, shape: ShapeConfig,
                    opt_cfg: Optional[adamw.AdamWConfig] = None,
-                   backend: str = "xla", rules=None, strategy: str = "tp"):
+                   backend: str = "auto", rules=None, strategy: str = "tp"):
     step, bundle = make_train_step(cfg, mesh, opt_cfg, rules=rules,
                                    backend=backend, strategy=strategy)
     rules = bundle["rules"]
@@ -141,7 +141,7 @@ def _opt_abstract(spec_tree, opt_cfg):
 
 def make_serve_step(cfg: ModelConfig, mesh: Mesh,
                     rules: Optional[AxisRules] = None,
-                    backend: str = "xla", strategy: str = "tp"):
+                    backend: str = "auto", strategy: str = "tp"):
     cfg = _with_tp_pad(cfg, mesh)
     rules = rules or default_rules(mesh, strategy)
     spec_tree = model_specs(cfg)
@@ -160,7 +160,7 @@ def make_serve_step(cfg: ModelConfig, mesh: Mesh,
                   "param_sh": param_shardings(rules, spec_tree)}
 
 
-def jit_serve_step(cfg, mesh, shape: ShapeConfig, backend: str = "xla",
+def jit_serve_step(cfg, mesh, shape: ShapeConfig, backend: str = "auto",
                    rules=None, strategy: str = "tp"):
     step, bundle = make_serve_step(cfg, mesh, rules=rules, backend=backend,
                                    strategy=strategy)

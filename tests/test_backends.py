@@ -55,9 +55,9 @@ def test_sharded_matches_scan_on_one_device_mesh(name):
     """shard_map × vmap recomposition on an in-process 1-device mesh
     (8-device semantics live in test_multidevice.py); representative
     features: plain, block-cg, warp-cg, shuffle, vote, atomics."""
-    import jax
+    from repro.launch.mesh import make_mesh
     sk = next(k for k in all_kernels() if k.name == name)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     args = sk.make_args()
     want = _launch(sk, args, backend="scan")
     got = _launch(sk, args, mesh=mesh, chunk=3)
@@ -120,9 +120,9 @@ def test_warp_batched_composes_with_block_vmap(name):
 
 
 def test_warp_batched_on_one_device_mesh():
-    import jax
+    from repro.launch.mesh import make_mesh
     sk = next(k for k in all_kernels() if k.name == "MatrixMulCUDA")
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     args = sk.make_args()
     want = _launch(sk, args, backend="scan", warp_exec="serial")
     got = _launch(sk, args, mesh=mesh, chunk=3, warp_exec="batched")
@@ -374,8 +374,8 @@ def test_atomic_old_capture_is_serial_only():
 def test_atomic_old_capture_rejected_on_mesh():
     """A mesh forces the sharded backend, whose merge cannot reproduce
     ticket semantics either — reject at build, never run silently."""
-    import jax
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     args = (np.full(8, -1, np.int32), np.zeros(1, np.int32))
     with pytest.raises(CoxUnsupported):
         _k_ticket.launch(grid=8, block=32, args=args, mesh=mesh)
@@ -481,10 +481,10 @@ def test_launch_cache_hits_on_repeat_and_splits_on_geometry():
 def test_mesh_key_is_content_based():
     """Two equivalent meshes must share a launch-cache key: id()-based
     keys can be recycled after GC and alias stale executables."""
-    import jax
+    from repro.launch.mesh import make_mesh
     from repro.core.api import _mesh_key
-    m1 = jax.make_mesh((1,), ("data",))
-    m2 = jax.make_mesh((1,), ("data",))
+    m1 = make_mesh((1,), ("data",))
+    m2 = make_mesh((1,), ("data",))
     k1, k2 = _mesh_key(m1), _mesh_key(m2)
     assert k1 == k2
     hash(k1)

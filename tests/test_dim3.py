@@ -337,9 +337,9 @@ def test_dim3_kernels_all_cells_bitwise_and_oracle(name):
 
 @pytest.mark.parametrize("name", _DIM3_PICKS)
 def test_dim3_kernels_sharded_one_device_mesh(name):
-    import jax
+    from repro.launch.mesh import make_mesh
     sk = next(k for k in all_kernels() if k.name == name)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     args = sk.make_args()
     want = sk.kernel.launch(grid=sk.grid, block=sk.block, args=args,
                             backend="scan")

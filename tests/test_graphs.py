@@ -16,6 +16,7 @@ import pytest
 from repro.core import cox
 from repro.core.streams import Dispatcher, Stream
 from repro.core.types import CoxUnsupported, GraphRef
+from repro.launch.mesh import make_mesh
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
@@ -130,7 +131,7 @@ def test_replay_bitwise_equals_eager(backend, warp_exec):
 
 
 def test_replay_bitwise_equals_eager_sharded():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     d, s, _ = _fresh()
     o, x, y, n = _args()
     kw = dict(mesh=mesh, backend="sharded")

@@ -29,6 +29,7 @@ from repro.core.backends.plan import DEFAULT_CHUNK, LaunchPlan
 from repro.core.runtime import resolve_launch, resolve_schedule
 from repro.core.streams import Dispatcher, Stream
 from repro.core.types import CoxUnsupported
+from repro.launch.mesh import make_mesh
 
 jax = pytest.importorskip("jax")
 
@@ -80,7 +81,7 @@ def test_stride_matches_chunked_bitwise(backend, warp_exec):
 
 @pytest.mark.parametrize("warp_exec", ["serial", "batched"])
 def test_stride_matches_chunked_sharded(warp_exec):
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     grid, block = 10, 64
     args = _saxpy_args(grid, block)
     kw = dict(grid=grid, block=block, args=args, mesh=mesh,
@@ -151,7 +152,7 @@ def test_stride_cooperative_pages_blocks_through_phases(backend):
     args = sk.make_args()
     kw = dict(grid=sk.grid, block=sk.block, args=args)
     if backend == "sharded":
-        kw["mesh"] = jax.make_mesh((1,), ("data",))
+        kw["mesh"] = make_mesh((1,), ("data",))
     else:
         kw["backend"] = backend
     want = _np(sk.kernel.launch(**kw))
@@ -186,6 +187,7 @@ def test_stride_placed_multi_device_bitwise():
     # launch exactly (grid=10 over 4 devices: uneven 3/3/3/1 stripes)
     code = textwrap.dedent("""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from tests.multidevice_kernels import vec_madd
         assert len(jax.devices()) == 4
         grid, block = 10, 128
@@ -195,7 +197,7 @@ def test_stride_placed_multi_device_bitwise():
         y = rng.normal(size=n).astype(np.float32)
         args = (np.zeros(n, np.float32), x, y, n)
         want = vec_madd.launch(grid=grid, block=block, args=args)["out"]
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         got = vec_madd.launch(grid=grid, block=block, args=args, mesh=mesh,
                               schedule="grid_stride", n_resident=2)["out"]
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -47,9 +47,9 @@ def test_grid_reduce_bitwise_matches_oracle(backend, warp_exec):
 
 @pytest.mark.parametrize("warp_exec", ["serial", "batched"])
 def test_grid_reduce_sharded_one_device_mesh(warp_exec):
-    import jax
+    from repro.launch.mesh import make_mesh
     sk = GRID_REDUCE
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     args = sk.make_args()
     want = _launch(sk, args, backend="scan", warp_exec="serial")
     got = _launch(sk, args, mesh=mesh, warp_exec=warp_exec)

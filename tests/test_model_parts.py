@@ -10,6 +10,7 @@ import pytest
 
 from repro.configs import registry
 from repro.kernels import ref
+from repro.launch.mesh import make_mesh
 from repro.models import layers as L
 from repro.models.params import (ParamSpec, default_rules, init_params,
                                  zero1_pspec)
@@ -46,7 +47,7 @@ def test_moe_shard_map_path_matches_local():
     cfg = registry.get("granite-moe-1b-a400m", smoke=True)
     p = init_params(L.moe_specs(cfg), jax.random.PRNGKey(1))
     x = rand((2, 8, cfg.d_model))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = default_rules(mesh)
     got = L.moe_apply(p, x, cfg=cfg, rules=rules)
     want = L.moe_apply(p, x, cfg=cfg, rules=None)
@@ -120,7 +121,7 @@ def test_head_padding_exactness():
 
 
 def test_zero1_adds_data_axis():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = default_rules(mesh)
     spec = ParamSpec((4, 8), jnp.float32, (None, "mlp"))
     ps = zero1_pspec(rules, spec)

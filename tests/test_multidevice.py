@@ -24,6 +24,7 @@ def run_worker(body: str) -> str:
 def test_cox_grid_sharded_matches_single():
     run_worker("""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         import tests.multidevice_kernels as mk
         from repro.core.oracle import run_grid as oracle_run
         assert len(jax.devices()) == 8
@@ -31,7 +32,7 @@ def test_cox_grid_sharded_matches_single():
         b = np.ones(2048, np.float32)
         out0 = np.zeros(2048, np.float32)
         args = (out0, a, b, 2000)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         got = mk.vec_madd.launch(grid=8, block=256, args=args, mesh=mesh)
         want = mk.vec_madd.launch(grid=8, block=256, args=args)
         np.testing.assert_allclose(np.asarray(got["out"]),
@@ -46,10 +47,11 @@ def test_cox_grid_sharded_matches_single():
 def test_cox_atomics_psum_merge():
     run_worker("""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         import tests.multidevice_kernels as mk
         a = np.random.default_rng(0).integers(0, 16, 1024).astype(np.int32)
         hist0 = np.zeros(16, np.float32)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         got = mk.histogram.launch(grid=8, block=128, args=(hist0, a, 1024),
                                   mesh=mesh)
         want = np.bincount(a, minlength=16).astype(np.float32)
@@ -65,12 +67,13 @@ def test_cox_grid_sync_sharded_8dev():
     # phase-0 partials written on every other device
     run_worker("""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from benchmarks.kernels_suite import all_kernels
         from repro.core.oracle import run_grid as oracle_run
         assert len(jax.devices()) == 8
         sk = next(k for k in all_kernels() if k.name == "gridReduce")
         args = sk.make_args()
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         got = sk.kernel.launch(grid=sk.grid, block=sk.block, args=args,
                                mesh=mesh)
         ref = oracle_run(sk.kernel.ir, grid=sk.grid, block=sk.block,
@@ -85,6 +88,7 @@ def test_cox_grid_sync_sharded_8dev():
 def test_moe_ep_on_2x4_mesh():
     run_worker("""
         import jax, numpy as np, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.configs import registry
         from repro.models import layers as L
         from repro.models.params import default_rules, init_params
@@ -92,7 +96,7 @@ def test_moe_ep_on_2x4_mesh():
         p = init_params(L.moe_specs(cfg), jax.random.PRNGKey(1))
         x = jnp.asarray(np.random.default_rng(2).normal(
             size=(4, 8, cfg.d_model)).astype(np.float32))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = default_rules(mesh)
         got = L.moe_apply(p, x, cfg=cfg, rules=rules)
         want = L.moe_apply(p, x, cfg=cfg, rules=None)
@@ -106,6 +110,7 @@ def test_moe_ep_on_2x4_mesh():
 def test_train_step_on_2x4_mesh():
     run_worker("""
         import jax, numpy as np, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.configs import registry
         from repro.configs.base import ShapeConfig
         from repro.parallel import steps as steps_mod
@@ -113,7 +118,7 @@ def test_train_step_on_2x4_mesh():
         from repro.optim import adamw
         from repro.data.pipeline import TokenSource, DataConfig
         cfg = registry.get("qwen2.5-14b", smoke=True)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         shape = ShapeConfig("t", 64, 4, "train")
         jitted, bundle, abstract = steps_mod.jit_train_step(cfg, mesh, shape)
         params = jax.device_put(

@@ -17,6 +17,7 @@ import pytest
 
 from repro.core import cox
 from repro.core.streams import Dispatcher
+from repro.launch.mesh import make_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -264,7 +265,7 @@ def test_explicit_device_pin_single_pool():
 
 
 def test_device_and_mesh_are_mutually_exclusive():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     with pytest.raises(cox.CoxUnsupported, match="mutually exclusive"):
         prioAdd.make_request(grid=1, block=256,
                              args=(np.zeros(256, np.float32),

@@ -28,6 +28,7 @@ import jax.numpy as jnp  # noqa: E402
 from repro.core import cox  # noqa: E402
 from repro.core.streams import Dispatcher, Stream  # noqa: E402
 from repro.core.types import CoxUnsupported  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 @cox.kernel
@@ -409,7 +410,7 @@ def test_request_kernel_pool_on_per_slot_streams():
 
 
 def test_donate_rejected_on_sharded():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     o, x, y, n = _args(512)
     with pytest.raises(CoxUnsupported):
         _saxpy.launch(grid=2, block=128, args=(o, x, y, n),

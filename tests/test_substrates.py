@@ -14,6 +14,7 @@ from repro.configs import registry
 from repro.data.pipeline import DataConfig, TokenSource
 from repro.ft.watchdog import FailureInjector, StepWatchdog, retry_loop
 from repro.launch.train import train
+from repro.launch.mesh import make_mesh
 from repro.optim import adamw
 
 
@@ -52,7 +53,7 @@ def test_checkpoint_elastic_resharding(tmp_path):
     mgr = CheckpointManager(str(tmp_path))
     x = jnp.arange(16, dtype=jnp.float32)
     mgr.save(0, {"x": x}, blocking=True)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("data"))
     back = mgr.restore(0, {"x": jax.ShapeDtypeStruct((16,), jnp.float32)},
                        {"x": sh})

@@ -74,14 +74,14 @@ def test_dryrun_single_cell_smoke():
     """The dry-run path works in-process on the 1-device platform when
     pointed at a tiny mesh (full 512-dev runs happen via the module CLI,
     which sets XLA_FLAGS before jax init)."""
-    import jax
+    from repro.launch.mesh import make_mesh
     from repro.configs import registry
     from repro.configs.base import ShapeConfig
     from repro.parallel import steps as steps_mod
     from repro.launch.hlo_analysis import analyze
 
     cfg = registry.get("granite-20b", smoke=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeConfig("t", 64, 2, "train")
     jitted, bundle, abstract = steps_mod.jit_train_step(cfg, mesh, shape)
     compiled = jitted.lower(*abstract).compile()
