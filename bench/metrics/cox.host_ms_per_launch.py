@@ -1,0 +1,4 @@
+"""``bench.readings.host_ms_per_launch``, for the cells whose launches stand
+alone (``cox_ms_per_launch``)."""
+
+from bench.readings import host_ms_per_launch as read  # noqa: F401
