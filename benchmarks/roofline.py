@@ -70,9 +70,8 @@ def from_telemetry(rows, peak_flops: float = None,
     """Roofline rows from the dispatcher's *live* counters
     (``cox.get_dispatcher().telemetry()``) instead of dry-run JSON:
     each stage-key row's op/mem estimates against the peaks of the
-    device JAX runs on (:func:`device_peaks`) give t_compute/t_memory,
-    the dominant term, and — where the row carries measured wall time —
-    the achieved fraction of the dominant roof.  The returned dicts
+    device JAX runs on (:func:`device_peaks`) give t_compute/t_memory
+    and the dominant term.  The returned dicts
     keep the telemetry fields (kernel, backend, warp_exec, chunk,
     launches, gflops) so the bench JSON can embed them verbatim."""
     peaks = device_peaks()
@@ -87,13 +86,6 @@ def from_telemetry(rows, peak_flops: float = None,
         row = dict(rec)
         row.update(t_compute=t_comp, t_memory=t_mem, dominant=dominant,
                    roofline_fraction=(t_comp / bound if bound else 0.0))
-        per = rec.get("s_per_launch", 0.0)
-        if rec.get("time_basis") == "measured" and per > 0 and bound > 0:
-            # achieved share of the dominant roof: 1.0 = running at the
-            # machine balance point, ≪1 = far off the roof (overhead,
-            # serialization, or a pessimistic estimate — the
-            # check_smoke accuracy gate bounds how far)
-            row["roof_attained"] = bound / per
         out.append(row)
     return out
 

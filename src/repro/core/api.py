@@ -14,7 +14,10 @@
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Sequence
+
+from jax.profiler import annotate_function
 
 from . import autotune  # noqa: F401  (cox.autotune — measured knob tuning)
 from . import autotune as _autotune  # distinct alias: make_request's
@@ -109,6 +112,7 @@ class KernelFn:
         return self._compiled_for(self._compile_key(
             collapse=collapse, warp_size=warp_size, block=block))
 
+    @functools.partial(annotate_function, name="cox.resolve")
     def make_request(self, *, grid, block, args: Sequence[Any],
                      collapse: str = "hybrid", mode: str = "auto",
                      simd: bool = True, warp_size: int = WARP_SIZE,

@@ -42,12 +42,14 @@ functional analogue of relaunching over the same device buffers).
 from __future__ import annotations
 
 import contextlib
+import functools
 import itertools
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import annotate_function
 
 from . import errors as _errors
 from . import faults as _faults
@@ -268,7 +270,7 @@ class Graph:
         nodes = self.nodes
 
         def builder():
-            return _trace_graph(disp, nodes, spec)
+            return _trace_graph(disp, nodes, spec, self.name)
 
         exe, raw_fn = disp.stage_graph(key, builder)
         self._frozen = True                # the DAG is baked in; no edits
@@ -368,7 +370,8 @@ def _node_sig(node: GraphNode, spec: Dict[str, Any]) -> tuple:
             + spec["node_bindings"][node.idx])
 
 
-def _trace_graph(disp, nodes: List[GraphNode], spec: Dict[str, Any]):
+def _trace_graph(disp, nodes: List[GraphNode], spec: Dict[str, Any],
+                 name: str):
     """Build the fused executable: one ``jax.jit`` program that walks
     the nodes in capture (= topological) order, threading producer
     outputs straight into consumer bindings.  External inputs arrive as
@@ -377,8 +380,9 @@ def _trace_graph(disp, nodes: List[GraphNode], spec: Dict[str, Any]):
     conversion point for rebound values).  Returns only terminal
     outputs — consumed intermediates exist solely as values inside the
     trace, free for XLA to fuse away.  Returns ``(jitted, raw)`` — the
-    fused executable plus the un-jitted trace function, the replay →
-    eager fallback rung of the degradation ladder.
+    fused executable, jitted as ``cox_graph_<name>`` (XLA module
+    ``jit_cox_graph_<name>``), plus the un-jitted trace function, the
+    replay → eager fallback rung of the degradation ladder.
 
     A node that fails to stage fails the whole instantiation with *its
     own* typed error (:func:`~repro.core.errors.classify`, naming the
@@ -415,6 +419,7 @@ def _trace_graph(disp, nodes: List[GraphNode], spec: Dict[str, Any]):
                 vals[(n.idx, k)] = v
         return {c: vals[t] for c, t in outputs.items()}
 
+    graph_fn.__name__ = graph_fn.__qualname__ = f"cox_graph_{name}"
     return jax.jit(graph_fn), graph_fn
 
 
@@ -464,6 +469,7 @@ class GraphExec:
     def output_names(self) -> Tuple[str, ...]:
         return tuple(self._outputs)
 
+    @functools.partial(annotate_function, name="cox.graph.replay")
     def replay(self, **bindings) -> Dict[str, Any]:
         for name, val in bindings.items():
             if name in self._vals:

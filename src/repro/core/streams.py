@@ -82,6 +82,7 @@ dispatch path.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import heapq
 import itertools
 import threading
@@ -91,6 +92,7 @@ from collections import OrderedDict, deque
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import jax
+from jax.profiler import TraceAnnotation, annotate_function
 
 from . import costmodel as _costmodel
 from . import errors as _errors
@@ -699,8 +701,7 @@ class Dispatcher:
         self.stage_fn_misses = 0
         # ---- telemetry (README "Autotune & telemetry") ----
         # per-stage-key live counters: launches, host dispatch seconds,
-        # estimated bytes/FLOPs (repro.core.costmodel), plus measured
-        # wall seconds when a caller notes them (note_measurement) —
+        # estimated bytes/FLOPs (repro.core.costmodel) —
         # benchmarks/roofline.py computes roofline position from these
         # real counters instead of dry-run JSON
         self._telemetry: "OrderedDict[tuple, Dict[str, Any]]" = OrderedDict()
@@ -856,6 +857,7 @@ class Dispatcher:
 
     # ---------------- enqueue ----------------
 
+    @functools.partial(annotate_function, name="cox.enqueue")
     def enqueue(self, req: LaunchRequest, stream: Stream) -> LaunchHandle:
         """Assign the request its place in the launch order: program
         order on its stream, pending event edges, and the default
@@ -951,9 +953,10 @@ class Dispatcher:
                 self._staged.move_to_end(key)
                 self.stage_hits += 1
                 return hit
-        plan, fn = self.stage_fn(req)
-        staged = (plan, jax.jit(fn, donate_argnums=(0,) if req.donate
-                                else ()))
+        with TraceAnnotation("cox.restage"):
+            plan, fn = self.stage_fn(req)
+            staged = (plan, jax.jit(fn, donate_argnums=(0,) if req.donate
+                                    else ()))
         with self._lock:
             self.stage_misses += 1
             self._staged[key] = staged
@@ -975,9 +978,14 @@ class Dispatcher:
                 self._staged_fns.move_to_end(key)
                 self.stage_fn_hits += 1
                 return hit
-        staged = _runtime.build_traceable(
-            req.ck, req.rl, simd=req.simd, mesh=req.mesh, axis=req.axis,
-            chunk=req.chunk)
+        with TraceAnnotation("cox.restage"):
+            plan, fn = _runtime.build_traceable(
+                req.ck, req.rl, simd=req.simd, mesh=req.mesh, axis=req.axis,
+                chunk=req.chunk)
+        # the launcher is built for this stage alone; its name is the one
+        # jax.jit gives the XLA module (jit_cox_<kernel>)
+        fn.__name__ = fn.__qualname__ = f"cox_{req.ck.kernel.name}"
+        staged = (plan, fn)
         with self._lock:
             self.stage_fn_misses += 1
             self._staged_fns[key] = staged
@@ -998,7 +1006,8 @@ class Dispatcher:
                 self._staged.move_to_end(key)
                 self.stage_hits += 1
                 return hit
-        staged = builder()
+        with TraceAnnotation("cox.restage"):
+            staged = builder()
         with self._lock:
             self.stage_misses += 1
             self._staged[key] = staged
@@ -1047,6 +1056,7 @@ class Dispatcher:
             raise AssertionError("cycle in launch-dependency graph")
         return out
 
+    @functools.partial(annotate_function, name="cox.dispatch")
     def _dispatch(self, req: LaunchRequest) -> None:
         name = req.ck.kernel.name
         if req.error is not None:         # already failed fast (descendant)
@@ -1229,7 +1239,8 @@ class Dispatcher:
                                        what=f"kernel '{name}'")
         try:
             t0 = time.perf_counter()
-            outputs = exe(req.globals_, req.scalars)   # async dispatch
+            with TraceAnnotation("cox.xla_call"):
+                outputs = exe(req.globals_, req.scalars)   # async dispatch
             dispatch_s = time.perf_counter() - t0
         except Exception as e:
             raise _errors.classify(e, site="dispatch",
@@ -1346,13 +1357,14 @@ class Dispatcher:
         name = req.ck.kernel.name
         if deadline is None:
             try:
-                _block_outputs(req.outputs)
+                with TraceAnnotation("cox.wait"):
+                    _block_outputs(req.outputs)
             except Exception as e:
                 err = _errors.classify(e, site="dispatch",
                                        what=f"kernel '{name}'")
                 self._record_async_failure(req, err, extra)
             return
-        with self._wd_lock:              # one deadline wait at a time
+        with self._wd_lock, TraceAnnotation("cox.wait"):   # one at a time
             wd = self.watchdog
             if wd is None or wd.deadline_s != deadline:
                 wd = StepWatchdog(deadline_s=deadline,
@@ -1599,86 +1611,64 @@ class Dispatcher:
     def _telemetry_key(req: LaunchRequest) -> tuple:
         """Human-readable stage identity: one row per distinct
         (kernel, backend, warp_exec, chunk, schedule, geometry,
-        device)."""
+        device, argument shapes)."""
         rl = req.rl
         return (req.ck.kernel.name, rl.backend, rl.warp_exec,
                 rl.chunk, rl.schedule, rl.n_resident,
                 rl.grid.astuple(), rl.block.astuple(),
-                _dev_id(req.device))
+                _dev_id(req.device), tuple(req.shapes.items()))
 
-    def _note_telemetry(self, req: LaunchRequest, dispatch_s: float) -> None:
-        """Record one dispatched launch against its stage-key row.  The
-        cost estimate comes from ``repro.core.costmodel`` (cached per
-        launch shape; 'static' by default — ``COX_COSTMODEL=xla``
-        upgrades to the compiled program's own cost analysis).  Never
-        raises: telemetry must not be able to fail a launch."""
+    @staticmethod
+    def _telemetry_row(req: LaunchRequest) -> Dict[str, Any]:
+        """A fresh stage-key row.  Its cost estimate is fixed for the
+        row's key, so it is taken once, here, from ``repro.core.
+        costmodel`` ('static' by default — ``COX_COSTMODEL=xla``
+        upgrades to the compiled program's own cost analysis)."""
         try:
             est = _costmodel.estimate_request(req)
         except Exception:       # pragma: no cover - estimate never raises
             est = None
+        return {
+            "launches": 0, "dispatch_s": 0.0, "bytes": 0.0, "flops": 0.0,
+            "op_estimate": est.op_estimate if est else 0.0,
+            "mem_estimate": est.mem_estimate if est else 0.0,
+            "estimate_source": est.source if est else None,
+            "chunk_source": getattr(req.rl, "chunk_source", "heuristic"),
+            "schedule_source":
+                getattr(req.rl, "schedule_source", "heuristic"),
+        }
+
+    def _note_telemetry(self, req: LaunchRequest, dispatch_s: float) -> None:
+        """Record one dispatched launch against its stage-key row.
+        Never raises: telemetry must not be able to fail a launch."""
         key = self._telemetry_key(req)
         with self._lock:
             rec = self._telemetry.get(key)
-            if rec is None:
-                rec = self._telemetry[key] = {
-                    "launches": 0, "dispatch_s": 0.0, "bytes": 0.0,
-                    "flops": 0.0, "op_estimate": 0.0, "mem_estimate": 0.0,
-                    "estimate_source": None, "chunk_source":
-                        getattr(req.rl, "chunk_source", "heuristic"),
-                    "schedule_source":
-                        getattr(req.rl, "schedule_source", "heuristic"),
-                    "measured_s": 0.0, "measured_launches": 0,
-                }
-                while len(self._telemetry) > TELEMETRY_MAX:
-                    self._telemetry.popitem(last=False)
-            else:
-                self._telemetry.move_to_end(key)
+        if rec is None:
+            rec = self._telemetry_row(req)    # the estimate: no _lock held
+        with self._lock:
+            rec = self._telemetry.setdefault(key, rec)
+            self._telemetry.move_to_end(key)
+            while len(self._telemetry) > TELEMETRY_MAX:
+                self._telemetry.popitem(last=False)
             rec["launches"] += 1
             rec["dispatch_s"] += dispatch_s
-            if est is not None:
-                rec["op_estimate"] = est.op_estimate
-                rec["mem_estimate"] = est.mem_estimate
-                rec["estimate_source"] = est.source
-                rec["bytes"] += est.mem_estimate
-                rec["flops"] += est.op_estimate
-
-    def note_measurement(self, req: LaunchRequest, seconds: float,
-                         launches: int = 1) -> None:
-        """Attach measured wall time to a request's stage-key row — the
-        benchmark harness and autotuner call this after timing a
-        synchronized launch, turning the row's estimates into achieved
-        GFLOPS/bandwidth."""
-        key = self._telemetry_key(req)
-        with self._lock:
-            rec = self._telemetry.get(key)
-            if rec is None:
-                return
-            rec["measured_s"] += float(seconds)
-            rec["measured_launches"] += int(launches)
+            rec["bytes"] += rec["mem_estimate"]
+            rec["flops"] += rec["op_estimate"]
 
     def telemetry(self) -> List[Dict[str, Any]]:
-        """The per-stage-key counter rows, with achieved GFLOPS and
-        GB/s derived where measured wall time is available (falling
-        back to host dispatch time — a lower bound — otherwise)."""
+        """The per-stage-key counter rows, with GFLOPS and GB/s derived
+        from host dispatch time (a lower bound on a launch's time)."""
         with self._lock:
             rows = [(k, dict(v)) for k, v in self._telemetry.items()]
         out: List[Dict[str, Any]] = []
         for (name, backend, warp_exec, chunk, schedule, n_resident,
-             grid, block, dev), rec in rows:
+             grid, block, dev, shapes), rec in rows:
             rec.update(kernel=name, backend=backend, warp_exec=warp_exec,
                        chunk=chunk, schedule=schedule,
                        n_resident=n_resident, grid=grid, block=block,
-                       device=dev)
-            n = max(1, rec["launches"])
-            if rec["measured_launches"] > 0 and rec["measured_s"] > 0:
-                per = rec["measured_s"] / rec["measured_launches"]
-                rec["time_basis"] = "measured"
-            elif rec["dispatch_s"] > 0:
-                per = rec["dispatch_s"] / n
-                rec["time_basis"] = "dispatch"
-            else:
-                per = 0.0
-                rec["time_basis"] = "none"
+                       device=dev, shapes=dict(shapes))
+            per = rec["dispatch_s"] / max(1, rec["launches"])
             rec["s_per_launch"] = per
             rec["gflops"] = (rec["op_estimate"] / per / 1e9) if per else 0.0
             rec["gbps"] = (rec["mem_estimate"] / per / 1e9) if per else 0.0
@@ -1694,7 +1684,9 @@ class Dispatcher:
         stays the first sticky error's repr (or None) for backward
         compatibility.  ``telemetry_keys``/``dispatch_s``/``bytes``
         summarize the live per-stage-key counters (full rows via
-        :meth:`telemetry`); ``autotune`` carries the knob-tuner's
+        :meth:`telemetry`); ``stage_hits``/``stage_misses`` count the
+        staging cache's lookups (a miss builds an executable, or a
+        graph's); ``autotune`` carries the knob-tuner's
         hit/miss/measurement counters."""
         with self._lock:
             first_sticky = (repr(next(iter(self._sticky.values())))
@@ -1722,6 +1714,8 @@ class Dispatcher:
                 "dispatch_s": sum(r["dispatch_s"]
                                   for r in self._telemetry.values()),
                 "bytes": sum(r["bytes"] for r in self._telemetry.values()),
+                "stage_hits": self.stage_hits,
+                "stage_misses": self.stage_misses,
                 "autotune": _autotune_stats(),
             }
 

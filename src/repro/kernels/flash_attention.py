@@ -186,5 +186,6 @@ def flash_decode(q, k_cache, v_cache, kv_len, *, scale=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_decode",
     )(kv_len, qg, kt, vt)
     return out.reshape(H, D)

@@ -25,6 +25,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..configs import registry
 from ..configs.base import ShapeConfig
@@ -267,17 +268,23 @@ class BatchedServer:
         tok_mat = np.tile(self.tokens.astype(np.int32), (T, 1))
         tok_mat[:, slot] = np.asarray(prompt, np.int32)
         mask = self.active.astype(np.int32)
-        self.cache, pos = fn(self.params, self.cache, jnp.asarray(tok_mat),
-                             jnp.asarray(self.pos), jnp.asarray(mask))
-        self.pos = np.array(pos)        # writable host copy
+        with TraceAnnotation("serve.prefill.call"):
+            self.cache, pos = fn(self.params, self.cache,
+                                 jnp.asarray(tok_mat),
+                                 jnp.asarray(self.pos), jnp.asarray(mask))
+        with TraceAnnotation("serve.prefill.wait"):
+            self.pos = np.array(pos)    # writable host copy
         self.tokens[slot] = prompt[-1]
         return self
 
     def _step_all(self):
-        toks = jnp.asarray(self.tokens)
-        pos = jnp.asarray(self.pos)
-        nxt, self.cache = self.step_fn(self.params, self.cache, toks, pos)
-        nxt = np.asarray(nxt)
+        with TraceAnnotation("serve.step.call"):
+            toks = jnp.asarray(self.tokens)
+            pos = jnp.asarray(self.pos)
+            nxt, self.cache = self.step_fn(self.params, self.cache, toks,
+                                           pos)
+        with TraceAnnotation("serve.step.wait"):
+            nxt = np.asarray(nxt)
         for i in range(self.batch):
             if self.active[i]:
                 self.pos[i] += 1
@@ -288,18 +295,19 @@ class BatchedServer:
         for _ in range(max_tokens):
             nxt = self._step_all()
             was_active = self.active.copy()
-            for i in range(self.batch):
-                if not self.active[i]:
-                    continue
-                t = int(nxt[i])
-                self.outputs[i].append(t)
-                self.tokens[i] = t
-                if eos is not None and t == eos:
-                    self.active[i] = False
-                if len(self.outputs[i]) >= max_tokens:
-                    self.active[i] = False
-                if self.pos[i] >= self.ctx - 1:
-                    self.active[i] = False
+            with TraceAnnotation("serve.step.emit"):
+                for i in range(self.batch):
+                    if not self.active[i]:
+                        continue
+                    t = int(nxt[i])
+                    self.outputs[i].append(t)
+                    self.tokens[i] = t
+                    if eos is not None and t == eos:
+                        self.active[i] = False
+                    if len(self.outputs[i]) >= max_tokens:
+                        self.active[i] = False
+                    if self.pos[i] >= self.ctx - 1:
+                        self.active[i] = False
             for p in pipelines or ():
                 p.step(nxt, was_active)
             if not self.active.any():
