@@ -15,6 +15,7 @@ import functools
 import jax
 
 from . import flash_attention as _fa
+from . import moe_decode as _moe
 from . import norms as _norms
 from . import ref as _ref
 from . import softmax as _sm
@@ -83,6 +84,12 @@ def ssd_scan(x, a, bmat, cmat, chunk: int = 128, backend: str = "auto"):
     return _dispatch(backend, functools.partial(_ssd.ssd_scan, chunk=chunk),
                      functools.partial(_ref.ssd_scan_chunked, chunk=chunk),
                      x, a, bmat, cmat)
+
+
+def moe_decode_experts(layer, ids, x, weights, w_gate, w_up, w_down,
+                       backend: str = "auto"):
+    return _dispatch(backend, _moe.moe_decode_experts, _ref.moe_decode_experts,
+                     layer, ids, x, weights, w_gate, w_up, w_down)
 
 
 def topk_gate(logits, k: int):

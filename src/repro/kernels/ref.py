@@ -186,3 +186,23 @@ def topk_gate(logits, k: int):
 def swiglu(x, w_gate, w_up, w_down):
     h = jax.nn.silu(x @ w_gate) * (x @ w_up)
     return h @ w_down
+
+
+def moe_decode_experts(layer, ids, x, weights, w_gate, w_up, w_down):
+    """The chosen experts' SwiGLU, weighted and summed per token.
+    layer: (1,); ids, weights: (T·k,), token-major; x: (T, d); stacks
+    (L, E, d, f) and (L, E, f, d) -> (T, d) f32.  Only the chosen slabs
+    are gathered; the products round as the capacity path's do."""
+    T, d = x.shape
+    k = ids.shape[0] // T
+    sel = (layer.reshape(()), ids)
+    xs = jnp.repeat(x, k, axis=0)                          # (T·k, d)
+    h = jnp.einsum("nd,ndf->nf", xs, w_gate[sel])
+    u = jnp.einsum("nd,ndf->nf", xs, w_up[sel])
+    down = jnp.einsum("nf,nfd->nd", jax.nn.silu(h) * u,
+                      w_down[sel]).astype(jnp.float32)
+    contrib = (down * weights[:, None]).reshape(T, k, d)
+    y = jnp.zeros((T, d), jnp.float32)
+    for j in range(k):                  # choice order, as the kernel sums
+        y = y + contrib[:, j]
+    return y

@@ -270,7 +270,38 @@ def _moe_local(p, xt, *, cfg, C: int, e_lo, E_loc: int):
     return y
 
 
-def moe_apply(p, x, *, cfg, rules=None):
+EXPERT_STACKS = ("w_gate", "w_up", "w_down")
+
+
+def moe_reads_chosen(cfg, rules, n_tokens: int, backend: str) -> bool:
+    """Whether ``moe_apply`` reads only the chosen experts' weights
+    (``ops.moe_decode_experts``) for ``n_tokens`` tokens: every expert is
+    on the one device the step runs on (a Pallas call is not partitioned
+    over a mesh), the T·k choices leave some expert with none, the
+    capacity path could drop no token (a token takes at most one slot of
+    an expert, so T <= capacity), so both paths compute the same
+    function, and the backend runs Pallas kernels."""
+    return ((rules is None or rules.mesh.size == 1)
+            and n_tokens * cfg.top_k < cfg.n_experts
+            and n_tokens <= moe_capacity(cfg, n_tokens)
+            and ops.resolve(backend) in ("pallas", "interpret"))
+
+
+def _moe_chosen(p, xt, *, cfg, layer, backend):
+    """Token-choice top-k that reads each chosen expert's slabs once.
+    With ``layer``, p's expert weights are the whole (L, E, ...) stacks
+    and ``layer`` picks the slabs; without, p holds one layer's."""
+    logits = xt.astype(jnp.float32) @ p["router"]            # (T,E)
+    w, idx = ops.topk_gate(logits, cfg.top_k)                # (T,k)
+    stacks = [p[n] for n in EXPERT_STACKS]
+    if layer is None:
+        stacks, layer = [s[None] for s in stacks], 0
+    return ops.moe_decode_experts(
+        jnp.asarray(layer, jnp.int32).reshape(1), idx.reshape(-1), xt,
+        w.reshape(-1), *stacks, backend=backend)
+
+
+def moe_apply(p, x, *, cfg, rules=None, backend="auto", layer=None):
     """Capacity-based token-choice top-k MoE with Megatron-style expert
     parallelism: tokens stay sharded on the data axis (activations are
     replicated over 'model'), each model rank runs only its expert slice
@@ -278,22 +309,36 @@ def moe_apply(p, x, *, cfg, rules=None):
     No global scatter, no token all-to-all.  Tokens beyond per-slab
     expert capacity are dropped (capacity_factor controls head-room;
     smoke configs use a no-drop factor, tested against the dense-dispatch
-    oracle)."""
-    B, S, d = x.shape
-    E, k = cfg.n_experts, cfg.top_k
+    oracle).
 
-    if rules is None or "model" not in rules.mesh.shape:
+    Where ``moe_reads_chosen`` holds (decode: a few tokens, experts all
+    local), the same function reads only the chosen experts' weights.
+    A caller that passes ``layer`` has checked that and passes the whole
+    expert stacks in p (see ``lm.decode_step``)."""
+    B, S, d = x.shape
+    E = cfg.n_experts
+    xt = x.reshape(B * S, d)
+
+    if layer is not None or moe_reads_chosen(cfg, rules, B * S, backend):
+        y = _moe_chosen(p, xt, cfg=cfg, layer=layer, backend=backend)
+    elif rules is None or "model" not in rules.mesh.shape:
         # single-device / test path: all experts local
-        xt = x.reshape(B * S, d)
         C = moe_capacity(cfg, B * S)
         y = _moe_local(p, xt, cfg=cfg, C=C, e_lo=jnp.int32(0), E_loc=E)
-        out = y.astype(x.dtype)
-        if cfg.n_shared:
-            out = out + (jax.nn.silu(xt @ p["s_gate"]) *
-                         (xt @ p["s_up"])) @ p["s_down"]
-        return constrain(out.reshape(B, S, d), rules,
-                         ("batch", None, "embed"))
+    else:
+        y = _moe_expert_parallel(p, x, cfg=cfg, rules=rules)
+    out = y.astype(x.dtype).reshape(B, S, d)
+    if cfg.n_shared:
+        sh = (jax.nn.silu(xt @ p["s_gate"]) * (xt @ p["s_up"])) @ p["s_down"]
+        out = out + sh.reshape(B, S, d)
+    return constrain(out, rules, ("batch", None, "embed"))
 
+
+def _moe_expert_parallel(p, x, *, cfg, rules):
+    """Each model rank's expert slice on its data slab, psum over
+    'model': (B, S, d) in x's dtype."""
+    B, S, d = x.shape
+    E = cfg.n_experts
     mesh = rules.mesh
     tp = mesh.shape["model"]
     assert E % tp == 0, "experts must divide the model axis"
@@ -318,18 +363,11 @@ def moe_apply(p, x, *, cfg, rules=None):
         y = jax.lax.psum(y, "model")
         return y.reshape(Bl, Sl, d).astype(xb.dtype)
 
-    y = jax.shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, router_spec, ew_spec, ew_spec, ew_spec),
         out_specs=x_spec, check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
-
-    out = y
-    if cfg.n_shared:
-        xt = x.reshape(B * S, d)
-        sh = (jax.nn.silu(xt @ p["s_gate"]) * (xt @ p["s_up"])) @ p["s_down"]
-        out = out + sh.reshape(B, S, d)
-    return constrain(out, rules, ("batch", None, "embed"))
 
 
 def moe_apply_dense(p, x, *, cfg, rules=None):

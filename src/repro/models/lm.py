@@ -87,7 +87,7 @@ def _dense_layer_apply(cfg, rules, window, backend, lp, x, positions):
     x = x + h
     h = L.apply_norm(lp["ln2"], x, cfg.norm, lp.get("ln2_b"), backend)
     if cfg.family == "moe":
-        h = L.moe_apply(lp["moe"], h, cfg=cfg, rules=rules)
+        h = L.moe_apply(lp["moe"], h, cfg=cfg, rules=rules, backend=backend)
     else:
         h = L.mlp_apply(lp["mlp"], h, cfg=cfg, rules=rules)
     return x + h
@@ -229,7 +229,17 @@ def decode_step(cfg, params, cache, tokens, pos, *, rules=None,
     x = L.constrain(x, rules, ("batch", "embed"))
 
     if cfg.family in ("dense", "moe", "vlm"):
-        def body(h, inp):
+        layers, stacks = params["layers"], None
+        if cfg.family == "moe" and L.moe_reads_chosen(cfg, rules, x.shape[0],
+                                                      backend):
+            # the expert stacks stay out of the scanned layers, whole: the
+            # kernel reads layer i's chosen slabs straight from them
+            moe = dict(layers["moe"])
+            stacks = {n: moe.pop(n) for n in L.EXPERT_STACKS}
+            layers = dict(layers, moe=moe)
+
+        def body(carry, inp):
+            h, i = carry
             lp, kc, vc = inp
             hn = L.apply_norm(lp["ln1"], h, cfg.norm, lp.get("ln1_b"), backend)
             y, newkv = L.attention_decode(lp["attn"], hn, {"k": kc, "v": vc},
@@ -238,15 +248,16 @@ def decode_step(cfg, params, cache, tokens, pos, *, rules=None,
             h = h + y
             hn = L.apply_norm(lp["ln2"], h, cfg.norm, lp.get("ln2_b"), backend)
             if cfg.family == "moe":
-                y = L.moe_apply(lp["moe"], hn[:, None], cfg=cfg,
-                                rules=rules)[:, 0]
+                y = L.moe_apply(dict(lp["moe"], **(stacks or {})), hn[:, None],
+                                cfg=cfg, rules=rules, backend=backend,
+                                layer=None if stacks is None else i)[:, 0]
             else:
                 y = L.mlp_apply(lp["mlp"], hn[:, None], cfg=cfg,
                                 rules=rules)[:, 0]
-            return h + y, (newkv["k"], newkv["v"])
+            return (h + y, i + 1), (newkv["k"], newkv["v"])
 
-        h, (ks, vs) = lax.scan(body, x, (params["layers"], cache["k"],
-                                         cache["v"]))
+        (h, _), (ks, vs) = lax.scan(body, (x, jnp.int32(0)),
+                                    (layers, cache["k"], cache["v"]))
         new_cache = {"k": ks, "v": vs}
 
     elif cfg.family == "ssm":

@@ -13,6 +13,7 @@ the module is imported: only one process at a time may load the TPU
 library, and every test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,9 +23,12 @@ from jax.sharding import PartitionSpec as P
 
 from benchmarks.kernels_suite import all_kernels
 from repro.core.runtime import build_traceable
+from repro.configs import registry
 from repro.kernels import flash_attention as fa
-from repro.kernels import norms, softmax, ssd_scan
+from repro.kernels import moe_decode, norms, softmax, ssd_scan
 from repro.launch.mesh import make_mesh
+from repro.models import lm
+from repro.parallel import steps
 
 # granite-moe-1b-a400m attention widths; mamba2-130m SSD widths
 GRANITE = dict(S=2048, H=16, Hkv=8, D=64, d=1024)
@@ -92,6 +96,53 @@ def test_flash_decode_compiles(one_chip, batch):
     n = _sds(one_chip, lead, jnp.int32)
     fn = jax.vmap(fa.flash_decode) if batch else fa.flash_decode
     assert "tpu_custom_call" in _compile_text(fn, q, kv, kv, n)
+
+
+# (layers, experts, d, f, top-k, tokens): granite-moe-1b-a400m at batch 1
+# and 3; deepseek-moe-16b, whose expert slabs are sliced along f to fit
+# VMEM (two layers: its whole stack would not fit one chip's HBM)
+@pytest.mark.parametrize("L,E,d,f,k,tokens", [
+    (24, 32, 1024, 512, 8, 1), (24, 32, 1024, 512, 8, 3),
+    (2, 64, 2048, 1408, 6, 1)])
+def test_moe_decode_experts_compiles(one_chip, L, E, d, f, k, tokens):
+    n = tokens * k
+    args = (_sds(one_chip, (1,), jnp.int32), _sds(one_chip, (n,), jnp.int32),
+            _sds(one_chip, (tokens, d), jnp.bfloat16),
+            _sds(one_chip, (n,), jnp.float32),
+            _sds(one_chip, (L, E, d, f), jnp.bfloat16),
+            _sds(one_chip, (L, E, d, f), jnp.bfloat16),
+            _sds(one_chip, (L, E, f, d), jnp.bfloat16))
+    text = _compile_text(moe_decode.moe_decode_experts, *args)
+    assert "moe_decode_experts" in text
+
+
+def test_serve_step_reads_only_the_chosen_experts(topo):
+    """The granite-moe-1b-a400m serve step at batch 1, as the server
+    builds it on one chip: each of the 24 layers' MoE is the kernel, fed
+    the whole (24, 32, ...) expert stacks, and nothing in the step holds
+    one layer's 32 experts (a slice of the stack the kernel cannot fuse
+    would copy them before every call)."""
+    cfg = registry.get("granite-moe-1b-a400m")
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    step, bundle = steps.make_serve_step(cfg, mesh, backend="pallas")
+    rules = bundle["rules"]
+
+    def shapes(specs, shardings):
+        return jax.tree_util.tree_map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            specs, shardings, is_leaf=lambda s: hasattr(s, "abstract"))
+
+    cache = lm.cache_specs(cfg, 1, 512)
+    tok = jax.ShapeDtypeStruct((1,), jnp.int32,
+                               sharding=NamedSharding(mesh, P()))
+    text = _compile_text(step, shapes(bundle["specs"], bundle["param_sh"]),
+                         shapes(cache, rules.tree_shardings(cache)), tok, tok)
+    calls = [line for line in text.splitlines()
+             if "moe_decode_experts" in line and "custom-call(" in line]
+    assert calls
+    for line in calls:
+        assert "bf16[24,32,1024,512]" in line and "bf16[24,32,512,1024]" in line
+    assert not re.search(r"bf16\[(1,)?32,(1024,512|512,1024)\]", text)
 
 
 def test_ssd_scan_compiles(one_chip):
