@@ -12,6 +12,12 @@ wide for VMEM is read in slices of its width f.  The stacks are passed
 unsliced: a custom call cannot fuse a dynamic-slice into its operand, so a
 per-layer slice would copy all E experts of the layer first.
 
+A chip that holds a share of the experts (``held`` given) walks every
+choice all the same; a choice held elsewhere is weighted 0, and its grid
+steps repeat the block index of the step before (the last held choice's
+last slice, or the first held choice's first slice where none came
+before), so Pallas fetches nothing for it.
+
 Rounding follows the capacity path: the gate and up products and
 ``silu(h) * u`` are rounded to the activation dtype, the down product
 (summed over the slices in f32) is rounded to it too, then weighted and
@@ -43,8 +49,8 @@ def f_tile(d: int, f: int, itemsize: int) -> int:
     return max(fits) if fits else f
 
 
-def _kernel(layer_ref, ids_ref, x_ref, w_ref, wg_ref, wu_ref, wd_ref, y_ref,
-            acc_ref, *, k: int):
+def _kernel(*refs, k: int):
+    x_ref, w_ref, wg_ref, wu_ref, wd_ref, y_ref, acc_ref = refs[-7:]
     j, c = pl.program_id(0), pl.program_id(1)
 
     @pl.when((j == 0) & (c == 0))
@@ -71,13 +77,28 @@ def _kernel(layer_ref, ids_ref, x_ref, w_ref, wg_ref, wu_ref, wd_ref, y_ref,
         y_ref[...] += jnp.where(row == j // k, w_ref[j] * down, 0.0)
 
 
-def moe_decode_experts(layer, ids, x, weights, w_gate, w_up, w_down, *,
-                       interpret: bool = False):
+def _held_fetches(ids, held, slices: int):
+    """For a share: the expert each choice's steps fetch, and the slice a
+    choice held elsewhere keeps (-1 for a held choice, which walks every
+    slice), so that its steps repeat the block index of the step before."""
+    j = jnp.arange(ids.shape[0])
+    last = jax.lax.cummax(jnp.where(held, j, -1))    # last held choice <= j
+    first = jnp.argmax(held)
+    fetch = ids[jnp.where(last >= 0, last, first)]
+    keep = jnp.where(held, -1, jnp.where(last >= 0, slices - 1, 0))
+    return fetch.astype(jnp.int32), keep.astype(jnp.int32)
+
+
+def moe_decode_experts(layer, ids, x, weights, w_gate, w_up, w_down,
+                       held=None, *, interpret: bool = False):
     """layer: (1,) int32; ids: (T·k,) int32, token-major; x: (T, d);
     weights: (T·k,) f32 combine weights; w_gate, w_up: (L, E, d, f);
     w_down: (L, E, f, d) -> y: (T, d) f32, the weighted sum of each
     token's chosen experts' SwiGLU outputs.  The grid walks the choices,
-    and within each the expert width in slices of ``f_tile``."""
+    and within each the expert width in slices of ``f_tile``.  ``held``
+    (T·k,) bool, where this chip holds a share of the experts, marks the
+    choices whose expert is among the stacks' E; the others add nothing
+    and fetch nothing."""
     T, d = x.shape
     n = ids.shape[0]
     f = w_gate.shape[-1]
@@ -85,20 +106,28 @@ def moe_decode_experts(layer, ids, x, weights, w_gate, w_up, w_down, *,
     rows = round_up(T, ROWS)
     xp = jnp.zeros((rows, d), dt).at[:T].set(x.astype(dt))
     tf = f_tile(d, f, w_gate.dtype.itemsize)
+    scalars = (layer, ids)
+    if held is not None:
+        weights = jnp.where(held, weights, 0.0)
+        scalars = (layer, *_held_fetches(ids, held, f // tf))
 
-    def gate_up(j, c, layer_ref, ids_ref):
-        return (layer_ref[0], ids_ref[j], 0, c)
+    def part(j, c, keep):
+        # a choice held elsewhere keeps the slice of the step before it
+        return c if keep is None else jnp.where(keep[j] < 0, c, keep[j])
 
-    def down(j, c, layer_ref, ids_ref):
-        return (layer_ref[0], ids_ref[j], c, 0)
+    def gate_up(j, c, layer_ref, ids_ref, keep=None):
+        return (layer_ref[0], ids_ref[j], 0, part(j, c, keep))
 
-    def whole(j, c, layer_ref, ids_ref):
+    def down(j, c, layer_ref, ids_ref, keep=None):
+        return (layer_ref[0], ids_ref[j], part(j, c, keep), 0)
+
+    def whole(j, c, *refs):
         return (0, 0)
 
     y = pl.pallas_call(
         functools.partial(_kernel, k=n // T),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(scalars),
             grid=(n, f // tf),
             in_specs=[
                 pl.BlockSpec((rows, d), whole),
@@ -114,5 +143,5 @@ def moe_decode_experts(layer, ids, x, weights, w_gate, w_up, w_down, *,
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="moe_decode_experts",
-    )(layer, ids, xp, weights, w_gate, w_up, w_down)
+    )(*scalars, xp, weights, w_gate, w_up, w_down)
     return y[:T]

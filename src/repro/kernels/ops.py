@@ -19,6 +19,7 @@ from . import moe_decode as _moe
 from . import norms as _norms
 from . import ref as _ref
 from . import softmax as _sm
+from . import ssd_decode as _ssd_dec
 from . import ssd_scan as _ssd
 from . import warp_reduce as _wr
 
@@ -86,10 +87,16 @@ def ssd_scan(x, a, bmat, cmat, chunk: int = 128, backend: str = "auto"):
                      x, a, bmat, cmat)
 
 
+def ssd_decode(h, layer, fresh, x, dt, a, b, c, d, backend: str = "auto"):
+    return _dispatch(backend, _ssd_dec.ssd_decode, _ref.ssd_decode,
+                     h, layer, fresh, x, dt, a, b, c, d)
+
+
 def moe_decode_experts(layer, ids, x, weights, w_gate, w_up, w_down,
-                       backend: str = "auto"):
+                       held=None, backend: str = "auto"):
+    share = () if held is None else (held,)
     return _dispatch(backend, _moe.moe_decode_experts, _ref.moe_decode_experts,
-                     layer, ids, x, weights, w_gate, w_up, w_down)
+                     layer, ids, x, weights, w_gate, w_up, w_down, *share)
 
 
 def topk_gate(logits, k: int):

@@ -188,11 +188,15 @@ def swiglu(x, w_gate, w_up, w_down):
     return h @ w_down
 
 
-def moe_decode_experts(layer, ids, x, weights, w_gate, w_up, w_down):
+def moe_decode_experts(layer, ids, x, weights, w_gate, w_up, w_down,
+                       held=None):
     """The chosen experts' SwiGLU, weighted and summed per token.
     layer: (1,); ids, weights: (T·k,), token-major; x: (T, d); stacks
     (L, E, d, f) and (L, E, f, d) -> (T, d) f32.  Only the chosen slabs
-    are gathered; the products round as the capacity path's do."""
+    are gathered; the products round as the capacity path's do.
+    ``held`` (T·k,) bool weights the choices held elsewhere 0."""
+    if held is not None:
+        weights = jnp.where(held, weights, 0.0)
     T, d = x.shape
     k = ids.shape[0] // T
     sel = (layer.reshape(()), ids)
@@ -206,3 +210,22 @@ def moe_decode_experts(layer, ids, x, weights, w_gate, w_up, w_down):
     for j in range(k):                  # choice order, as the kernel sums
         y = y + contrib[:, j]
     return y
+
+
+def ssd_decode(h, layer, fresh, x, dt, a, b, c, d):
+    """Mamba2's one-token state update of one layer, every head: h
+    (L, B, H, P, N) f32 state stack; layer (1,); fresh (B,) bool, the
+    sequences that start from the zero state; x (B, H, P); dt (B, H);
+    a, d (H,); b, c (B, N) -> (y (B, H, P) f32, the stack with layer
+    ``layer`` replaced by h' = h·exp(a·dt) + (x·dt) ⊗ b), where
+    y = h'·c + d·x."""
+    i = layer.reshape(())
+    state = jnp.where(fresh[:, None, None, None], 0.0, h[i])
+    dt = dt.astype(jnp.float32)
+    decay = jnp.exp(a * dt)                                   # (B, H)
+    x = x.astype(jnp.float32)
+    b, c = b.astype(jnp.float32), c.astype(jnp.float32)
+    state = state * decay[..., None, None] + \
+        jnp.einsum("bhp,bn->bhpn", x * dt[..., None], b)
+    y = jnp.einsum("bhpn,bn->bhp", state, c) + x * d[None, :, None]
+    return y, h.at[i].set(state)

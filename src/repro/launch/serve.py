@@ -230,6 +230,10 @@ class BatchedServer:
         self.tokens = np.zeros((self.batch,), np.int32)
         self.active = np.zeros((self.batch,), bool)
         self.outputs: List[List[int]] = [[] for _ in range(self.batch)]
+        # the decode step dispatched ahead of its turn, and the decode
+        # call's token limit while it may dispatch ahead (see _step_all)
+        self._ahead = None
+        self._until: Optional[int] = None
 
     def _build_prefill(self, T: int):
         """One jitted program for a whole T-token prompt: ``lax.scan``
@@ -277,21 +281,45 @@ class BatchedServer:
         self.tokens[slot] = prompt[-1]
         return self
 
-    def _step_all(self):
+    def _call(self, tokens):
+        """Dispatch one decode step of every slot at ``self.pos`` (host or
+        device tokens); its tokens, still on the device.  Host arrays go
+        in as copies: the device may read them after the loop has moved
+        on and changed ``self.pos`` and ``self.tokens`` in place."""
         with TraceAnnotation("serve.step.call"):
-            toks = jnp.asarray(self.tokens)
-            pos = jnp.asarray(self.pos)
+            toks = jnp.asarray(np.array(tokens) if isinstance(tokens, np.ndarray)
+                               else tokens)
+            pos = jnp.asarray(self.pos.copy())
             nxt, self.cache = self.step_fn(self.params, self.cache, toks,
                                            pos)
-        with TraceAnnotation("serve.step.wait"):
-            nxt = np.asarray(nxt)
-        for i in range(self.batch):
-            if self.active[i]:
-                self.pos[i] += 1
         return nxt
+
+    def _step_all(self):
+        """One decode step of every slot; its tokens, on the host.  Inside
+        ``decode`` without an ``eos`` (``self._until`` set), which slots
+        the step leaves active follows from counts and positions alone, so
+        the step after it is dispatched first, fed these tokens on the
+        device: the chip runs it while the host emits and runs the
+        pipelines.  The chip computes the same steps on the same inputs
+        either way."""
+        nxt = self._ahead if self._ahead is not None else self._call(self.tokens)
+        self._ahead = None
+        was_active = self.active.copy()
+        self.pos += was_active
+        if self._until is not None:
+            count = np.array([len(o) for o in self.outputs])
+            stays = (was_active & (count + 1 < self._until)
+                     & (self.pos < self.ctx - 1))
+            if stays.any():
+                self._ahead = self._call(
+                    nxt if was_active.all()
+                    else jnp.where(was_active, nxt, self.tokens.copy()))
+        with TraceAnnotation("serve.step.wait"):
+            return np.asarray(nxt)
 
     def decode(self, max_tokens: int, eos: Optional[int] = None,
                pipelines: Optional[List["TokenPipeline"]] = None):
+        self._until = max_tokens if eos is None else None
         for _ in range(max_tokens):
             nxt = self._step_all()
             was_active = self.active.copy()
@@ -312,6 +340,7 @@ class BatchedServer:
                 p.step(nxt, was_active)
             if not self.active.any():
                 break
+        self._until = None
         return self.outputs
 
 

@@ -61,15 +61,16 @@ def norm_spec(cfg) -> ParamSpec:
     return ParamSpec((cfg.d_model,), jnp.float32, ("embed",), init="ones")
 
 
-def apply_norm(w, x, kind: str = "rms", b=None, backend: str = "auto"):
+def apply_norm(w, x, kind: str = "rms", b=None, backend: str = "auto",
+               eps: float = 1e-6):
     if kind == "rms":
-        return ops.rmsnorm(x, w, backend=backend)
+        return ops.rmsnorm(x, w, eps=eps, backend=backend)
     return ops.layernorm(x, w, b if b is not None else jnp.zeros_like(w),
-                         backend=backend)
+                         eps=eps, backend=backend)
 
 
 # ---------------------------------------------------------------------------
-# attention (GQA, rope, optional window) — train/prefill and decode paths
+# attention (GQA, rope or none, optional window) — train/prefill and decode
 # ---------------------------------------------------------------------------
 
 
@@ -120,8 +121,9 @@ def attention_apply(p, x, positions, *, cfg, rules=None, causal=True,
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
-    q = rope(q, positions)
-    k = rope(k, positions)
+    if cfg.rope:
+        q = rope(q, positions)
+        k = rope(k, positions)
     q = constrain(q, rules, ("batch", None, "heads", None))
     k = constrain(k, rules, ("batch", None, "kv_heads", None))
     att = jax.vmap(lambda qq, kk, vv: ops.attention(
@@ -148,13 +150,14 @@ def attention_decode(p, x, cache, pos, *, cfg, rules=None, backend="auto",
     v = jnp.einsum("bd,dhk->bhk", x, p["wv"])
     if "bq" in p:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    # rope wants (..., S, H, D): add a singleton S axis
-    qr = rope(q[:, None], pos[:, None])[:, 0]
-    kr = rope(k[:, None], pos[:, None])[:, 0]
-    kc = _scatter_token(cache["k"], kr, slot)
+    if cfg.rope:
+        # rope wants (..., S, H, D): add a singleton S axis
+        q = rope(q[:, None], pos[:, None])[:, 0]
+        k = rope(k[:, None], pos[:, None])[:, 0]
+    kc = _scatter_token(cache["k"], k, slot)
     vc = _scatter_token(cache["v"], v, slot)
     out = jax.vmap(lambda qq, kk, vv, ln: ops.decode_attention(
-        qq, kk, vv, ln, backend=backend))(qr, kc, vc, kv_len)
+        qq, kk, vv, ln, backend=backend))(q, kc, vc, kv_len)
     hm = _head_mask(cfg)
     if hm is not None:
         out = out * hm[None, :, None].astype(out.dtype)
@@ -204,17 +207,19 @@ def mlp_apply(p, x, *, cfg, rules=None):
 
 
 def moe_specs(cfg) -> Dict[str, ParamSpec]:
+    """The router over all n_experts, and the held experts' weights."""
     d, fe = cfg.d_model, cfg.d_expert or cfg.d_ff
     E = cfg.n_experts
+    _, held = cfg.held_experts()
     dt = cfg.param_dtype
     sp = {
         "router": ParamSpec((d, E), jnp.float32, ("embed", None)),
-        "w_gate": ParamSpec((E, d, fe), dt, ("experts", "embed", None)),
-        "w_up": ParamSpec((E, d, fe), dt, ("experts", "embed", None)),
-        "w_down": ParamSpec((E, fe, d), dt, ("experts", None, "embed")),
+        "w_gate": ParamSpec((held, d, fe), dt, ("experts", "embed", None)),
+        "w_up": ParamSpec((held, d, fe), dt, ("experts", "embed", None)),
+        "w_down": ParamSpec((held, fe, d), dt, ("experts", None, "embed")),
     }
     if cfg.n_shared:
-        fs = fe * cfg.n_shared
+        fs = cfg.shared_width()
         sp.update({
             "s_gate": ParamSpec((d, fs), dt, ("embed", "mlp")),
             "s_up": ParamSpec((d, fs), dt, ("embed", "mlp")),
@@ -230,7 +235,8 @@ def moe_capacity(cfg, n_tokens: int) -> int:
 def _moe_local(p, xt, *, cfg, C: int, e_lo, E_loc: int):
     """Token-choice top-k over a LOCAL token slab, computing only the
     expert slice [e_lo, e_lo+E_loc) whose weights this rank holds.
-    Returns the (partial) combined output — summed over ranks outside.
+    Returns the (partial) combined output — summed over ranks outside,
+    or, for a chip's share of a deployment, passed on as it is.
     GShard positions: per-choice running cumsum, no sort."""
     T, d = xt.shape
     E, k = cfg.n_experts, cfg.top_k
@@ -275,9 +281,11 @@ EXPERT_STACKS = ("w_gate", "w_up", "w_down")
 
 def moe_reads_chosen(cfg, rules, n_tokens: int, backend: str) -> bool:
     """Whether ``moe_apply`` reads only the chosen experts' weights
-    (``ops.moe_decode_experts``) for ``n_tokens`` tokens: every expert is
-    on the one device the step runs on (a Pallas call is not partitioned
-    over a mesh), the T·k choices leave some expert with none, the
+    (``ops.moe_decode_experts``) for ``n_tokens`` tokens: the experts the
+    chip holds are on the one device the step runs on (a Pallas call is
+    not partitioned over a mesh), the T·k choices leave some expert with
+    none (for a share, the T·k·held/E choices expected on the held
+    experts leave some held expert with none: the same test), the
     capacity path could drop no token (a token takes at most one slot of
     an expert, so T <= capacity), so both paths compute the same
     function, and the backend runs Pallas kernels."""
@@ -290,15 +298,23 @@ def moe_reads_chosen(cfg, rules, n_tokens: int, backend: str) -> bool:
 def _moe_chosen(p, xt, *, cfg, layer, backend):
     """Token-choice top-k that reads each chosen expert's slabs once.
     With ``layer``, p's expert weights are the whole (L, E, ...) stacks
-    and ``layer`` picks the slabs; without, p holds one layer's."""
+    and ``layer`` picks the slabs; without, p holds one layer's.  A chip
+    holding a share of the experts routes over all of them and adds what
+    its own give."""
     logits = xt.astype(jnp.float32) @ p["router"]            # (T,E)
     w, idx = ops.topk_gate(logits, cfg.top_k)                # (T,k)
     stacks = [p[n] for n in EXPERT_STACKS]
     if layer is None:
         stacks, layer = [s[None] for s in stacks], 0
+    ids, held = idx.reshape(-1), None
+    lo, n_held = cfg.held_experts()
+    if n_held < cfg.n_experts:
+        ids = ids - lo
+        held = (ids >= 0) & (ids < n_held)
+        ids = jnp.clip(ids, 0, n_held - 1)
     return ops.moe_decode_experts(
-        jnp.asarray(layer, jnp.int32).reshape(1), idx.reshape(-1), xt,
-        w.reshape(-1), *stacks, backend=backend)
+        jnp.asarray(layer, jnp.int32).reshape(1), ids, xt,
+        w.reshape(-1), *stacks, held=held, backend=backend)
 
 
 def moe_apply(p, x, *, cfg, rules=None, backend="auto", layer=None):
@@ -306,6 +322,9 @@ def moe_apply(p, x, *, cfg, rules=None, backend="auto", layer=None):
     parallelism: tokens stay sharded on the data axis (activations are
     replicated over 'model'), each model rank runs only its expert slice
     on its data slab, and one psum over 'model' combines partial outputs.
+    A chip that holds a share of the experts (``cfg.held_experts``)
+    routes over all of them and computes its own experts' part, with no
+    exchange where it has no mesh.
     No global scatter, no token all-to-all.  Tokens beyond per-slab
     expert capacity are dropped (capacity_factor controls head-room;
     smoke configs use a no-drop factor, tested against the dense-dispatch
@@ -322,9 +341,10 @@ def moe_apply(p, x, *, cfg, rules=None, backend="auto", layer=None):
     if layer is not None or moe_reads_chosen(cfg, rules, B * S, backend):
         y = _moe_chosen(p, xt, cfg=cfg, layer=layer, backend=backend)
     elif rules is None or "model" not in rules.mesh.shape:
-        # single-device / test path: all experts local
+        # single-device / test path: the held experts are all local
         C = moe_capacity(cfg, B * S)
-        y = _moe_local(p, xt, cfg=cfg, C=C, e_lo=jnp.int32(0), E_loc=E)
+        lo, n_held = cfg.held_experts()
+        y = _moe_local(p, xt, cfg=cfg, C=C, e_lo=jnp.int32(lo), E_loc=n_held)
     else:
         y = _moe_expert_parallel(p, x, cfg=cfg, rules=rules)
     out = y.astype(x.dtype).reshape(B, S, d)
@@ -338,11 +358,11 @@ def _moe_expert_parallel(p, x, *, cfg, rules):
     """Each model rank's expert slice on its data slab, psum over
     'model': (B, S, d) in x's dtype."""
     B, S, d = x.shape
-    E = cfg.n_experts
+    lo, n_held = cfg.held_experts()
     mesh = rules.mesh
     tp = mesh.shape["model"]
-    assert E % tp == 0, "experts must divide the model axis"
-    E_loc = E // tp
+    assert n_held % tp == 0, "experts must divide the model axis"
+    E_loc = n_held // tp
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
     dp = 1
     for a in batch_axes:
@@ -359,7 +379,8 @@ def _moe_expert_parallel(p, x, *, cfg, rules):
         C = moe_capacity(cfg, Bl * Sl)
         rank = jax.lax.axis_index("model")
         pl = {"router": router, "w_gate": wg, "w_up": wu, "w_down": wd}
-        y = _moe_local(pl, xt, cfg=cfg, C=C, e_lo=rank * E_loc, E_loc=E_loc)
+        y = _moe_local(pl, xt, cfg=cfg, C=C, e_lo=lo + rank * E_loc,
+                       E_loc=E_loc)
         y = jax.lax.psum(y, "model")
         return y.reshape(Bl, Sl, d).astype(xb.dtype)
 
@@ -375,12 +396,13 @@ def moe_apply_dense(p, x, *, cfg, rules=None):
     validate the capacity path on small shapes."""
     B, S, d = x.shape
     E, k = cfg.n_experts, cfg.top_k
+    lo, n_held = cfg.held_experts()
     logits = (x.astype(jnp.float32) @ p["router"])
     w, idx = ops.topk_gate(logits.reshape(-1, E), k)
     T = B * S
     xt = x.reshape(T, d)
     onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)
-    combine = (w[..., None] * onehot).sum(1)                 # (T,E)
+    combine = (w[..., None] * onehot).sum(1)[:, lo:lo + n_held]  # (T,held)
     h = jnp.einsum("td,edf->tef", xt, p["w_gate"])
     u = jnp.einsum("td,edf->tef", xt, p["w_up"])
     h = jax.nn.silu(h) * u
@@ -401,7 +423,7 @@ def mamba2_specs(cfg) -> Dict[str, ParamSpec]:
     di = cfg.ssm_inner            # 2*d typically
     H, P, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
     dt = cfg.param_dtype
-    return {
+    sp = {
         # in_proj → [z (gate), x, B, C, dt]
         "w_in": ParamSpec((d, 2 * di + 2 * N + H), dt,
                           ("embed", "ssm_inner")),
@@ -412,6 +434,10 @@ def mamba2_specs(cfg) -> Dict[str, ParamSpec]:
         "norm": ParamSpec((di,), jnp.float32, ("ssm_inner",), init="ones"),
         "w_out": ParamSpec((di, d), dt, ("ssm_inner", "embed")),
     }
+    if cfg.conv_bias:
+        sp["conv_b"] = ParamSpec((di + 2 * N,), dt, ("ssm_inner",),
+                                 init="zeros")
+    return sp
 
 
 def _mamba_split(cfg, proj):
@@ -422,9 +448,10 @@ def _mamba_split(cfg, proj):
     return z, xBC, dt
 
 
-def _causal_conv(xBC, conv, state=None):
-    """Depthwise causal conv along S. xBC: (B,S,C); conv: (K,C).
-    If state (B,K-1,C) given, runs in streaming mode, returns new state."""
+def _causal_conv(xBC, conv, state=None, bias=None):
+    """Depthwise causal conv along S, then SiLU. xBC: (B,S,C); conv:
+    (K,C); bias (C,) or None.  If state (B,K-1,C) given, runs in
+    streaming mode, returns new state."""
     K = conv.shape[0]
     if state is None:
         pad = jnp.zeros_like(xBC[:, :K - 1])
@@ -432,6 +459,8 @@ def _causal_conv(xBC, conv, state=None):
     else:
         xp = jnp.concatenate([state.astype(xBC.dtype), xBC], axis=1)
     out = sum(xp[:, i:i + xBC.shape[1]] * conv[i] for i in range(K))
+    if bias is not None:
+        out = out + bias
     new_state = xp[:, -(K - 1):] if K > 1 else None
     return jax.nn.silu(out), new_state
 
@@ -442,7 +471,7 @@ def mamba2_apply(p, x, *, cfg, rules=None, backend="auto"):
     di, N, H, P = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
     proj = x @ p["w_in"]
     z, xBC, dtp = _mamba_split(cfg, proj)
-    xBC, _ = _causal_conv(xBC, p["conv"])
+    xBC, _ = _causal_conv(xBC, p["conv"], bias=p.get("conv_b"))
     xs = xBC[..., :di]
     Bm = xBC[..., di:di + N].astype(jnp.float32)
     Cm = xBC[..., di + N:].astype(jnp.float32)
@@ -454,36 +483,44 @@ def mamba2_apply(p, x, *, cfg, rules=None, backend="auto"):
     chunk = min(cfg.ssd_chunk, S)
     y = jax.vmap(lambda xx, aa, bb, cc: ops.ssd_scan(
         xx, aa, bb, cc, chunk=chunk, backend=backend))(xh, a, Bm, Cm)
-    y = y + xh * p["D"][None, None, :, None]
+    # the skip term multiplies the input before its dt scaling
+    y = y + xs.reshape(B, S, H, P).astype(jnp.float32) * \
+        p["D"][None, None, :, None]
     y = y.reshape(B, S, di).astype(x.dtype)
     y = y * jax.nn.silu(z)
-    y = ops.rmsnorm(y, p["norm"], backend=backend)
+    y = ops.rmsnorm(y, p["norm"], eps=cfg.norm_eps, backend=backend)
     out = y @ p["w_out"]
     return constrain(out, rules, ("batch", None, "embed"))
 
 
-def mamba2_decode(p, x, state, *, cfg, backend="auto"):
-    """One-token recurrent step.  x: (B, d);
-    state: {"h": (B,H,N,P) f32, "conv": (B,K-1,C)}."""
+def mamba2_decode(p, x, state, *, cfg, fresh, backend="auto", layer=None):
+    """One-token recurrent step.  x: (B, d); state: {"h": (B,H,P,N) f32,
+    "conv": (B,K-1,C)}, or with ``layer``, "h" the (L,B,H,P,N) stack of a
+    run of layers, updated in place at ``layer``; fresh: (B,) bool, the
+    sequences that start here, from the zero state."""
     B, d = x.shape
     di, N, H, P = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
+    hs, conv0 = state["h"], state["conv"]
+    one = layer is None
+    if one:
+        hs, layer = hs[None], 0
+    conv0 = jnp.where(fresh[:, None, None], 0, conv0).astype(conv0.dtype)
     proj = x @ p["w_in"]
     z, xBC, dtp = _mamba_split(cfg, proj[:, None])
-    xBC, conv_state = _causal_conv(xBC, p["conv"], state["conv"])
+    xBC, conv_state = _causal_conv(xBC, p["conv"], conv0, p.get("conv_b"))
     z, xBC, dtp = z[:, 0], xBC[:, 0], dtp[:, 0]
     xs = xBC[..., :di]
-    Bm = xBC[..., di:di + N].astype(jnp.float32)
-    Cm = xBC[..., di + N:].astype(jnp.float32)
+    Bm = xBC[..., di:di + N]
+    Cm = xBC[..., di + N:]
     dt = jax.nn.softplus(dtp.astype(jnp.float32) + p["dt_bias"])  # (B,H)
-    A = -jnp.exp(p["A_log"])
-    decay = jnp.exp(A * dt)                                       # (B,H)
-    xh = xs.reshape(B, H, P).astype(jnp.float32) * dt[..., None]
-    h = state["h"] * decay[..., None, None] + \
-        jnp.einsum("bn,bhp->bhnp", Bm, xh)
-    y = jnp.einsum("bn,bhnp->bhp", Cm, h) + xh * p["D"][None, :, None]
+    y, hs = ops.ssd_decode(hs, jnp.asarray(layer, jnp.int32).reshape(1),
+                           fresh, xs.reshape(B, H, P), dt,
+                           -jnp.exp(p["A_log"]), Bm, Cm, p["D"],
+                           backend=backend)
     y = y.reshape(B, di).astype(x.dtype) * jax.nn.silu(z)
-    y = ops.rmsnorm(y, p["norm"], backend=backend)
-    return y @ p["w_out"], {"h": h, "conv": conv_state}
+    y = ops.rmsnorm(y, p["norm"], eps=cfg.norm_eps, backend=backend)
+    return y @ p["w_out"], {"h": hs[0] if one else hs,
+                            "conv": conv_state.astype(state["conv"].dtype)}
 
 
 # ---------------------------------------------------------------------------

@@ -2,6 +2,13 @@
 
 Layers are scanned (stacked params) to keep HLO size and compile time
 independent of depth — essential for the 512-device dry-runs.
+
+A dense/MoE stack may change its mixer by layer (``cfg.layer_types``:
+attention or Mamba2, each followed by the layer's MLP or MoE).  Each run
+of same-mixer layers (``cfg.layer_runs``) is one scanned stack, and its
+cache holds K/V for an attention run, the f32 SSM state and the conv
+tail for a Mamba run.  A stack of one run keeps ``params["layers"]`` and
+the cache as that run's; several runs make both lists, one entry a run.
 """
 from __future__ import annotations
 
@@ -36,10 +43,28 @@ def _norm_pair(cfg, name: str) -> Dict[str, ParamSpec]:
     return sp
 
 
-def _dense_layer_specs(cfg) -> Dict[str, Any]:
+def _norm(cfg, p, name: str, x, backend):
+    return L.apply_norm(p[name], x, cfg.norm, p.get(name + "_b"), backend,
+                        eps=cfg.norm_eps)
+
+
+def _runs(cfg, tree):
+    """(mixer, that run's subtree) for each run of ``cfg.layer_runs``."""
+    trees = tree if isinstance(tree, (list, tuple)) else [tree]
+    return [(kind, t) for (kind, _, _), t in zip(cfg.layer_runs(), trees)]
+
+
+def _one_or_list(per_run):
+    return per_run[0] if len(per_run) == 1 else list(per_run)
+
+
+def _dense_layer_specs(cfg, mixer: str = "attention") -> Dict[str, Any]:
     sp: Dict[str, Any] = {}
     sp.update(_norm_pair(cfg, "ln1"))
-    sp["attn"] = L.attention_specs(cfg)
+    if mixer == "mamba":
+        sp["mamba"] = L.mamba2_specs(cfg)
+    else:
+        sp["attn"] = L.attention_specs(cfg)
     sp.update(_norm_pair(cfg, "ln2"))
     if cfg.family == "moe":
         sp["moe"] = L.moe_specs(cfg)
@@ -59,7 +84,9 @@ def lm_specs(cfg) -> Dict[str, Any]:
     specs: Dict[str, Any] = {"embed": L.embed_specs(cfg)}
     specs.update(_norm_pair(cfg, "final_norm"))
     if cfg.family in ("dense", "moe", "vlm"):
-        specs["layers"] = _stack(_dense_layer_specs(cfg), cfg.n_layers)
+        specs["layers"] = _one_or_list(
+            [_stack(_dense_layer_specs(cfg, kind), n)
+             for kind, _, n in cfg.layer_runs()])
     elif cfg.family == "ssm":
         specs["layers"] = _stack(_ssm_layer_specs(cfg), cfg.n_layers)
     elif cfg.family == "hybrid":
@@ -80,12 +107,16 @@ def lm_specs(cfg) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 
-def _dense_layer_apply(cfg, rules, window, backend, lp, x, positions):
-    h = L.apply_norm(lp["ln1"], x, cfg.norm, lp.get("ln1_b"), backend)
-    h = L.attention_apply(lp["attn"], h, positions, cfg=cfg, rules=rules,
-                          causal=True, window=window, backend=backend)
+def _dense_layer_apply(cfg, rules, window, backend, mixer, lp, x, positions):
+    h = _norm(cfg, lp, "ln1", x, backend)
+    if mixer == "mamba":
+        h = L.mamba2_apply(lp["mamba"], h, cfg=cfg, rules=rules,
+                           backend=backend)
+    else:
+        h = L.attention_apply(lp["attn"], h, positions, cfg=cfg, rules=rules,
+                              causal=True, window=window, backend=backend)
     x = x + h
-    h = L.apply_norm(lp["ln2"], x, cfg.norm, lp.get("ln2_b"), backend)
+    h = _norm(cfg, lp, "ln2", x, backend)
     if cfg.family == "moe":
         h = L.moe_apply(lp["moe"], h, cfg=cfg, rules=rules, backend=backend)
     else:
@@ -94,17 +125,17 @@ def _dense_layer_apply(cfg, rules, window, backend, lp, x, positions):
 
 
 def _ssm_layer_apply(cfg, rules, backend, lp, x):
-    h = L.apply_norm(lp["ln1"], x, cfg.norm, lp.get("ln1_b"), backend)
+    h = _norm(cfg, lp, "ln1", x, backend)
     h = L.mamba2_apply(lp["mamba"], h, cfg=cfg, rules=rules, backend=backend)
     return x + h
 
 
 def _shared_attn_apply(cfg, rules, backend, sp, x, positions):
-    h = L.apply_norm(sp["ln1"], x, cfg.norm, sp.get("ln1_b"), backend)
+    h = _norm(cfg, sp, "ln1", x, backend)
     h = L.attention_apply(sp["attn"], h, positions, cfg=cfg, rules=rules,
                           causal=True, window=cfg.window, backend=backend)
     x = x + h
-    h = L.apply_norm(sp["ln2"], x, cfg.norm, sp.get("ln2_b"), backend)
+    h = _norm(cfg, sp, "ln2", x, backend)
     return x + L.mlp_apply(sp["mlp"], h, cfg=cfg, rules=rules)
 
 
@@ -132,10 +163,11 @@ def hidden_states(cfg, params, x, positions, *, rules=None, backend="auto"):
     """Run the layer stack on embedded inputs x: (B, S, d)."""
     remat = cfg.remat == "full"
     if cfg.family in ("dense", "moe", "vlm"):
-        fn = functools.partial(_dense_layer_apply, cfg, rules, cfg.window,
-                               backend)
-        x = _scan_layers(lambda lp, h: fn(lp, h, positions),
-                         params["layers"], x, remat, rules=rules)
+        for kind, stack in _runs(cfg, params["layers"]):
+            fn = functools.partial(_dense_layer_apply, cfg, rules,
+                                   cfg.window, backend, kind)
+            x = _scan_layers(lambda lp, h, fn=fn: fn(lp, h, positions),
+                             stack, x, remat, rules=rules)
     elif cfg.family == "ssm":
         fn = functools.partial(_ssm_layer_apply, cfg, rules, backend)
         x = _scan_layers(fn, params["layers"], x, remat, rules=rules)
@@ -155,8 +187,7 @@ def hidden_states(cfg, params, x, positions, *, rules=None, backend="auto"):
             start += width
     else:
         raise ValueError(cfg.family)
-    return L.apply_norm(params["final_norm"], x, cfg.norm,
-                        params.get("final_norm_b"), backend)
+    return _norm(cfg, params, "final_norm", x, backend)
 
 
 def forward(cfg, params, batch, *, rules=None, backend="auto"):
@@ -184,31 +215,43 @@ def forward(cfg, params, batch, *, rules=None, backend="auto"):
 # ---------------------------------------------------------------------------
 
 
+def _ssm_cache_specs(cfg, n: int, B: int) -> Dict[str, ParamSpec]:
+    """f32 SSM state (n, B, H, P, N) and conv tail of n Mamba layers."""
+    H, N, P = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim
+    conv_c = cfg.ssm_inner + 2 * N
+    return {
+        "h": ParamSpec((n, B, H, P, N), jnp.float32,
+                       (None, "batch", "ssm_inner", None, None), init="zeros"),
+        "conv": ParamSpec((n, B, cfg.conv_k - 1, conv_c), cfg.param_dtype,
+                          (None, "batch", None, "ssm_inner"), init="zeros"),
+    }
+
+
 def cache_specs(cfg, batch: int, seq_len: int) -> Dict[str, Any]:
     """Abstract KV/state cache layout for one-token decode.
 
     Dense/MoE/VLM: per-layer KV (L, B, S, Hkv, Dh) — S sharded over
-    'model' (seq_kv) so 32k×128 caches fit HBM.
-    SSM: recurrent state (L, B, H, N, P) + conv tail.
+    'model' (seq_kv) so 32k×128 caches fit HBM; with a layer pattern, one
+    entry per run: KV for an attention run, SSM state and conv tail for
+    a Mamba run.
+    SSM: recurrent state (L, B, H, P, N) + conv tail.
     Hybrid: SSM states + one KV cache per shared-attention application
     (window-bounded for long contexts)."""
     Lc, B, S = cfg.n_layers, batch, seq_len
     Hkv, Dh = cfg.n_kv, cfg.d_head
     dt = cfg.param_dtype
     if cfg.family in ("dense", "moe", "vlm"):
-        kv = ParamSpec((Lc, B, S, Hkv, Dh), dt,
-                       (None, "batch", "seq_kv", "kv_heads", None),
-                       init="zeros")
-        return {"k": kv, "v": kv}
-    di = cfg.ssm_inner
-    H, N, P = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim
-    conv_c = di + 2 * N
-    ssm = {
-        "h": ParamSpec((Lc, B, H, N, P), jnp.float32,
-                       (None, "batch", "ssm_inner", None, None), init="zeros"),
-        "conv": ParamSpec((Lc, B, cfg.conv_k - 1, conv_c), dt,
-                          (None, "batch", None, "ssm_inner"), init="zeros"),
-    }
+        runs = []
+        for kind, _, n in cfg.layer_runs():
+            if kind == "mamba":
+                runs.append(_ssm_cache_specs(cfg, n, B))
+                continue
+            kv = ParamSpec((n, B, S, Hkv, Dh), dt,
+                           (None, "batch", "seq_kv", "kv_heads", None),
+                           init="zeros")
+            runs.append({"k": kv, "v": kv})
+        return _one_or_list(runs)
+    ssm = _ssm_cache_specs(cfg, Lc, B)
     if cfg.family == "ssm":
         return ssm
     # hybrid: shared-attention KV per application, window-bounded
@@ -227,45 +270,24 @@ def decode_step(cfg, params, cache, tokens, pos, *, rules=None,
     lengths.  Returns (logits (B, Vpad), new cache)."""
     x = L.embed_apply(params["embed"], tokens)          # (B, d)
     x = L.constrain(x, rules, ("batch", "embed"))
+    # a sequence at position 0 starts from the zero recurrent state
+    fresh = pos == 0
 
     if cfg.family in ("dense", "moe", "vlm"):
-        layers, stacks = params["layers"], None
-        if cfg.family == "moe" and L.moe_reads_chosen(cfg, rules, x.shape[0],
-                                                      backend):
-            # the expert stacks stay out of the scanned layers, whole: the
-            # kernel reads layer i's chosen slabs straight from them
-            moe = dict(layers["moe"])
-            stacks = {n: moe.pop(n) for n in L.EXPERT_STACKS}
-            layers = dict(layers, moe=moe)
-
-        def body(carry, inp):
-            h, i = carry
-            lp, kc, vc = inp
-            hn = L.apply_norm(lp["ln1"], h, cfg.norm, lp.get("ln1_b"), backend)
-            y, newkv = L.attention_decode(lp["attn"], hn, {"k": kc, "v": vc},
-                                          pos, cfg=cfg, rules=rules,
-                                          backend=backend)
-            h = h + y
-            hn = L.apply_norm(lp["ln2"], h, cfg.norm, lp.get("ln2_b"), backend)
-            if cfg.family == "moe":
-                y = L.moe_apply(dict(lp["moe"], **(stacks or {})), hn[:, None],
-                                cfg=cfg, rules=rules, backend=backend,
-                                layer=None if stacks is None else i)[:, 0]
-            else:
-                y = L.mlp_apply(lp["mlp"], hn[:, None], cfg=cfg,
-                                rules=rules)[:, 0]
-            return (h + y, i + 1), (newkv["k"], newkv["v"])
-
-        (h, _), (ks, vs) = lax.scan(body, (x, jnp.int32(0)),
-                                    (layers, cache["k"], cache["v"]))
-        new_cache = {"k": ks, "v": vs}
+        h, runs = x, []
+        for (kind, layers), (_, run_cache) in zip(
+                _runs(cfg, params["layers"]), _runs(cfg, cache)):
+            h, new = _decode_run(cfg, kind, layers, run_cache, h, pos, fresh,
+                                 rules=rules, backend=backend)
+            runs.append(new)
+        new_cache = _one_or_list(runs)
 
     elif cfg.family == "ssm":
         def body(h, inp):
             lp, hs, cs = inp
-            hn = L.apply_norm(lp["ln1"], h, cfg.norm, lp.get("ln1_b"), backend)
+            hn = _norm(cfg, lp, "ln1", h, backend)
             y, st = L.mamba2_decode(lp["mamba"], hn, {"h": hs, "conv": cs},
-                                    cfg=cfg, backend=backend)
+                                    cfg=cfg, backend=backend, fresh=fresh)
             return h + y, (st["h"], st["conv"])
 
         h, (hs, cs) = lax.scan(body, x, (params["layers"], cache["h"],
@@ -289,11 +311,11 @@ def decode_step(cfg, params, cache, tokens, pos, *, rules=None,
 
             def body(hh, inp):
                 lp, hstate, cstate = inp
-                hn = L.apply_norm(lp["ln1"], hh, cfg.norm, lp.get("ln1_b"),
-                                  backend)
+                hn = _norm(cfg, lp, "ln1", hh, backend)
                 y, st = L.mamba2_decode(lp["mamba"], hn,
                                         {"h": hstate, "conv": cstate},
-                                        cfg=cfg, backend=backend)
+                                        cfg=cfg, backend=backend,
+                                        fresh=fresh)
                 return hh + y, (st["h"], st["conv"])
 
             h, (hs, cs) = lax.scan(
@@ -305,13 +327,13 @@ def decode_step(cfg, params, cache, tokens, pos, *, rules=None,
             hs_out.append(hs)
             cs_out.append(cs)
             sp = params["shared_attn"]
-            hn = L.apply_norm(sp["ln1"], h, cfg.norm, sp.get("ln1_b"), backend)
+            hn = _norm(cfg, sp, "ln1", h, backend)
             y, newkv = L.attention_decode(
                 sp["attn"], hn, {"k": cache["k"][app], "v": cache["v"][app]},
                 pos, cfg=cfg, rules=rules, backend=backend,
                 slot=slot, kv_len=kv_len)
             h = h + y
-            hn = L.apply_norm(sp["ln2"], h, cfg.norm, sp.get("ln2_b"), backend)
+            hn = _norm(cfg, sp, "ln2", h, backend)
             h = h + L.mlp_apply(sp["mlp"], hn[:, None], cfg=cfg,
                                 rules=rules)[:, 0]
             k_out.append(newkv["k"])
@@ -324,8 +346,57 @@ def decode_step(cfg, params, cache, tokens, pos, *, rules=None,
     else:
         raise ValueError(cfg.family)
 
-    h = L.apply_norm(params["final_norm"], h, cfg.norm,
-                     params.get("final_norm_b"), backend)
+    h = _norm(cfg, params, "final_norm", h, backend)
     logits = L.unembed_apply(params["embed"], h, cfg)
     logits = L.constrain(logits, rules, ("batch", "vocab"))
     return logits, new_cache
+
+
+def _decode_run(cfg, mixer, layers, cache, h, pos, fresh, *, rules, backend):
+    """One token through a run of same-mixer layers (stacked params,
+    scanned), each with its MLP or MoE: (h, the run's new cache).  A
+    Mamba run carries its whole SSM state stack through the scan, each
+    layer's state updated in place (``ssd_decode``)."""
+    stacks = None
+    if cfg.family == "moe" and L.moe_reads_chosen(cfg, rules, h.shape[0],
+                                                  backend):
+        # the expert stacks stay out of the scanned layers, whole: the
+        # kernel reads layer i's chosen slabs straight from them
+        moe = dict(layers["moe"])
+        stacks = {n: moe.pop(n) for n in L.EXPERT_STACKS}
+        layers = dict(layers, moe=moe)
+    carried, names = ((cache["h"],), ("conv",)) if mixer == "mamba" \
+        else ((), ("k", "v"))
+
+    def body(carry, inp):
+        x, i, *stack = carry
+        lp, *state = inp
+        hn = _norm(cfg, lp, "ln1", x, backend)
+        if mixer == "mamba":
+            y, new = L.mamba2_decode(lp["mamba"], hn,
+                                     {"h": stack[0], "conv": state[0]},
+                                     cfg=cfg, backend=backend, fresh=fresh,
+                                     layer=i)
+            stack = [new["h"]]
+        else:
+            y, new = L.attention_decode(lp["attn"], hn, dict(zip(names, state)),
+                                        pos, cfg=cfg, rules=rules,
+                                        backend=backend)
+        x = x + y
+        hn = _norm(cfg, lp, "ln2", x, backend)
+        if cfg.family == "moe":
+            y = L.moe_apply(dict(lp["moe"], **(stacks or {})), hn[:, None],
+                            cfg=cfg, rules=rules, backend=backend,
+                            layer=None if stacks is None else i)[:, 0]
+        else:
+            y = L.mlp_apply(lp["mlp"], hn[:, None], cfg=cfg,
+                            rules=rules)[:, 0]
+        return (x + y, i + 1, *stack), tuple(new[n] for n in names)
+
+    (h, _, *stack), states = lax.scan(
+        body, (h, jnp.int32(0), *carried),
+        (layers, *(cache[n] for n in names)))
+    new_cache = dict(zip(names, states))
+    if stack:
+        new_cache["h"] = stack[0]
+    return h, new_cache

@@ -25,14 +25,16 @@ from benchmarks.kernels_suite import all_kernels
 from repro.core.runtime import build_traceable
 from repro.configs import registry
 from repro.kernels import flash_attention as fa
-from repro.kernels import moe_decode, norms, softmax, ssd_scan
+from repro.kernels import moe_decode, norms, softmax, ssd_decode, ssd_scan
 from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.parallel import steps
 
-# granite-moe-1b-a400m attention widths; mamba2-130m SSD widths
+# granite-moe-1b-a400m attention widths; mamba2-130m SSD widths;
+# granite-4.0-h-small's Mamba2 (a run of 5 layers' state stack)
 GRANITE = dict(S=2048, H=16, Hkv=8, D=64, d=1024)
 MAMBA2 = dict(S=2048, H=24, P=64, N=128)
+HYBRID_SSM = dict(L=5, B=1, H=128, P=64, N=128)
 
 
 @pytest.fixture(scope="module")
@@ -151,6 +153,56 @@ def test_ssd_scan_compiles(one_chip):
     a = _sds(one_chip, (S, H), jnp.float32)
     bc = _sds(one_chip, (S, N), jnp.float32)
     assert "tpu_custom_call" in _compile_text(ssd_scan.ssd_scan, x, a, bc, bc)
+
+
+def test_ssd_decode_compiles(one_chip):
+    L, B, H, P, N = (HYBRID_SSM[k] for k in ("L", "B", "H", "P", "N"))
+    args = (_sds(one_chip, (L, B, H, P, N), jnp.float32),
+            _sds(one_chip, (1,), jnp.int32), _sds(one_chip, (B,), jnp.bool_),
+            _sds(one_chip, (B, H, P), jnp.bfloat16),
+            _sds(one_chip, (B, H), jnp.float32),
+            _sds(one_chip, (H,), jnp.float32),
+            _sds(one_chip, (B, N), jnp.bfloat16),
+            _sds(one_chip, (B, N), jnp.bfloat16),
+            _sds(one_chip, (H,), jnp.float32))
+    # the stack is updated in place: donated, as the serve step's scan
+    # carry is
+    text = jax.jit(ssd_decode.ssd_decode, donate_argnums=(0,)).lower(
+        *args).compile().as_text()
+    assert "ssd_decode" in text
+
+
+def test_hybrid_serve_step_takes_the_kernels_on_whole_stacks(topo):
+    """granite-4.0-h-small's serve step (10 layers: Mamba2 runs of 5 and
+    4, one attention layer) as the server builds it on one chip: each
+    Mamba2 run's scan calls ``ssd_decode`` on its whole f32 state stack
+    (5 + 4 = 9 layers), kept in HBM, and each run's MoE calls
+    ``moe_decode_experts`` on the run's whole stacks of the 18 held
+    experts (5 + 1 + 4 = 10 layers)."""
+    cfg = registry.get("granite-4.0-h-small")
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    step, bundle = steps.make_serve_step(cfg, mesh, backend="pallas")
+    rules = bundle["rules"]
+
+    def shapes(specs, shardings):
+        return jax.tree_util.tree_map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            specs, shardings, is_leaf=lambda s: hasattr(s, "abstract"))
+
+    cache = lm.cache_specs(cfg, 1, 512)
+    tok = jax.ShapeDtypeStruct((1,), jnp.int32,
+                               sharding=NamedSharding(mesh, P()))
+    text = _compile_text(step, shapes(bundle["specs"], bundle["param_sh"]),
+                         shapes(cache, rules.tree_shardings(cache)), tok, tok)
+    calls = [line for line in text.splitlines() if "custom-call(" in line]
+    ssd = [line for line in calls if re.match(r"\s*%ssd_decode", line)]
+    stacks = sorted(int(m) for line in ssd
+                    for m in re.findall(r"f32\[(\d+),1,128,64,128\]\{[^}]*\}\)", line))
+    assert stacks == [4, 5]
+    moe = [line for line in calls if re.match(r"\s*%moe_decode_experts", line)]
+    held = sorted(int(m) for line in moe
+                  for m in re.findall(r"bf16\[(\d+),18,768,4096\]", line))
+    assert held == [1, 4, 5]
 
 
 _COX = {k.name: k for k in all_kernels()}
