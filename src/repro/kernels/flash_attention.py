@@ -189,3 +189,161 @@ def flash_decode(q, k_cache, v_cache, kv_len, *, scale=None,
         name="flash_decode",
     )(kv_len, qg, kt, vt)
     return out.reshape(H, D)
+
+
+# ---------------------------------------------------------------------------
+# decode on the cache stacks: the token written and attended in place
+# ---------------------------------------------------------------------------
+
+LANES = 128
+
+
+def _cache_kernel(layer_ref, pos_ref, len_ref, q_ref, kn_ref, vn_ref, k_ref,
+                  v_ref, o_ref, ko_ref, vo_ref, m_scr, l_scr, acc_scr, *,
+                  scale: float, bk: int, nk: int, minor: bool):
+    b, ik = pl.program_id(0), pl.program_id(1)
+    pos, kv_len = pos_ref[b], len_ref[b]
+    ax = 2 if minor else 1          # the positions' axis, heads first
+    f32 = jnp.float32
+
+    def heads(blk):                 # (Hkv, D, positions), or (Hkv, ·, D)
+        blk = blk.astype(f32)
+        return blk if minor else jnp.swapaxes(blk, 0, 1)
+
+    @pl.when(ik == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        if not minor:               # the token's row is the block written
+            ko_ref[0, 0] = kn_ref[0]
+            vo_ref[0, 0] = vn_ref[0]
+
+    if minor:
+        @pl.when(ik == pos // bk)
+        def _write():
+            # the block written is the 128 positions around the token's,
+            # as fetched, with the token's column in place
+            off = pl.multiple_of(pos % bk // LANES * LANES, LANES)
+            lane = jax.lax.broadcasted_iota(jnp.int32, ko_ref.shape[2:], 2)
+            for new, blk, out in ((kn_ref, k_ref, ko_ref),
+                                  (vn_ref, v_ref, vo_ref)):
+                out[0, 0] = jnp.where(lane == pos % LANES, new[0],
+                                      blk[0, 0, :, :, pl.ds(off, LANES)])
+
+    @pl.when(ik * bk < kv_len)
+    def _body():
+        q = q_ref[0].astype(f32) * scale                  # (Hkv, g, D)
+        k, v = heads(k_ref[0, 0]), heads(v_ref[0, 0])
+        # the block as fetched holds the row at pos from before this
+        # step: the token's takes its place
+        at = ik * bk + jax.lax.broadcasted_iota(jnp.int32, k.shape, ax)
+        k = jnp.where(at == pos, heads(kn_ref[0]), k)
+        v = jnp.where(at == pos, heads(vn_ref[0]), v)
+        # each q-head group against its kv head, batched over kv heads
+        s = jax.lax.dot_general(q, k, (((2,), (3 - ax,)), ((0,), (0,))),
+                                preferred_element_type=f32)  # (Hkv, g, bk)
+        at = ik * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(at < kv_len, s, NEG_INF)
+        m_prev = m_scr[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = l_scr[...] * alpha + p.sum(axis=2, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p, v, (((2,), (ax,)), ((0,), (0,))), preferred_element_type=f32)
+        m_scr[...] = m_new
+
+    @pl.when(ik == nk - 1)
+    def _finish():
+        lsum = l_scr[...]
+        lsum = jnp.where(lsum == 0.0, 1.0, lsum)
+        o_ref[0] = (acc_scr[...] / lsum).astype(o_ref.dtype)
+
+
+def decode_attn_cache(layer, pos, kv_len, q, k_new, v_new, k_stack, v_stack,
+                      *, minor: bool = False, scale=None, bk: int = 512,
+                      interpret: bool = False):
+    """One decode step's attention of every sequence on the whole K/V
+    stacks of a run of layers, with the token's K and V written in place.
+
+    layer: (1,) int32; pos, kv_len: (B,) int32, the row each sequence
+    writes and how many rows it attends (pos < kv_len); q: (B, H, D);
+    k_new, v_new: (B, Hkv, D); stacks: (n, B, S, Hkv, D) -> (out (B, H,
+    D), k_stack, v_stack), the stacks aliased to their inputs and changed
+    only at ``(layer, b, pos[b])``.
+
+    ``minor`` says how the device stores the stacks: with the positions
+    minor, (n, B, Hkv, D, S) in memory (the TPU's layout for D = 64, which
+    would otherwise pad each row to 128 lanes), else as indexed.  The
+    kernel walks them in that order, so no stack is transposed or copied.
+    The grid walks the sequences and blocks of ``bk`` positions; a
+    block's index is clamped to the sequence's last valid block, so
+    blocks past ``kv_len`` are never fetched.  The stacks' output blocks
+    are the token's row (with the positions minor, the 128 positions
+    around it), so each sequence writes back two small blocks; the stacks
+    stay in HBM and must be an intermediate or donated, as a scan carry
+    is.  A q-head group reads its kv head's tiles in VMEM.  The math is
+    ``flash_decode``'s: f32 scores, online softmax."""
+    Bt, H, D = q.shape
+    n, _, S, Hkv, _ = k_stack.shape
+    g = H // Hkv
+    scale = scale if scale is not None else 1.0 / (D ** 0.5)
+    bk = min(bk, S)
+    assert S % bk == 0 and not (minor and bk % LANES)
+    nk = S // bk
+    dt = k_stack.dtype
+    stacks = [k_stack, v_stack]
+    new = [k_new.astype(dt), v_new.astype(dt)]
+    if minor:
+        stacks = [jnp.moveaxis(s, 2, 4) for s in stacks]   # (n, B, Hkv, D, S)
+        new = [t[..., None] for t in new]                   # (B, Hkv, D, 1)
+        token = pl.BlockSpec((1, Hkv, D, 1), lambda b, ik, *_: (b, 0, 0, 0))
+        block = pl.BlockSpec(
+            (1, 1, Hkv, D, bk), lambda b, ik, layer, pos, ln: (
+                layer[0], b, 0, 0, jnp.minimum(ik, (ln[b] - 1) // bk)))
+        row = pl.BlockSpec(
+            (1, 1, Hkv, D, LANES), lambda b, ik, layer, pos, ln: (
+                layer[0], b, 0, 0, pos[b] // LANES))
+    else:
+        new = [t[:, None] for t in new]                     # (B, 1, Hkv, D)
+        token = pl.BlockSpec((1, 1, Hkv, D), lambda b, ik, *_: (b, 0, 0, 0))
+        block = pl.BlockSpec(
+            (1, 1, bk, Hkv, D), lambda b, ik, layer, pos, ln: (
+                layer[0], b, jnp.minimum(ik, (ln[b] - 1) // bk), 0, 0))
+        row = pl.BlockSpec(
+            (1, 1, 1, Hkv, D), lambda b, ik, layer, pos, ln: (
+                layer[0], b, pos[b], 0, 0))
+    stack_out = [jax.ShapeDtypeStruct(stacks[0].shape, dt)] * 2
+    if not interpret:
+        # the stacks stay in HBM, where the kernel reads and writes them
+        stacks = [pltpu.with_memory_space_constraint(s, pltpu.HBM)
+                  for s in stacks]
+        stack_out = [pltpu.HBM(stacks[0].shape, dt)] * 2
+    per_seq = pl.BlockSpec((1, Hkv, g, D), lambda b, ik, *_: (b, 0, 0, 0))
+    # a position past the stacks writes the last row, as
+    # dynamic_update_slice does, and never names a block outside them
+    pos = jnp.minimum(pos.astype(jnp.int32), S - 1)
+    kv_len = jnp.minimum(kv_len.astype(jnp.int32), S)
+    out, k_stack, v_stack = pl.pallas_call(
+        functools.partial(_cache_kernel, scale=scale, bk=bk, nk=nk,
+                          minor=minor),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(Bt, nk),
+            in_specs=[per_seq, token, token, block, block],
+            out_specs=[per_seq, row, row],
+            scratch_shapes=[pltpu.VMEM((Hkv, g, 1), jnp.float32),
+                            pltpu.VMEM((Hkv, g, 1), jnp.float32),
+                            pltpu.VMEM((Hkv, g, D), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((Bt, Hkv, g, D), q.dtype), *stack_out],
+        input_output_aliases={6: 1, 7: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="decode_attn_cache",
+    )(layer.astype(jnp.int32), pos, kv_len, q.reshape(Bt, Hkv, g, D), *new,
+      *stacks)
+    if minor:
+        k_stack, v_stack = (jnp.moveaxis(s, 4, 2) for s in (k_stack, v_stack))
+    return out.reshape(Bt, H, D), k_stack, v_stack

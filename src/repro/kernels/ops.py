@@ -80,6 +80,14 @@ def decode_attention(q, k_cache, v_cache, kv_len, backend: str = "auto"):
                      q, k_cache, v_cache, kv_len)
 
 
+def decode_attn_cache(layer, pos, kv_len, q, k_new, v_new, k_stack, v_stack,
+                      minor: bool = False, backend: str = "auto"):
+    return _dispatch(backend,
+                     functools.partial(_fa.decode_attn_cache, minor=minor),
+                     _ref.decode_attn_cache,
+                     layer, pos, kv_len, q, k_new, v_new, k_stack, v_stack)
+
+
 def ssd_scan(x, a, bmat, cmat, chunk: int = 128, backend: str = "auto"):
     # xla: the chunked dual form — same math, production XLA path
     return _dispatch(backend, functools.partial(_ssd.ssd_scan, chunk=chunk),

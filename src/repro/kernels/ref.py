@@ -99,6 +99,18 @@ def decode_attention(q, k_cache, v_cache, kv_len=None, scale=None):
     return jnp.einsum("hs,shd->hd", p, v32).astype(q.dtype)
 
 
+def decode_attn_cache(layer, pos, kv_len, q, k_new, v_new, k_stack, v_stack):
+    """One decode step of every sequence on layer ``layer`` of the K/V
+    stacks (n, B, S, Hkv, D): the token's K and V (B, Hkv, D) written at
+    ``pos`` (B,), then ``decode_attention`` of q (B, H, D) over the first
+    ``kv_len`` (B,) rows -> (out (B, H, D), the stacks so written)."""
+    i, b = layer.reshape(()), jnp.arange(q.shape[0])
+    k_stack = k_stack.at[i, b, pos].set(k_new.astype(k_stack.dtype))
+    v_stack = v_stack.at[i, b, pos].set(v_new.astype(v_stack.dtype))
+    out = jax.vmap(decode_attention)(q, k_stack[i], v_stack[i], kv_len)
+    return out, k_stack, v_stack
+
+
 def ssd_scan(x, a, b, c, *, chunk: int = 0):
     """Mamba2 SSD (state-space dual) sequential reference.
 

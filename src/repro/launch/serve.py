@@ -202,6 +202,23 @@ class RequestKernelPool:
         return hists
 
 
+def prefill_program(raw_step):
+    """``prefill(params, cache, tok_mat, pos0, mask) -> (cache, pos)``:
+    ``lax.scan`` of the raw (un-jitted) serve step over a (T, B) token
+    matrix, each slot's position advanced by its ``mask``; the math is
+    identical to stepping token by token."""
+    def prefill(params, cache, tok_mat, pos0, mask):
+        def body(carry, toks):
+            cache, pos = carry
+            _, cache = raw_step(params, cache, toks, pos)
+            return (cache, pos + mask), None
+
+        (cache, pos), _ = jax.lax.scan(body, (cache, pos0), tok_mat)
+        return cache, pos
+
+    return prefill
+
+
 class BatchedServer:
     def __init__(self, arch: str, *, batch: int = 4, ctx: int = 128,
                  mesh=None, seed: int = 0, params=None):
@@ -236,23 +253,12 @@ class BatchedServer:
         self._until: Optional[int] = None
 
     def _build_prefill(self, T: int):
-        """One jitted program for a whole T-token prompt: ``lax.scan``
-        of the raw (un-jitted) serve step over the token matrix, cache
-        donated across the scan.  Replaces T host round-trips (one
-        jitted dispatch per prompt token) with a single call; the math
-        is identical to stepping token-by-token."""
-        raw = self.bundle["raw_step"]
-
-        def prefill(params, cache, tok_mat, pos0, mask):
-            def body(carry, toks):
-                cache, pos = carry
-                _, cache = raw(params, cache, toks, pos)
-                return (cache, pos + mask), None
-
-            (cache, pos), _ = jax.lax.scan(body, (cache, pos0), tok_mat)
-            return cache, pos
-
-        return jax.jit(prefill, donate_argnums=(1,))
+        """One jitted program for a whole T-token prompt
+        (``prefill_program``), cache donated across the scan.  Replaces
+        T host round-trips (one jitted dispatch per prompt token) with a
+        single call."""
+        return jax.jit(prefill_program(self.bundle["raw_step"]),
+                       donate_argnums=(1,))
 
     def prefill_prompt(self, slot: int, prompt: List[int]):
         """Feed a prompt through the decode path in ONE step per slot: a

@@ -12,6 +12,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout
 
 from ..kernels import ops
 from .params import AxisRules, ParamSpec
@@ -136,12 +137,44 @@ def attention_apply(p, x, positions, *, cfg, rules=None, causal=True,
     return constrain(out, rules, ("batch", None, "embed"))
 
 
+def decode_attn_in_place(cfg, rules, backend: str) -> bool:
+    """Whether a decode step's attention writes and reads a run's K/V
+    stacks in place (``attention_decode`` with ``layer``, on
+    ``ops.decode_attn_cache``): the stacks are whole on the one device
+    the step runs on (a Pallas call is not partitioned over ``seq_kv``)
+    and the backend runs Pallas kernels.  Nothing in ``cfg`` enters:
+    every attention run of a dense, MoE or VLM stack can take the path
+    (the hybrid family's rolling window and the encoder-decoder keep
+    their own decode)."""
+    del cfg
+    return ((rules is None or rules.mesh.size == 1)
+            and ops.resolve(backend) in ("pallas", "interpret"))
+
+
+def cache_positions_minor(rules, shape, dtype) -> bool:
+    """Whether the device the step runs on keeps a K/V stack of ``shape``
+    (n, B, S, Hkv, Dh) with its positions minor, as the TPU does where
+    Dh = 64 would pad each row to 128 lanes: the device's own default
+    layout, asked of its client."""
+    device = rules.mesh.devices.flat[0] if rules is not None \
+        else jax.devices()[0]
+    try:
+        layout = device.client.get_default_layout(jnp.dtype(dtype),
+                                                  tuple(shape), device)
+    except jax.errors.JaxRuntimeError:       # a backend without layouts
+        return False
+    return Layout.from_pjrt_layout(layout).major_to_minor[-1] == 2
+
+
 def attention_decode(p, x, cache, pos, *, cfg, rules=None, backend="auto",
-                     slot=None, kv_len=None):
+                     slot=None, kv_len=None, layer=None):
     """One-token decode.  x: (B, d); cache: {k: (B, S, Hkv, Dh), v: ...};
     pos: (B,) absolute positions (for RoPE); slot: (B,) cache write slots
     (rolling-buffer windows; defaults to pos); kv_len: (B,) valid cache
-    length (defaults to pos+1)."""
+    length (defaults to pos+1).  With ``layer`` (where
+    ``decode_attn_in_place`` holds), the cache holds the (L, B, S, Hkv,
+    Dh) stacks of a run of layers, and layer ``layer``'s rows are written
+    and read in place."""
     B, d = x.shape
     slot = pos if slot is None else slot
     kv_len = pos + 1 if kv_len is None else kv_len
@@ -154,10 +187,17 @@ def attention_decode(p, x, cache, pos, *, cfg, rules=None, backend="auto",
         # rope wants (..., S, H, D): add a singleton S axis
         q = rope(q[:, None], pos[:, None])[:, 0]
         k = rope(k[:, None], pos[:, None])[:, 0]
-    kc = _scatter_token(cache["k"], k, slot)
-    vc = _scatter_token(cache["v"], v, slot)
-    out = jax.vmap(lambda qq, kk, vv, ln: ops.decode_attention(
-        qq, kk, vv, ln, backend=backend))(q, kc, vc, kv_len)
+    if layer is None:
+        kc = _scatter_token(cache["k"], k, slot)
+        vc = _scatter_token(cache["v"], v, slot)
+        out = jax.vmap(lambda qq, kk, vv, ln: ops.decode_attention(
+            qq, kk, vv, ln, backend=backend))(q, kc, vc, kv_len)
+    else:
+        kc, vc = cache["k"], cache["v"]
+        out, kc, vc = ops.decode_attn_cache(
+            jnp.asarray(layer, jnp.int32).reshape(1), slot, kv_len, q, k, v,
+            kc, vc, minor=cache_positions_minor(rules, kc.shape, kc.dtype),
+            backend=backend)
     hm = _head_mask(cfg)
     if hm is not None:
         out = out * hm[None, :, None].astype(out.dtype)

@@ -356,7 +356,10 @@ def _decode_run(cfg, mixer, layers, cache, h, pos, fresh, *, rules, backend):
     """One token through a run of same-mixer layers (stacked params,
     scanned), each with its MLP or MoE: (h, the run's new cache).  A
     Mamba run carries its whole SSM state stack through the scan, each
-    layer's state updated in place (``ssd_decode``)."""
+    layer's state updated in place (``ssd_decode``); so does an attention
+    run its K/V stacks where ``decode_attn_in_place`` holds
+    (``decode_attn_cache``).  The rest of the cache goes in and out as
+    the scan's per-layer slices."""
     stacks = None
     if cfg.family == "moe" and L.moe_reads_chosen(cfg, rules, h.shape[0],
                                                   backend):
@@ -365,23 +368,25 @@ def _decode_run(cfg, mixer, layers, cache, h, pos, fresh, *, rules, backend):
         moe = dict(layers["moe"])
         stacks = {n: moe.pop(n) for n in L.EXPERT_STACKS}
         layers = dict(layers, moe=moe)
-    carried, names = ((cache["h"],), ("conv",)) if mixer == "mamba" \
-        else ((), ("k", "v"))
+    if mixer == "mamba":
+        carried, names = ("h",), ("conv",)
+    elif L.decode_attn_in_place(cfg, rules, backend):
+        carried, names = ("k", "v"), ()
+    else:
+        carried, names = (), ("k", "v")
 
     def body(carry, inp):
-        x, i, *stack = carry
-        lp, *state = inp
+        x, i, *whole = carry
+        lp, *sliced = inp
+        state = dict(zip(carried, whole), **dict(zip(names, sliced)))
         hn = _norm(cfg, lp, "ln1", x, backend)
         if mixer == "mamba":
-            y, new = L.mamba2_decode(lp["mamba"], hn,
-                                     {"h": stack[0], "conv": state[0]},
-                                     cfg=cfg, backend=backend, fresh=fresh,
-                                     layer=i)
-            stack = [new["h"]]
+            y, new = L.mamba2_decode(lp["mamba"], hn, state, cfg=cfg,
+                                     backend=backend, fresh=fresh, layer=i)
         else:
-            y, new = L.attention_decode(lp["attn"], hn, dict(zip(names, state)),
-                                        pos, cfg=cfg, rules=rules,
-                                        backend=backend)
+            y, new = L.attention_decode(lp["attn"], hn, state, pos, cfg=cfg,
+                                        rules=rules, backend=backend,
+                                        layer=i if carried else None)
         x = x + y
         hn = _norm(cfg, lp, "ln2", x, backend)
         if cfg.family == "moe":
@@ -391,12 +396,10 @@ def _decode_run(cfg, mixer, layers, cache, h, pos, fresh, *, rules, backend):
         else:
             y = L.mlp_apply(lp["mlp"], hn[:, None], cfg=cfg,
                             rules=rules)[:, 0]
-        return (x + y, i + 1, *stack), tuple(new[n] for n in names)
+        return ((x + y, i + 1, *(new[n] for n in carried)),
+                tuple(new[n] for n in names))
 
-    (h, _, *stack), states = lax.scan(
-        body, (h, jnp.int32(0), *carried),
+    (h, _, *whole), sliced = lax.scan(
+        body, (h, jnp.int32(0), *(cache[n] for n in carried)),
         (layers, *(cache[n] for n in names)))
-    new_cache = dict(zip(names, states))
-    if stack:
-        new_cache["h"] = stack[0]
-    return h, new_cache
+    return h, dict(zip(carried, whole), **dict(zip(names, sliced)))

@@ -27,6 +27,7 @@ from repro.configs import registry
 from repro.kernels import flash_attention as fa
 from repro.kernels import moe_decode, norms, softmax, ssd_decode, ssd_scan
 from repro.launch.mesh import make_mesh
+from repro.launch.serve import prefill_program
 from repro.models import lm
 from repro.parallel import steps
 
@@ -203,6 +204,71 @@ def test_hybrid_serve_step_takes_the_kernels_on_whole_stacks(topo):
     held = sorted(int(m) for line in moe
                   for m in re.findall(r"bf16\[(\d+),18,768,4096\]", line))
     assert held == [1, 4, 5]
+
+
+def _serve_programs(topo, arch, ctx, prompt):
+    """The serve step and a ``prompt``-token prefill of ``arch`` at batch
+    1 and context ``ctx``, as the server builds them on one chip (the
+    cache donated), compiled: their HLO texts."""
+    cfg = registry.get(arch)
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    step, bundle = steps.make_serve_step(cfg, mesh, backend="pallas")
+    rules = bundle["rules"]
+
+    def shapes(specs, shardings):
+        return jax.tree_util.tree_map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            specs, shardings, is_leaf=lambda s: hasattr(s, "abstract"))
+
+    params = shapes(bundle["specs"], bundle["param_sh"])
+    cache = lm.cache_specs(cfg, 1, ctx)
+    cache = shapes(cache, rules.tree_shardings(cache))
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32,
+                                    sharding=NamedSharding(mesh, P()))
+
+    def text(fn, *args):
+        return jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
+
+    return (text(step, params, cache, ints(1), ints(1)),
+            text(prefill_program(step), params, cache, ints(prompt, 1),
+                 ints(1), ints(1)))
+
+
+# (arch, context, a stack's shape, as the kernel sees it (the TPU keeps
+# Dh = 64 with the positions minor), one layer of it, one sequence's
+# heads-major).  granite-4.0-h-small at 2048: at 4096 its wk and wv,
+# (1, d_model = 4096, 8, 128), have one layer's cache shape
+CACHE_STACKS = [
+    ("granite-moe-1b-a400m", 4096, "24,1,4096,8,64", "24,1,8,64,4096",
+     ["1,1,4096,8,64", "1,4096,8,64", "8,4096,64", "1,1,8,64,4096",
+      "1,8,64,4096", "8,64,4096"]),
+    ("granite-4.0-h-small", 2048, "1,1,2048,8,128", "1,1,2048,8,128",
+     ["1,2048,8,128", "8,2048,128", "1,1,8,128,2048", "1,8,128,2048"]),
+]
+
+
+@pytest.mark.parametrize("arch,ctx,stack,seen,cuts", CACHE_STACKS,
+                         ids=[a for a, *_ in CACHE_STACKS])
+def test_decode_attention_works_on_the_whole_cache_stacks(topo, arch, ctx,
+                                                          stack, seen, cuts):
+    """The serve step and a prefill program: every attention layer's
+    ``decode_attn_cache`` call is handed the run's whole K and V stacks in
+    the order the chip stores them, and no copy, slice, update, transpose
+    or fusion makes a whole stack, one layer's cache or one sequence's
+    heads-major cache."""
+    made = re.compile(r"= bf16\[({})\]\S* ({})\(".format(
+        "|".join([stack, seen] + cuts),
+        "copy|copy-start|copy-done|dynamic-slice|dynamic-update-slice|"
+        "transpose|fusion"))
+    for text in _serve_programs(topo, arch, ctx, 16):
+        calls = [line for line in text.splitlines()
+                 if re.match(r"\s*%decode_attn_cache(\.\d+)? = ", line)]
+        assert calls
+        for line in calls:
+            assert line.count(f"bf16[{seen}]") >= 4   # 2 operands, 2 results
+        assert not [line for line in text.splitlines() if made.search(line)]
 
 
 _COX = {k.name: k for k in all_kernels()}
