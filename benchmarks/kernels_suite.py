@@ -365,9 +365,9 @@ _reg("VoteAnyKernel3", "vote", VoteAnyKernel3, 1, 64, _vote_args)
 
 # ---------------------------------------------------------------------------
 # extra kernels outside the paper's 31-row table: atomics + a memory-light
-# many-block kernel, used by the backend-equivalence tests and the
-# backend sweep in benchmarks/run.py (Table-1 coverage counts stay on
-# KERNELS; ALL_KERNELS = KERNELS + EXTRA_KERNELS)
+# many-block kernel, used by the backend-equivalence and grid-stride
+# tests (Table-1 coverage counts stay on KERNELS; all_kernels() =
+# KERNELS + EXTRA_KERNELS)
 # ---------------------------------------------------------------------------
 
 EXTRA_KERNELS: List[SuiteKernel] = []

@@ -65,31 +65,6 @@ def device_peaks(device=None) -> Dict[str, float]:
                          f"{device.device_kind!r}") from None
 
 
-def from_telemetry(rows, peak_flops: float = None,
-                   mem_bw: float = None) -> list:
-    """Roofline rows from the dispatcher's *live* counters
-    (``cox.get_dispatcher().telemetry()``) instead of dry-run JSON:
-    each stage-key row's op/mem estimates against the peaks of the
-    device JAX runs on (:func:`device_peaks`) give t_compute/t_memory
-    and the dominant term.  The returned dicts
-    keep the telemetry fields (kernel, backend, warp_exec, chunk,
-    launches, gflops) so the bench JSON can embed them verbatim."""
-    peaks = device_peaks()
-    pf = peak_flops if peak_flops is not None else peaks["peak_flops"]
-    bw = mem_bw if mem_bw is not None else peaks["mem_bw"]
-    out = []
-    for rec in rows:
-        t_comp = rec.get("op_estimate", 0.0) / pf
-        t_mem = rec.get("mem_estimate", 0.0) / bw
-        dominant = "compute" if t_comp >= t_mem else "memory"
-        bound = max(t_comp, t_mem)
-        row = dict(rec)
-        row.update(t_compute=t_comp, t_memory=t_mem, dominant=dominant,
-                   roofline_fraction=(t_comp / bound if bound else 0.0))
-        out.append(row)
-    return out
-
-
 def model_flops(rec: Dict[str, Any]) -> float:
     """6·N(_active)·D over the step's global tokens."""
     n = rec.get("active_param_count") or rec.get("param_count") or 0
@@ -135,25 +110,6 @@ _SUGGEST = {
     "collective": "reshard to cut all-gathers (sequence-parallel residuals),"
                   " overlap DP all-reduce with backward, compress grads",
 }
-
-
-def emit_rows(path: str):
-    with open(path) as f:
-        recs = json.load(f)
-    for rec in recs:
-        if rec.get("mesh") != "16x16":
-            continue  # roofline table is single-pod per the brief
-        name = f"roofline.{rec['arch']}.{rec['shape']}"
-        if rec["status"] != "ok":
-            print(f"{name},0.0,status={rec['status']}")
-            continue
-        t = terms(rec)
-        us = max(t["t_compute"], t["t_memory"], t["t_collective"]) * 1e6
-        print(f"{name},{us:.1f},"
-              f"compute_s={t['t_compute']:.3e};mem_s={t['t_memory']:.3e};"
-              f"coll_s={t['t_collective']:.3e};dominant={t['dominant']};"
-              f"useful={t['useful']:.2f};fix={_SUGGEST[t['dominant']][:40]}",
-              flush=True)
 
 
 def markdown_table(path: str) -> str:

@@ -11,8 +11,8 @@ line of what it checked; a failed check raises and the process exits
 non-zero.  The last line of standard output is the JSON contract line
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}``.
 Without a TPU the script refuses to run: it never carries on with the
-CPU.  It reads nothing outside the checkout (the autotune cache is off)
-and makes its data from ``--seed``.
+CPU.  It reads nothing outside the checkout and makes its data from
+``--seed``.
 
 Phases (one chip):
 
@@ -514,7 +514,6 @@ def main(argv=None) -> int:
                          "over four chips")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
-    os.environ["COX_AUTOTUNE_CACHE"] = "off"
     dev = phase_device(args.chips)
     if args.chips == 4:
         phase_four_chips(FULL, args.seed)

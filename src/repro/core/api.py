@@ -19,10 +19,6 @@ from typing import Any, Dict, Optional, Sequence
 
 from jax.profiler import annotate_function
 
-from . import autotune  # noqa: F401  (cox.autotune — measured knob tuning)
-from . import autotune as _autotune  # distinct alias: make_request's
-#                                      autotune= knob shadows the module
-from . import costmodel  # noqa: F401  (cox.costmodel — op/mem estimates)
 from . import errors  # noqa: F401  (cox.errors — typed error hierarchy)
 from . import faults  # noqa: F401  (cox.faults — fault injection)
 from . import flat as _flat
@@ -120,8 +116,7 @@ class KernelFn:
                      chunk=None, warp_exec: str = "auto",
                      schedule: str = "auto",
                      n_resident: Optional[int] = None,
-                     donate: bool = False, device: Any = None,
-                     autotune: Optional[bool] = None
+                     donate: bool = False, device: Any = None
                      ) -> _streams.LaunchRequest:
         """Resolve the launch knobs and bind the arguments into a
         :class:`~repro.core.streams.LaunchRequest` — the unit the stream
@@ -130,20 +125,15 @@ class KernelFn:
         call site; staging and dispatch happen later, behind the
         dispatcher.
 
-        ``chunk=`` accepts an int (explicit, never overridden by the
-        autotuner), ``None`` (the heuristic default) or ``'auto'``
-        (tune the chunk by measurement).  ``autotune=True`` measures
-        every knob left on auto — candidate cells pruned by the cost
-        model, winners persisted in the on-disk cache
-        (``repro.core.autotune``) — and ``autotune=None`` defers to the
-        ``COX_AUTOTUNE`` env (plus ``chunk='auto'``, which always
-        tunes).
+        ``chunk=`` accepts an int >= 1 (explicit, honored verbatim) or
+        ``None`` (the heuristic default); anything else raises
+        ``ValueError``.
 
         ``schedule=`` picks the launch schedule: ``'auto'`` (default)
         lets the footprint verdict choose between the chunk-table walk
         and the grid-stride loop once argument shapes are bound;
         ``'chunked'``/``'grid_stride'`` force either (explicit, never
-        overridden by the autotuner), and ``n_resident=`` sizes the
+        overridden by the verdict), and ``n_resident=`` sizes the
         grid-stride wave (implies ``schedule='grid_stride'``).
 
         ``device=`` pins the launch to one XLA device (multi-device
@@ -166,13 +156,6 @@ class KernelFn:
                                      n_resident=n_resident, mesh=mesh)
         globals_, shapes, scalars = bind_kernel_args(ck, args)
         rl = _runtime.resolve_schedule(ck, rl, shapes)
-        tune = (autotune if autotune is not None
-                else (chunk == "auto" or _autotune.enabled()))
-        if tune:
-            rl = _autotune.tune(ck, token, rl, shapes=shapes,
-                                scalars=scalars, globals_=globals_,
-                                simd=simd, mesh=mesh, req_backend=backend,
-                                req_warp_exec=warp_exec)
         if donate:
             # fail at the call site, not at deferred staging
             check_donate_supported(rl.backend, ck.kernel.name)
@@ -190,8 +173,7 @@ class KernelFn:
                mesh=None, axis: str = "data", backend: str = "auto",
                chunk=None, warp_exec: str = "auto",
                schedule: str = "auto", n_resident: Optional[int] = None,
-               donate: bool = False,
-               device: Any = None, autotune: Optional[bool] = None,
+               donate: bool = False, device: Any = None,
                stream: Optional[Stream] = None) -> Dict[str, Any]:
         """Launch with backend dispatch (see ``repro.core.backends``):
         enqueue on the (default) stream and dispatch — the async CUDA
@@ -226,8 +208,7 @@ class KernelFn:
             mode=mode, simd=simd, warp_size=warp_size, mesh=mesh,
             axis=axis, backend=backend, chunk=chunk, warp_exec=warp_exec,
             schedule=schedule, n_resident=n_resident,
-            donate=donate, device=device, autotune=autotune,
-            stream=stream).arrays()
+            donate=donate, device=device, stream=stream).arrays()
 
     def launch_async(self, *, stream: Optional[Stream] = None,
                      **knobs) -> LaunchHandle:

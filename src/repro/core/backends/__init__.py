@@ -14,7 +14,7 @@ XLA program:
 * ``sharded`` — shard_map over a mesh axis × the same vmap executor
                 within each device, psum merge across devices.
 
-``repro.core.flat.choose_backend`` is the autotune heuristic (kernel
+``repro.core.flat.choose_backend`` is the backend heuristic (kernel
 features + grid size + mesh → backend name); ``get_backend`` resolves a
 name to its module.
 """

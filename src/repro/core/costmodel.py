@@ -1,136 +1,32 @@
-"""Per-launch cost model — op/mem estimates for every staged executable.
+"""Wave footprint model — the chunked-vs-grid-stride schedule verdict.
 
-tinygrad's ``ASTRunner`` (SNIPPETS.md §1) attaches ``op_estimate``/
-``mem_estimate`` to each compiled kernel and logs achieved GFLOPS per
-dispatch; this module is that idiom for COX launches.  Two estimate
-sources, cheapest first:
-
-* ``static`` — an IR walk: arithmetic-instruction count × threads for
-  ops, 2 × bound global bytes for memory (read+write traffic proxy).
-  No compile, no trace — cheap enough for the dispatcher to record on
-  every launch.
-* ``xla``    — the launch's *actual* staged program: lower + compile
-  abstractly (``jax.ShapeDtypeStruct`` args, no data) and read
-  ``hlo_analysis.xla_cost`` (``compiled.cost_analysis()``), falling
-  back to the while-aware HLO parse when the backend reports nothing.
-  One extra compile per distinct launch shape — the autotuner and the
-  benchmark harness use it; ``COX_COSTMODEL=xla`` forces it on the
-  dispatcher's telemetry too.
-
-Both carry the static *kernel features* the autotuner prunes with:
-shared-memory footprint, warp peel count, and collective density.
 ``chunk_footprint``/``stride_footprint`` are the wave residency models
 — per-block copies of global memory plus per-warp shared copies, with
 the chunked schedule additionally charged for its materialized O(grid)
 block-id table — and ``schedule_verdict`` turns them into the
-chunked-vs-grid-stride lowering decision (``COX_FOOTPRINT_BUDGET``
-overrides the budget so tests can force the stride path).
+chunked-vs-grid-stride lowering decision against ``FOOTPRINT_BUDGET``
+(tests monkeypatch the constant to force the stride path on small
+inputs).  Everything here is counted from kernel parameters and bound
+argument shapes: nothing compiles or traces.
 """
 from __future__ import annotations
 
-import dataclasses
-import os
-import threading
-from typing import Any, Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
-from . import kernel_ir as K
 from . import flat as _flat
-from .execute import CompiledKernel, walk_instrs
-
-# estimate source for the dispatcher's always-on telemetry.  'static'
-# (default) never compiles; 'xla' lowers each distinct launch shape once
-ENV_MODE = "COX_COSTMODEL"
+from .execute import CompiledKernel
 
 # residency budget for a chunked wave's schedule-dependent footprint —
 # the chunk× copies of global memory plus the materialized O(grid)
 # block-id table — sized to a desktop L3.  Launches whose chunked
 # footprint blows it are lowered to the grid-stride schedule
-# (schedule_verdict below); COX_FOOTPRINT_BUDGET overrides the value
-# (positive byte count) so tests/CI can force the grid-stride path on
-# small inputs.
+# (schedule_verdict below).
 FOOTPRINT_BUDGET = 64 << 20
-ENV_BUDGET = "COX_FOOTPRINT_BUDGET"
 
-# wave widths the residency sizer considers, widest first — the same
-# family as autotune.CHUNK_CANDIDATES so a grid-stride wave and a tuned
-# chunk are directly comparable cells
+# wave widths the residency sizer considers, widest first
 RESIDENT_CANDIDATES = (32, 16, 8, 4, 2, 1)
-
-
-@dataclasses.dataclass(frozen=True)
-class CostEstimate:
-    """One staged launch's cost record (the ASTRunner fields plus the
-    static features the autotuner prunes candidates with)."""
-    op_estimate: float        # FLOPs (or arith-op proxy) per dispatch
-    mem_estimate: float       # bytes touched per dispatch
-    coll_estimate: float      # collective bytes (sharded launches)
-    shared_footprint: int     # static shared-memory bytes per block
-    peel_count: int           # warp-graph peel blocks (batched-exec cost)
-    collective_density: float  # warp collectives per IR instruction
-    source: str               # 'xla' | 'static'
-
-    def gflops(self, seconds: float) -> float:
-        """Achieved GFLOPS for a measured wall time."""
-        if seconds <= 0:
-            return 0.0
-        return self.op_estimate / seconds / 1e9
-
-    def gbps(self, seconds: float) -> float:
-        """Achieved memory bandwidth (GB/s) for a measured wall time."""
-        if seconds <= 0:
-            return 0.0
-        return self.mem_estimate / seconds / 1e9
-
-
-_cache: Dict[tuple, CostEstimate] = {}
-_cache_lock = threading.Lock()
-_CACHE_MAX = 1024
-
-
-def telemetry_mode() -> str:
-    mode = os.environ.get(ENV_MODE, "static").strip().lower()
-    return mode if mode in ("static", "xla") else "static"
-
-
-def footprint_budget() -> int:
-    """The live residency budget: ``COX_FOOTPRINT_BUDGET`` (a positive
-    byte count, validated — garbage raises at the launch that reads it
-    rather than silently disabling the model) or the built-in
-    ``FOOTPRINT_BUDGET`` default."""
-    raw = os.environ.get(ENV_BUDGET)
-    if raw is None or not raw.strip():
-        return FOOTPRINT_BUDGET
-    try:
-        val = int(raw.strip())
-    except ValueError:
-        raise ValueError(
-            f"{ENV_BUDGET}={raw!r} is not an integer byte count") from None
-    if val <= 0:
-        raise ValueError(
-            f"{ENV_BUDGET}={raw!r} must be a positive byte count")
-    return val
-
-
-def kernel_features(ck: CompiledKernel) -> Tuple[int, int, float]:
-    """Static features: (shared bytes/block, peel count, collective
-    density).  Peels come from the compiled warp machines — a batched
-    PC machine runs every ``lax.switch`` branch, so peel-heavy kernels
-    price warp batching out; collective density is the fraction of
-    instructions that are warp collectives (the batched win scales
-    with it, BENCH_PR2.json)."""
-    shared = _flat.shared_footprint(ck.kernel)
-    from .regions import warp_peel_count
-    machines = (ck.machine if not ck.phases
-                else tuple(p.machine for p in ck.phases))
-    if not isinstance(machines, (tuple, list)):
-        machines = (machines,)
-    peels = sum(warp_peel_count(m) for m in machines)
-    instrs = list(walk_instrs(ck))
-    n_coll = sum(1 for s in instrs if isinstance(s, K.WarpCall))
-    density = n_coll / max(1, len(instrs))
-    return shared, peels, density
 
 
 def global_bytes(ck: CompiledKernel, shapes: Dict[str, tuple]) -> int:
@@ -155,7 +51,7 @@ def _per_block_bytes(ck: CompiledKernel, shapes: Dict[str, tuple], *,
     """One block's resident bytes in a vmap wave: its copy of global
     memory (the write-mask merge's cost) plus its shared memory — per
     warp when the batched plane copies it."""
-    shared, _, _ = kernel_features(ck)
+    shared = _flat.shared_footprint(ck.kernel)
     per_block = global_bytes(ck, shapes)
     per_block += shared * (n_warps if warp_exec == "batched" else 1)
     return per_block
@@ -214,7 +110,7 @@ def resident_slots(ck: CompiledKernel, shapes: Dict[str, tuple], *,
     clamped-chunk fallback's failure mode.  Grid-stride keeps the wave
     useful and spends the budget where width actually helps."""
     from .backends.plan import DEFAULT_CHUNK
-    budget = footprint_budget() if budget is None else int(budget)
+    budget = FOOTPRINT_BUDGET if budget is None else int(budget)
     floor = min(int(grid), DEFAULT_CHUNK)
     for width in RESIDENT_CANDIDATES:
         if width <= floor:
@@ -245,7 +141,7 @@ def schedule_verdict(ck: CompiledKernel, shapes: Dict[str, tuple], *,
     by construction)."""
     grid = int(grid)
     chunk = max(1, int(chunk))
-    budget = footprint_budget() if budget is None else int(budget)
+    budget = FOOTPRINT_BUDGET if budget is None else int(budget)
     if backend == "scan":
         if bid_table_bytes(grid, 1) > budget:
             return "grid_stride", 1
@@ -259,117 +155,3 @@ def schedule_verdict(ck: CompiledKernel, shapes: Dict[str, tuple], *,
     return "grid_stride", resident_slots(ck, shapes, grid=grid,
                                          n_warps=n_warps,
                                          warp_exec=warp_exec, budget=budget)
-
-
-def _static_estimate(ck: CompiledKernel, rl, shapes: Dict[str, tuple]
-                     ) -> CostEstimate:
-    shared, peels, density = kernel_features(ck)
-    instrs = list(walk_instrs(ck))
-    # arithmetic proxy: every non-structural instruction is ~1 op per
-    # thread; warp collectives cost ~log2(W) lane ops
-    arith = 0.0
-    for s in instrs:
-        if isinstance(s, K.WarpCall):
-            arith += max(1, int(np.log2(max(2, ck.warp_size))))
-        elif not isinstance(s, (K.Barrier,)):
-            arith += 1
-    threads = rl.grid.total * rl.block.total
-    gbytes = global_bytes(ck, shapes)
-    return CostEstimate(
-        op_estimate=arith * threads,
-        mem_estimate=2.0 * gbytes,
-        coll_estimate=0.0,
-        shared_footprint=shared, peel_count=peels,
-        collective_density=density, source="static")
-
-
-def _abstract_args(ck: CompiledKernel, shapes: Dict[str, tuple]):
-    """(globals, scalars) as ``ShapeDtypeStruct`` pytrees matching the
-    staged launcher's calling convention (flat 1-D globals)."""
-    import jax
-    from .types import ArraySpec
-    globals_: Dict[str, Any] = {}
-    scalars: Dict[str, Any] = {}
-    for spec in ck.kernel.params:
-        if isinstance(spec, ArraySpec):
-            shape = shapes.get(spec.name, (1,))
-            n = 1
-            for d in shape:
-                n *= int(d)
-            globals_[spec.name] = jax.ShapeDtypeStruct((n,), spec.dtype.jnp)
-        else:
-            scalars[spec.name] = jax.ShapeDtypeStruct((), spec.dtype.jnp)
-    return globals_, scalars
-
-
-def _xla_estimate(ck: CompiledKernel, rl, shapes: Dict[str, tuple], *,
-                  simd: bool, mesh, axis: str) -> CostEstimate:
-    import jax
-    from . import runtime as _runtime
-    from ..launch import hlo_analysis
-    _, fn = _runtime.build_traceable(ck, rl, simd=simd, mesh=mesh, axis=axis)
-    g, s = _abstract_args(ck, shapes)
-    compiled = jax.jit(fn).lower(g, s).compile()
-    cost = hlo_analysis.xla_cost(compiled)
-    flops = float(cost.get("flops", 0.0))
-    mem = float(cost.get("bytes accessed", 0.0))
-    coll = 0.0
-    if flops <= 0.0 or mem <= 0.0:
-        # some jaxlib builds report empty cost_analysis on CPU; fall
-        # back to the while-aware HLO parse (same numbers the dry-run
-        # bench JSON used to carry)
-        totals = hlo_analysis.analyze(compiled.as_text())
-        flops = flops if flops > 0.0 else float(totals.get("flops", 0.0))
-        mem = mem if mem > 0.0 else float(totals.get("out_bytes", 0.0))
-        coll = float(totals.get("coll_bytes", 0.0))
-    st = _static_estimate(ck, rl, shapes)
-    return CostEstimate(
-        op_estimate=flops if flops > 0.0 else st.op_estimate,
-        mem_estimate=mem if mem > 0.0 else st.mem_estimate,
-        coll_estimate=coll,
-        shared_footprint=st.shared_footprint, peel_count=st.peel_count,
-        collective_density=st.collective_density, source="xla")
-
-
-def estimate(ck: CompiledKernel, rl, shapes: Dict[str, tuple], *,
-             simd: bool = True, mesh=None, axis: str = "data",
-             mode: Optional[str] = None) -> CostEstimate:
-    """The cost record for one resolved launch shape, cached per
-    (kernel, knobs, shapes).  ``mode=None`` follows ``COX_COSTMODEL``
-    ('static' default); 'xla' lowers+compiles the staged program once
-    per shape and reads the backend's cost analysis.  Never raises —
-    an 'xla' failure degrades to the static walk."""
-    mode = telemetry_mode() if mode is None else mode
-    key = (id(ck), rl.backend, rl.mode, rl.warp_exec,
-           rl.grid.astuple(), rl.block.astuple(), rl.chunk,
-           getattr(rl, "schedule", "chunked"),
-           getattr(rl, "n_resident", None), simd,
-           mesh is not None, tuple(sorted(shapes.items())), mode)
-    with _cache_lock:
-        hit = _cache.get(key)
-        if hit is not None:
-            return hit
-    if mode == "xla":
-        try:
-            est = _xla_estimate(ck, rl, shapes, simd=simd, mesh=mesh,
-                                axis=axis)
-        except Exception:
-            est = _static_estimate(ck, rl, shapes)
-    else:
-        est = _static_estimate(ck, rl, shapes)
-    with _cache_lock:
-        _cache[key] = est
-        while len(_cache) > _CACHE_MAX:
-            _cache.pop(next(iter(_cache)))
-    return est
-
-
-def estimate_request(req, mode: Optional[str] = None) -> CostEstimate:
-    """:func:`estimate` keyed off a dispatcher ``LaunchRequest``."""
-    return estimate(req.ck, req.rl, req.shapes, simd=req.simd,
-                    mesh=req.mesh, axis=req.axis, mode=mode)
-
-
-def clear_cache() -> None:
-    with _cache_lock:
-        _cache.clear()

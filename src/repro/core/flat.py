@@ -182,7 +182,7 @@ def choose_warp_exec(kernel: K.Kernel, *, n_warps: int,
     grid-chunk × warps × lanes.  'serial' is the paper's Code 3
     inter-warp loop.
 
-    Heuristic ('auto', measured on the coverage suite — BENCH_PR2.json):
+    Heuristic ('auto', from CPU timings of the coverage suite):
     batch when the block has more than one warp, the kernel keeps
     per-block state in **shared memory** (the blockwise-internal-work
     signal, same as ``choose_backend``'s vmap test), the per-warp

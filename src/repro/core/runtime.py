@@ -23,6 +23,7 @@ functions of (bid, inputs), so any chunk can be re-executed anywhere;
 from __future__ import annotations
 
 import dataclasses
+import operator
 from typing import Any, Dict, Optional, Sequence
 
 import jax.numpy as jnp
@@ -45,14 +46,11 @@ class ResolvedLaunch:
     resolve identically.
 
     ``chunk``/``chunk_source`` carry the resolved vmap-wave width and
-    *where it came from*: ``'explicit'`` (caller passed ``chunk=``, the
-    autotuner must never override it), ``'heuristic'`` (defaulted to
-    ``min(grid, DEFAULT_CHUNK)``, fair game for measurement),
+    *where it came from*: ``'explicit'`` (caller passed ``chunk=``; the
+    footprint verdict never swaps the schedule underneath it),
+    ``'heuristic'`` (defaulted to ``min(grid, DEFAULT_CHUNK)``), or
     ``'cooperative'`` (pinned to ``grid`` by the all-resident grid-sync
-    rule), or ``'autotuned'`` (a measured winner).  Before this field
-    existed an explicit ``chunk=`` and the default were
-    indistinguishable downstream — the autotuner could have silently
-    overridden a user knob.
+    rule).
 
     ``schedule``/``n_resident``/``schedule_source`` mirror that design
     for the *launch schedule*: ``'chunked'`` walks a materialized
@@ -64,7 +62,7 @@ class ResolvedLaunch:
     passed ``schedule=``), ``'heuristic'`` (the footprint verdict,
     applied once argument shapes are bound), ``'cooperative'`` (a
     multi-phase grid beyond the resident capacity, grid-strided instead
-    of rejected), or ``'autotuned'`` (a measured winner)."""
+    of rejected)."""
     grid: Dim3
     block: Dim3
     backend: str    # 'scan' | 'vmap' | 'sharded'
@@ -72,7 +70,7 @@ class ResolvedLaunch:
     warp_exec: str  # 'serial' | 'batched'
     n_warps: int
     chunk: Optional[int] = None  # resolved blocks-per-wave (None: plan default)
-    chunk_source: str = "heuristic"  # 'explicit'|'heuristic'|'cooperative'|'autotuned'
+    chunk_source: str = "heuristic"  # 'explicit'|'heuristic'|'cooperative'
     schedule: str = "chunked"    # 'chunked' | 'grid_stride'
     n_resident: Optional[int] = None  # grid-stride wave width (None: chunked)
     schedule_source: str = "heuristic"  # same provenance set as chunk_source
@@ -81,14 +79,21 @@ class ResolvedLaunch:
 def resolve_chunk(ck: CompiledKernel, grid: int, chunk) -> tuple:
     """Resolve the ``chunk`` knob to ``(value, source)`` — the one place
     the explicit-vs-defaulted distinction is decided.  ``chunk`` accepts
-    an int (explicit — clamped to the grid but otherwise honored
-    verbatim, and never overridden by autotune), ``None``/'auto' (the
-    ``min(grid, DEFAULT_CHUNK)`` heuristic, tunable), with cooperative
-    launches pinning ``chunk == grid`` exactly as ``LaunchPlan.build``
-    enforces."""
-    auto = chunk is None or chunk == "auto"
+    an int >= 1 (explicit — clamped to the grid but otherwise honored
+    verbatim) or ``None`` (the ``min(grid, DEFAULT_CHUNK)`` heuristic);
+    anything else raises ``ValueError``.  Cooperative launches pin
+    ``chunk == grid`` exactly as ``LaunchPlan.build`` enforces."""
+    auto = chunk is None
+    if not auto:
+        try:
+            c = operator.index(chunk)   # ints only: no 'auto', no floats
+        except TypeError:
+            c = 0
+        if c < 1:
+            raise ValueError(f"chunk must be an int >= 1 or None, got "
+                             f"{chunk!r}")
     if ck.n_phases > 1:
-        if not auto and int(chunk) < grid:
+        if not auto and c < grid:
             raise CoxUnsupported(
                 f"cooperative launch of '{ck.kernel.name}': chunk={chunk} "
                 f"would split the grid into waves, but a grid barrier "
@@ -97,9 +102,6 @@ def resolve_chunk(ck: CompiledKernel, grid: int, chunk) -> tuple:
         return grid, "cooperative"
     if auto:
         return min(grid, DEFAULT_CHUNK), "heuristic"
-    c = int(chunk)
-    if c < 1:
-        raise ValueError(f"chunk must be >= 1, got {chunk!r}")
     return min(c, grid), "explicit"
 
 

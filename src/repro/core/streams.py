@@ -94,7 +94,6 @@ from typing import Any, Deque, Dict, List, Optional, Tuple
 import jax
 from jax.profiler import TraceAnnotation, annotate_function
 
-from . import costmodel as _costmodel
 from . import errors as _errors
 from . import faults as _faults
 from . import placement as _placement
@@ -131,11 +130,6 @@ RETRY_BACKOFF_S = 0.005
 # deadline-wait poll period: the watchdog timer marks the deadline; the
 # waiter polls readiness at this granularity (host-side, no device cost)
 DEADLINE_POLL_S = 0.001
-
-# per-stage-key telemetry retention (launch counts, dispatch time, cost
-# estimates) — bounded like every other long-lived dispatcher structure
-TELEMETRY_MAX = 512
-
 
 def _is_deleted(x) -> bool:
     """True for a jax.Array whose buffer was donated away (a later
@@ -699,12 +693,6 @@ class Dispatcher:
         self.stage_misses = 0
         self.stage_fn_hits = 0
         self.stage_fn_misses = 0
-        # ---- telemetry (README "Autotune & telemetry") ----
-        # per-stage-key live counters: launches, host dispatch seconds,
-        # estimated bytes/FLOPs (repro.core.costmodel) —
-        # benchmarks/roofline.py computes roofline position from these
-        # real counters instead of dry-run JSON
-        self._telemetry: "OrderedDict[tuple, Dict[str, Any]]" = OrderedDict()
         self._capturing: "weakref.WeakSet[Stream]" = weakref.WeakSet()
         # ---- fault tolerance (README "Error model & fault tolerance") ----
         # errored requests whose handle was dropped without a sync move
@@ -1238,14 +1226,11 @@ class Dispatcher:
                 raise _errors.classify(e, site="dispatch",
                                        what=f"kernel '{name}'")
         try:
-            t0 = time.perf_counter()
             with TraceAnnotation("cox.xla_call"):
                 outputs = exe(req.globals_, req.scalars)   # async dispatch
-            dispatch_s = time.perf_counter() - t0
         except Exception as e:
             raise _errors.classify(e, site="dispatch",
                                    what=f"kernel '{name}'")
-        self._note_telemetry(req, dispatch_s)
         if _faults.consume("timeout", name) is not None:
             req.injected_hang = True      # outputs never report ready
         return outputs
@@ -1605,95 +1590,28 @@ class Dispatcher:
                 return str(d)
         return f"device:{did}"
 
-    # ---------------- telemetry (per-stage-key live counters) --------------
-
-    @staticmethod
-    def _telemetry_key(req: LaunchRequest) -> tuple:
-        """Human-readable stage identity: one row per distinct
-        (kernel, backend, warp_exec, chunk, schedule, geometry,
-        device, argument shapes)."""
-        rl = req.rl
-        return (req.ck.kernel.name, rl.backend, rl.warp_exec,
-                rl.chunk, rl.schedule, rl.n_resident,
-                rl.grid.astuple(), rl.block.astuple(),
-                _dev_id(req.device), tuple(req.shapes.items()))
-
-    @staticmethod
-    def _telemetry_row(req: LaunchRequest) -> Dict[str, Any]:
-        """A fresh stage-key row.  Its cost estimate is fixed for the
-        row's key, so it is taken once, here, from ``repro.core.
-        costmodel`` ('static' by default — ``COX_COSTMODEL=xla``
-        upgrades to the compiled program's own cost analysis)."""
-        try:
-            est = _costmodel.estimate_request(req)
-        except Exception:       # pragma: no cover - estimate never raises
-            est = None
-        return {
-            "launches": 0, "dispatch_s": 0.0, "bytes": 0.0, "flops": 0.0,
-            "op_estimate": est.op_estimate if est else 0.0,
-            "mem_estimate": est.mem_estimate if est else 0.0,
-            "estimate_source": est.source if est else None,
-            "chunk_source": getattr(req.rl, "chunk_source", "heuristic"),
-            "schedule_source":
-                getattr(req.rl, "schedule_source", "heuristic"),
-        }
-
-    def _note_telemetry(self, req: LaunchRequest, dispatch_s: float) -> None:
-        """Record one dispatched launch against its stage-key row.
-        Never raises: telemetry must not be able to fail a launch."""
-        key = self._telemetry_key(req)
-        with self._lock:
-            rec = self._telemetry.get(key)
-        if rec is None:
-            rec = self._telemetry_row(req)    # the estimate: no _lock held
-        with self._lock:
-            rec = self._telemetry.setdefault(key, rec)
-            self._telemetry.move_to_end(key)
-            while len(self._telemetry) > TELEMETRY_MAX:
-                self._telemetry.popitem(last=False)
-            rec["launches"] += 1
-            rec["dispatch_s"] += dispatch_s
-            rec["bytes"] += rec["mem_estimate"]
-            rec["flops"] += rec["op_estimate"]
-
-    def telemetry(self) -> List[Dict[str, Any]]:
-        """The per-stage-key counter rows, with GFLOPS and GB/s derived
-        from host dispatch time (a lower bound on a launch's time)."""
-        with self._lock:
-            rows = [(k, dict(v)) for k, v in self._telemetry.items()]
-        out: List[Dict[str, Any]] = []
-        for (name, backend, warp_exec, chunk, schedule, n_resident,
-             grid, block, dev, shapes), rec in rows:
-            rec.update(kernel=name, backend=backend, warp_exec=warp_exec,
-                       chunk=chunk, schedule=schedule,
-                       n_resident=n_resident, grid=grid, block=block,
-                       device=dev, shapes=dict(shapes))
-            per = rec["dispatch_s"] / max(1, rec["launches"])
-            rec["s_per_launch"] = per
-            rec["gflops"] = (rec["op_estimate"] / per / 1e9) if per else 0.0
-            rec["gbps"] = (rec["mem_estimate"] / per / 1e9) if per else 0.0
-            out.append(rec)
-        return out
-
     def health(self) -> Dict[str, Any]:
         """Counters for monitoring a long-lived dispatcher — the serving
-        layer and the benchmark gate read these.  ``devices`` carries
-        the per-device dispatch/failure/degradation counters (the
-        chaos drill asserts a fault stays confined to one device);
-        ``sticky_devices`` the currently-poisoned devices; ``sticky``
-        stays the first sticky error's repr (or None) for backward
-        compatibility.  ``telemetry_keys``/``dispatch_s``/``bytes``
-        summarize the live per-stage-key counters (full rows via
-        :meth:`telemetry`); ``stage_hits``/``stage_misses`` count the
-        staging cache's lookups (a miss builds an executable, or a
-        graph's); ``autotune`` carries the knob-tuner's
-        hit/miss/measurement counters."""
+        layer, ``chip_smoke.py`` and the benchmark's COX loop read these.
+
+        * ``failures``/``retries``/``degradations``/``timeouts`` —
+          launches that failed for good, transient retries, ladder
+          fallbacks and watchdog deadline expiries, since construction;
+        * ``errored_retained`` — failed requests held for
+          ``get_last_error()`` (bounded by ``error_log_max``);
+        * ``inflight``/``pending`` — dispatched launches not yet synced,
+          and enqueued launches not yet dispatched;
+        * ``sticky`` — the first sticky error's repr (or None);
+          ``sticky_devices`` maps each poisoned device to its error;
+        * ``devices`` — per-device dispatch/failure/degradation counters
+          (the chaos drill asserts a fault stays confined to one device);
+        * ``watchdog_strikes`` — the watchdog's strike count (0 without
+          one);
+        * ``stage_hits``/``stage_misses`` — the staging cache's lookups
+          (a miss builds an executable, or a graph's)."""
         with self._lock:
             first_sticky = (repr(next(iter(self._sticky.values())))
                             if self._sticky else None)
-            schedules: Dict[str, int] = {}
-            for k in self._telemetry:        # k[4] is the schedule
-                schedules[k[4]] = schedules.get(k[4], 0) + 1
             return {
                 "failures": self.failures,
                 "retries": self.retries,
@@ -1709,25 +1627,9 @@ class Dispatcher:
                             for k, v in self._dev_counters.items()},
                 "watchdog_strikes": (self.watchdog.strikes
                                      if self.watchdog else 0),
-                "telemetry_keys": len(self._telemetry),
-                "schedules": schedules,
-                "dispatch_s": sum(r["dispatch_s"]
-                                  for r in self._telemetry.values()),
-                "bytes": sum(r["bytes"] for r in self._telemetry.values()),
                 "stage_hits": self.stage_hits,
                 "stage_misses": self.stage_misses,
-                "autotune": _autotune_stats(),
             }
-
-
-def _autotune_stats() -> Dict[str, int]:
-    """The knob-tuner's counters (lazy import: autotune pulls in the
-    cost model, which health probes must not pay for eagerly)."""
-    try:
-        from . import autotune as _autotune
-        return _autotune.stats()
-    except Exception:           # pragma: no cover - import always works
-        return {}
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,8 @@
 """JAX's persistent compilation cache for the entry points.
 
 Called by each entry point's ``main`` (``serve``, ``train``,
-``benchmarks/run.py``, ``chip_smoke.py``), never at import.  Where
+``chip_smoke.py``, ``bench/run.py`` and ``bench/control*.py``), never
+at import.  Where
 ``JAX_COMPILATION_CACHE_DIR`` is set, that directory is the cache and
 no other is set.  Otherwise the cache lives at the fixed
 ``.jax_cache/`` of the checkout (git-ignored): the path is part of what

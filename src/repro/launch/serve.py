@@ -474,16 +474,8 @@ def main():
                          "postprocess launch to fail and assert the "
                          "remaining slots complete with correct totals "
                          "(requires --postproc)")
-    ap.add_argument("--autotune", action="store_true",
-                    help="measure knob candidates for every all-auto "
-                         "launch (winners persisted in the on-disk "
-                         "autotune cache; a warm cache issues zero "
-                         "measurement launches)")
     args = ap.parse_args()
     compile_cache.enable()
-    if args.autotune:
-        import os
-        os.environ.setdefault("COX_AUTOTUNE", "1")
     out = serve_requests(args.arch, batch=args.batch, ctx=args.ctx,
                          n_requests=args.requests, max_tokens=args.tokens,
                          postproc=args.postproc, graph=args.graph,
@@ -506,14 +498,6 @@ def main():
             f"{name}: {c['dispatches']}d/{c['failures']}f/"
             f"{c['degradations']}g" for name, c in sorted(devs.items()))
         msg += f" [devices: {cells}]"
-    # autotune cache effectiveness: memory/disk hits vs measured misses
-    # plus the measurement-launch count (zero on a warm fleet) — the
-    # production signal that knob warmup amortized
-    at = out["dispatch_health"].get("autotune", {})
-    if at:
-        msg += (f" [autotune: {at.get('hits', 0)}h/"
-                f"{at.get('disk_hits', 0)}dh/{at.get('misses', 0)}m, "
-                f"{at.get('measurements', 0)} measured]")
     print(msg)
 
 

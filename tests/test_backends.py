@@ -19,6 +19,7 @@ from repro.core import flat as cox_flat
 from repro.core.kernel_ir import uses_grid_sync
 from repro.core.backends import available_backends, get_backend
 from repro.core.backends.plan import LaunchPlan
+from repro.core.runtime import resolve_launch
 from repro.core.types import CoxUnsupported
 
 RUNNABLE = [sk for sk in all_kernels() if sk.kernel is not None]
@@ -106,17 +107,41 @@ def test_warp_batched_bitwise_matches_serial(sk):
 
 
 @pytest.mark.parametrize("name", ["MatrixMulCUDA", "reduce0", "reduce4",
-                                  "histogram64", "blockCounter"])
+                                  "histogram64", "blockCounter",
+                                  "vectorAdd", "matrixMul1D", "transpose",
+                                  "stencil2d", "saxpyHeavy",
+                                  "warpPrefixStats", "gridReduce"])
 def test_warp_batched_composes_with_block_vmap(name):
     """grid-chunk × warp × lane batching all at once: the vmap backend
-    with batched warps must still equal scan with serial warps."""
+    with batched warps must still equal scan with serial warps.
+    Cooperative (grid-sync) kernels keep the plan's all-resident
+    wave instead of chunk=3."""
     sk = next(k for k in all_kernels() if k.name == name)
     args = sk.make_args()
+    coop = uses_grid_sync(sk.kernel.ir)
     want = _launch(sk, args, backend="scan", warp_exec="serial")
-    got = _launch(sk, args, backend="vmap", chunk=3, warp_exec="batched")
+    got = _launch(sk, args, backend="vmap", warp_exec="batched",
+                  **({} if coop else {"chunk": 3}))
     for k in want:
         np.testing.assert_array_equal(got[k], want[k],
                                       err_msg=f"{name}.{k}")
+
+
+@pytest.mark.parametrize("name", ["reduce4", "warpPrefixStats"])
+@pytest.mark.parametrize("warp_exec", ["serial", "batched"])
+def test_scalar_collectives_match_simd(name, warp_exec):
+    """Table 2's w/o-AVX baseline: the per-lane scalar collectives
+    (``simd=False``) on the scan backend equal the vectorised ones,
+    bitwise, under either warp execution (a warp-cg reduction and the
+    collective-dense shuffle/vote composite)."""
+    sk = next(k for k in all_kernels() if k.name == name)
+    args = sk.make_args()
+    want = _launch(sk, args, backend="scan", warp_exec="serial")
+    got = _launch(sk, args, backend="scan", warp_exec=warp_exec,
+                  simd=False)
+    for k in want:
+        np.testing.assert_array_equal(got[k], want[k],
+                                      err_msg=f"{sk.name}.{k}")
 
 
 def test_warp_batched_on_one_device_mesh():
@@ -326,6 +351,14 @@ def test_launch_plan_requires_resolved_knobs():
         LaunchPlan.build(ck, grid=2, block=64, warp_exec="auto")
     plan = LaunchPlan.build(ck, grid=2, block=64)
     assert plan.warp_exec == "serial" and plan.mode == "normal"
+
+
+@pytest.mark.parametrize("chunk", ["auto", 0, -3, 2.5])
+def test_bad_chunk_is_rejected(chunk):
+    # chunk= takes an int >= 1 or None; 'auto' is no chunk value
+    ck = _k_id.compiled(block=64)
+    with pytest.raises(ValueError, match="chunk must be an int >= 1"):
+        resolve_launch(ck, grid=4, block=64, chunk=chunk)
 
 
 def test_launch_cache_splits_on_warp_exec():

@@ -247,6 +247,40 @@ def test_replay_with_rebound_inputs():
                               np.asarray(res["out"]))
 
 
+@cox.kernel
+def _half_step(c, out: cox.Array(cox.f32), x: cox.Array(cox.f32),
+               y: cox.Array(cox.f32), n: cox.i32):
+    i = c.block_idx() * c.block_dim() + c.thread_idx()
+    if i < n:
+        out[i] = 0.5 * x[i] + y[i]
+
+
+@pytest.mark.parametrize("depth", [1, 4, 16])
+def test_chain_replay_rebound_bitwise_equals_eager(depth):
+    # a depth-d chain of dependent launches captured once, then replayed
+    # with its input rebound to the previous output, three steps deep:
+    # each step bitwise-equal to issuing the chain eagerly
+    d, s, _ = _fresh()
+    o, x0, y, n = _args()
+
+    def chain(x):
+        h = s.launch(_half_step, grid=8, block=256, args=(o, x, y, n))
+        for _ in range(depth - 1):
+            h = s.launch(_half_step, grid=8, block=256,
+                         args=(o, h.outputs["out"], y, n))
+        return h
+
+    g = cox.Graph()
+    with g.capture(s):
+        chain(x0)
+    exe = g.instantiate()
+    xe = xg = x0
+    for _ in range(3):
+        xe = np.asarray(chain(xe).result()["out"])
+        xg = np.asarray(exe.replay(x=xg)["out"])
+        np.testing.assert_array_equal(xg, xe)
+
+
 def test_replay_rejects_unknown_input():
     d, s, _ = _fresh()
     o, x, y, n = _args()

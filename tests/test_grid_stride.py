@@ -3,15 +3,16 @@
 The tentpole contract: ``schedule='grid_stride'`` runs a fixed number
 of resident block slots (``n_resident``) that loop over strided block
 ids, so the host never materializes the O(grid) chunk tables and the
-per-wave working set stays inside ``COX_FOOTPRINT_BUDGET`` regardless
-of grid size.  Wave *i* covers exactly the contiguous bids of chunk
-row *i* of a ``chunk=n_resident`` chunked schedule, so the two are
-bitwise-identical by construction — verified here across all three
-backends × both warp-exec flavors, atomics, a partial last wave, dim3
-grids, captured-graph replay, and placed multi-device runs.  The
-footprint verdict (``costmodel.schedule_verdict``), its provenance
-(``schedule_source``), the ``COX_FOOTPRINT_BUDGET`` override, and the
-autotuner's grid-stride candidate cells are pinned alongside.
+per-wave working set stays inside ``costmodel.FOOTPRINT_BUDGET``
+regardless of grid size.  Wave *i* covers exactly the contiguous bids
+of chunk row *i* of a ``chunk=n_resident`` chunked schedule, so the two
+are bitwise-identical by construction — verified here across every
+runnable suite kernel, all three backends × both warp-exec flavors,
+atomics, a partial last wave, dim3 grids, captured-graph replay, and
+placed multi-device runs.  The footprint verdict
+(``costmodel.schedule_verdict``) and its provenance
+(``schedule_source``) are pinned alongside; tests that need a small
+budget monkeypatch the constant.
 """
 import os
 import subprocess
@@ -23,12 +24,10 @@ import pytest
 
 from benchmarks.kernels_suite import all_kernels
 from repro.core import cox
-from repro.core import autotune as _autotune
 from repro.core import costmodel
 from repro.core.backends.plan import DEFAULT_CHUNK, LaunchPlan
 from repro.core.runtime import resolve_launch, resolve_schedule
 from repro.core.streams import Dispatcher, Stream
-from repro.core.types import CoxUnsupported
 from repro.launch.mesh import make_mesh
 
 jax = pytest.importorskip("jax")
@@ -36,6 +35,7 @@ jax = pytest.importorskip("jax")
 VECTOR_ADD = next(k for k in all_kernels() if k.name == "vectorAdd")
 HISTOGRAM = next(k for k in all_kernels() if k.name == "histogram64")
 GRID_REDUCE = next(k for k in all_kernels() if k.name == "gridReduce")
+RUNNABLE = [sk for sk in all_kernels() if sk.kernel is not None]
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -77,6 +77,21 @@ def test_stride_matches_chunked_bitwise(backend, warp_exec):
     got = _np(_saxpy.launch(**kw, schedule="grid_stride", n_resident=3))
     np.testing.assert_array_equal(got["out"], want["out"],
                                   err_msg=f"{backend}/{warp_exec}")
+
+
+@pytest.mark.parametrize("sk", RUNNABLE, ids=lambda sk: sk.name)
+def test_suite_stride_matches_chunked(sk):
+    # every runnable suite kernel: a two-slot grid-stride wave against
+    # the chunk-table walk, same arguments, bitwise (cooperative kernels
+    # page their blocks through the two slots phase by phase)
+    args = sk.make_args()
+    kw = dict(grid=sk.grid, block=sk.block, args=args)
+    want = _np(sk.kernel.launch(**kw, schedule="chunked"))
+    got = _np(sk.kernel.launch(**kw, schedule="grid_stride", n_resident=2))
+    assert got.keys() == want.keys()
+    for k in want:
+        np.testing.assert_array_equal(got[k], want[k],
+                                      err_msg=f"{sk.name}.{k}")
 
 
 @pytest.mark.parametrize("warp_exec", ["serial", "batched"])
@@ -224,7 +239,7 @@ def test_oversubscribed_grid_never_materializes_table_over_budget(
     # fit, so the verdict must stride — and the stride footprint is
     # grid-independent, inside budget by construction.
     budget = 64 << 10
-    monkeypatch.setenv(costmodel.ENV_BUDGET, str(budget))
+    monkeypatch.setattr(costmodel, "FOOTPRINT_BUDGET", budget)
     grid = 1 << 20
     ck = _saxpy.compiled(block=64)
     rl = resolve_launch(ck, grid=grid, block=64)
@@ -257,7 +272,7 @@ def test_oversubscribed_launch_runs_and_matches(monkeypatch):
     args = _saxpy_args(grid, block, seed=6)
     want = _np(_saxpy.launch(grid=grid, block=block, args=args,
                              backend="vmap"))
-    monkeypatch.setenv(costmodel.ENV_BUDGET, "64")
+    monkeypatch.setattr(costmodel, "FOOTPRINT_BUDGET", 64)
     req = _saxpy.make_request(grid=grid, block=block, args=args,
                               backend="vmap")
     assert req.rl.schedule == "grid_stride"
@@ -283,7 +298,7 @@ def test_scan_verdict_keys_on_the_bid_sequence_alone():
 
 
 def test_explicit_schedule_is_never_overridden(monkeypatch):
-    monkeypatch.setenv(costmodel.ENV_BUDGET, "64")
+    monkeypatch.setattr(costmodel, "FOOTPRINT_BUDGET", 64)
     grid, block = 16, 64
     args = _saxpy_args(grid, block)
     req = _saxpy.make_request(grid=grid, block=block, args=args,
@@ -311,101 +326,3 @@ def test_explicit_grid_stride_without_width_gets_the_sized_wave():
     assert req.rl.schedule == "grid_stride"
     assert req.rl.n_resident is not None
     assert 1 <= req.rl.n_resident <= grid
-
-
-# ---------------------------------------------------------------------------
-# COX_FOOTPRINT_BUDGET: validated override
-# ---------------------------------------------------------------------------
-
-
-def test_budget_env_validation(monkeypatch):
-    monkeypatch.delenv(costmodel.ENV_BUDGET, raising=False)
-    assert costmodel.footprint_budget() == costmodel.FOOTPRINT_BUDGET
-    monkeypatch.setenv(costmodel.ENV_BUDGET, "1048576")
-    assert costmodel.footprint_budget() == 1048576
-    monkeypatch.setenv(costmodel.ENV_BUDGET, "lots")
-    with pytest.raises(ValueError, match="integer byte count"):
-        costmodel.footprint_budget()
-    monkeypatch.setenv(costmodel.ENV_BUDGET, "0")
-    with pytest.raises(ValueError, match="positive"):
-        costmodel.footprint_budget()
-    monkeypatch.setenv(costmodel.ENV_BUDGET, "-3")
-    with pytest.raises(ValueError, match="positive"):
-        costmodel.footprint_budget()
-    monkeypatch.setenv(costmodel.ENV_BUDGET, "  ")
-    assert costmodel.footprint_budget() == costmodel.FOOTPRINT_BUDGET
-
-
-# ---------------------------------------------------------------------------
-# autotune: grid-stride cells replace the blind chunk clamp
-# ---------------------------------------------------------------------------
-
-
-def test_autotune_candidates_stride_when_no_chunk_fits(monkeypatch):
-    monkeypatch.setenv(costmodel.ENV_BUDGET, str(4 << 10))
-    ck = _saxpy.compiled(block=64)
-    rl = resolve_launch(ck, grid=4096, block=64, backend="vmap",
-                        warp_exec="serial")
-    shapes = {"out": (256,), "x": (256,), "y": (256,)}
-    rl = resolve_schedule(ck, rl, shapes)
-    assert rl.schedule == "grid_stride"
-    # every chunked cell is over budget (bid table >= 16 KiB) …
-    assert _autotune._chunk_candidates(ck, rl, shapes, warp_exec="serial",
-                                       tunable_chunk=True,
-                                       allow_empty=True) == []
-    # … so the candidate set is pure grid-stride
-    cands = _autotune._candidates(ck, rl, shapes,
-                                  tunable=(False, False, True, True))
-    assert cands, "no candidates"
-    assert all(c.schedule == "grid_stride" for c in cands)
-    assert all(c.label.split("/")[-1].startswith("gs") for c in cands)
-    # widths come from the cost-model sizer (plus the resolver's own
-    # pick) — never wider, and in particular never the O(grid) table
-    exp = {costmodel.resident_slots(ck, shapes, grid=4096,
-                                    n_warps=rl.n_warps,
-                                    warp_exec="serial"), rl.n_resident}
-    assert {c.n_resident for c in cands} <= exp
-
-
-def test_autotune_clamp_survives_only_when_chunked_is_pinned(monkeypatch):
-    # schedule='chunked' pins the table walk; with nothing fitting the
-    # budget the old clamp remains the last resort (wave-only term)
-    monkeypatch.setenv(costmodel.ENV_BUDGET, "64")
-    ck = _saxpy.compiled(block=64)
-    rl = resolve_launch(ck, grid=4096, block=64, backend="vmap",
-                        warp_exec="serial", schedule="chunked")
-    shapes = {"out": (256,), "x": (256,), "y": (256,)}
-    chunks = _autotune._chunk_candidates(ck, rl, shapes,
-                                         warp_exec="serial",
-                                         tunable_chunk=True)
-    assert chunks == [1]
-    cands = _autotune._candidates(ck, rl, shapes,
-                                  tunable=(False, False, True, False))
-    assert all(c.schedule == "chunked" for c in cands)
-
-
-# ---------------------------------------------------------------------------
-# telemetry: schedule provenance reaches the dispatcher rows
-# ---------------------------------------------------------------------------
-
-
-def test_telemetry_records_schedule_and_provenance():
-    d = Dispatcher()
-    s = Stream("tel", d)
-    grid, block = 10, 64
-    args = _saxpy_args(grid, block, seed=8)
-    s.launch(_saxpy, grid=grid, block=block, args=args, backend="vmap",
-             schedule="grid_stride", n_resident=3).result()
-    s.launch(_saxpy, grid=grid, block=block, args=args,
-             backend="vmap").result()
-    rows = d.telemetry()
-    by_sched = {r["schedule"]: r for r in rows
-                if r["kernel"] == "_saxpy"}
-    assert "grid_stride" in by_sched and "chunked" in by_sched
-    gs = by_sched["grid_stride"]
-    assert gs["n_resident"] == 3
-    assert gs["schedule_source"] == "explicit"
-    assert by_sched["chunked"]["n_resident"] is None
-    health = d.health()
-    assert health["schedules"]["grid_stride"] >= 1
-    assert health["schedules"]["chunked"] >= 1

@@ -89,6 +89,22 @@ def test_round_robin_spread_and_bitwise_equality():
     """)
 
 
+def test_pool_sizes_bitwise_equal_to_one_device_pool():
+    # the same 4-stream program over 1/2/4/8-device pools (8 forced
+    # host devices): every pool's outputs are bitwise-equal to the
+    # 1-device pool's, and min(k, 4) devices take dispatches
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    r = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmarks", "placement_worker.py")],
+        capture_output=True, text=True, env=env, timeout=900, cwd=ROOT)
+    assert r.returncode == 0, f"worker failed:\n{r.stdout}\n{r.stderr}"
+    for k in (1, 2, 4, 8):
+        assert f"placement.devices_{k}: " in r.stdout, r.stdout
+    assert "placement-ok" in r.stdout
+
+
 def test_cross_device_event_and_data_edges():
     # producer pinned to device 0, consumer pinned to device 1: the
     # data edge crosses devices through an explicit transfer, the event
