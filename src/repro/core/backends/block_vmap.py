@@ -3,11 +3,16 @@
 COX's host runtime (paper §4) forks one pthread per CUDA block because
 blocks are independent between grid-wide syncs.  This backend is the
 XLA rendition of that observation: ``jax.vmap`` over the compiled block
-function runs a *chunk* of blocks simultaneously — each on its own copy
-of global memory with write-mask/atomic-delta tracking — and the copies
-are reconciled by the shared merge module (single-writer stores selected
-exactly, atomic deltas summed).  An outer ``lax.scan`` walks the chunks
-so memory stays bounded at ``chunk × |globals|``.
+function runs a *chunk* of blocks simultaneously, and an outer
+``lax.scan`` (or grid-stride ``fori_loop``) walks the chunks.  Each
+block of a wave returns only what it can write: a copy and write mask
+of each array it stores to through the copy path, delta buffers of its
+atomic targets, and (index, value) store logs of its wave-logged arrays
+(``execute.wave_logged``: stored, never reloaded).  The wave merges
+them onto the carried globals — copies by the shared merge module's
+single-writer select, deltas summed, logs scattered in program order —
+and read-only globals pass through untouched.  A wave's working set is
+chunk × |copy-path arrays| plus chunk × the logged stores.
 
 The chunk axis is what exposes inter-block parallelism to the host
 scheduler: XLA sees wide batched array ops instead of a length-`grid`
@@ -28,22 +33,58 @@ from .plan import LaunchPlan
 name = "vmap"
 
 
-def _merge_wave(plan: LaunchPlan, block_fn, bids, g,
+def _merge_wave(plan: LaunchPlan, block_fn, bids, carry,
                 scalars: Dict[str, Any], *, fold_deltas: bool):
-    """One vmap wave over ``bids`` (-1 marking pad slots) + the
-    write-mask/atomic-delta merge — the body both the chunk-table walk
-    and the grid-stride loop run, so the two schedules are the same
-    computation over the same wave contents."""
+    """One vmap wave over ``bids`` (-1 marking pad slots) and its merge
+    into ``carry = (globals, mask_acc, delta_acc)`` — the body both the
+    chunk-table walk and the grid-stride loop run, so the two schedules
+    are the same computation over the same wave contents.
+
+    ``block_fn`` is a tracked single-phase block function, whose
+    outputs are the wave form of ``execute.make_block_fn``.  Copy-path
+    arrays merge by single-writer select, atomic targets by summed
+    deltas, and each wave-logged store instruction lands as one flat
+    scatter over the chunk's lanes, in program order (the single-writer
+    contract makes different blocks' entries disjoint; a later store of
+    the same block overwrites an earlier one).  Under tracking
+    (``fold_deltas=False``) the accumulators gather the wave's masks —
+    a logged store's indices set its mask with one scatter of ``True``
+    — and deltas for ``merge.cross_device_merge``."""
+    g, m_acc, d_acc = carry
     u = plan.uniforms(bids, scalars)                # bid: (chunk,)
     u_axes = {k: (0 if k == "bid" else None) for k in u}
-    g2, m2, d2 = jax.vmap(lambda uu, gg: block_fn(uu, gg),
-                          in_axes=(u_axes, None))(u, g)
+    g2, m2, d2, logs = jax.vmap(block_fn, in_axes=(u_axes, None))(u, g)
     # pad slots (bid < 0) ran with garbage indices; their writes are
-    # discarded by zeroing the masks/deltas before the merge
-    valid = (bids >= 0)[:, None]
-    m2 = {k: v & valid for k, v in m2.items()}
-    d2 = {k: jnp.where(valid, v, 0) for k, v in d2.items()}
-    return merge.merge_chunk(g, g2, m2, d2, fold_deltas=fold_deltas)
+    # discarded by zeroing the masks/deltas before the merge and by
+    # pointing their log entries one past the end, where they drop
+    valid = bids >= 0
+    m2 = {k: v & valid[:, None] for k, v in m2.items()}
+    d2 = {k: jnp.where(valid[:, None], v, 0) for k, v in d2.items()}
+    with jax.named_scope("cox_merge"):
+        g_new, wrote, dsum = merge.merge_chunk(g, g2, m2, d2,
+                                               fold_deltas=fold_deltas)
+    g = {**g, **g_new}
+    if not fold_deltas:
+        m_acc = {**m_acc, **{k: m_acc[k] | w for k, w in wrote.items()}}
+        d_acc = {**d_acc, **{k: d_acc[k] + d for k, d in dsum.items()}}
+    with jax.named_scope("cox_wave_log"):
+        for k, entries in logs.items():
+            for idx, val in entries:            # (chunk, n_warps, W) each
+                flat = jnp.where(valid[:, None, None], idx,
+                                 jnp.int32(g[k].shape[0])).reshape(-1)
+                g[k] = g[k].at[flat].set(val.reshape(-1), mode="drop")
+                if not fold_deltas:
+                    m_acc[k] = m_acc[k].at[flat].set(True, mode="drop")
+    return g, m_acc, d_acc
+
+
+def _accumulators(plan: LaunchPlan, globals_, fold_deltas: bool):
+    """The wave loop's starting carry: the globals, plus zero masks and
+    deltas where the caller tracks them (the sharded backend)."""
+    if fold_deltas:
+        return globals_, {}, {}
+    return (globals_, merge.zeros_masks(globals_),
+            merge.zeros_deltas(globals_) if plan.has_atomics else {})
 
 
 def run_chunked(plan: LaunchPlan, block_fn, bid_chunks, globals_,
@@ -57,21 +98,12 @@ def run_chunked(plan: LaunchPlan, block_fn, bid_chunks, globals_,
     them to :func:`merge.cross_device_merge`; the single-device caller
     ignores them (``fold_deltas=True`` applies deltas in-line).
     """
-    track = not fold_deltas
-    masks0 = merge.zeros_masks(globals_) if track else {}
-    deltas0 = (merge.zeros_deltas(globals_)
-               if track and plan.has_atomics else {})
-
     def chunk_step(carry, bids):
-        g, m_acc, d_acc = carry
-        g, wrote, dsum = _merge_wave(plan, block_fn, bids, g, scalars,
-                                     fold_deltas=fold_deltas)
-        if track:
-            m_acc = {k: m_acc[k] | wrote[k] for k in m_acc}
-            d_acc = {k: d_acc[k] + dsum[k] for k in d_acc}
-        return (g, m_acc, d_acc), None
+        return _merge_wave(plan, block_fn, bids, carry, scalars,
+                           fold_deltas=fold_deltas), None
 
-    (g, m, d), _ = lax.scan(chunk_step, (globals_, masks0, deltas0),
+    (g, m, d), _ = lax.scan(chunk_step,
+                            _accumulators(plan, globals_, fold_deltas),
                             jnp.asarray(bid_chunks))
     return g, m, d
 
@@ -84,7 +116,7 @@ def run_strided(plan: LaunchPlan, block_fn, globals_,
     resident waves, each wave a vmap over ``n_resident`` block slots
     whose ids are computed in-graph (``plan.stride_bids``) — no
     ``(n_chunks, chunk)`` table is ever materialized, so the working
-    set is ``n_resident × |globals|`` regardless of grid size.
+    set is one wave's regardless of grid size.
 
     Wave *i* covers the contiguous ids ``base + [i·R, (i+1)·R)`` —
     exactly row *i* of the chunk table a chunked plan with ``chunk=R``
@@ -93,28 +125,18 @@ def run_strided(plan: LaunchPlan, block_fn, globals_,
     (``base`` may be a traced ``axis_index`` offset); the defaults
     cover the whole grid.  Returns ``(globals, masks, deltas)`` exactly
     like :func:`run_chunked`."""
-    track = not fold_deltas
-    masks0 = merge.zeros_masks(globals_) if track else {}
-    deltas0 = (merge.zeros_deltas(globals_)
-               if track and plan.has_atomics else {})
     total = plan.grid if total is None else int(total)
     n_waves = plan.n_stride_waves(total)
     limit = jnp.minimum(jnp.asarray(base, jnp.int32) + jnp.int32(total),
                         jnp.int32(plan.grid))
 
     def wave_step(i, carry):
-        g, m_acc, d_acc = carry
         bids = plan.stride_bids(i, base=base, limit=limit)
-        g, wrote, dsum = _merge_wave(plan, block_fn, bids, g, scalars,
-                                     fold_deltas=fold_deltas)
-        if track:
-            m_acc = {k: m_acc[k] | wrote[k] for k in m_acc}
-            d_acc = {k: d_acc[k] + dsum[k] for k in d_acc}
-        return (g, m_acc, d_acc)
+        return _merge_wave(plan, block_fn, bids, carry, scalars,
+                           fold_deltas=fold_deltas)
 
-    g, m, d = lax.fori_loop(0, n_waves, wave_step,
-                            (globals_, masks0, deltas0))
-    return g, m, d
+    return lax.fori_loop(0, n_waves, wave_step,
+                         _accumulators(plan, globals_, fold_deltas))
 
 
 def run_phase_wave(plan: LaunchPlan, fn, bids, globals_, scalars, state,

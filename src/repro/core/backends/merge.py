@@ -18,6 +18,14 @@ warp-batched execution (``execute.py``'s ``(n_warps, W)`` lane plane)
   multi-device atomics at all;
 * elements nobody touched keep the carried-in value.
 
+A vmap wave of blocks (``block_vmap._merge_wave``) hands this module
+copies only of the arrays that need them: a global no instruction
+writes has no copy, an atomic-only target brings its deltas alone, and
+a wave-logged array (``execute.wave_logged``: stored, never reloaded)
+comes as (index, value) store logs that the wave scatters onto the
+carry itself — the same single-writer contract, without a per-block
+copy of the array.
+
 Delta buffers live in the "numeric image" of the array dtype
 (:func:`num` — bool promotes to int32 so masks/flags can be atomic
 targets); :func:`denum` maps merged values back.
@@ -115,26 +123,37 @@ def merge_chunk(g: Dict[str, Any], chunk_g: Dict[str, Any],
     ``g``.  The copy axis is a chunk of blocks (grid backends) or the
     warp axis of a batched (n_warps, W) plane (``execute.py``).
 
-    Returns ``(g_new, wrote_any, delta_sum)`` where ``wrote_any`` is the
-    per-array union of the copies' write masks and ``delta_sum`` the
-    per-array summed deltas (numeric image; empty when the kernel has no
-    atomics).  With ``fold_deltas=True`` the summed deltas are applied
-    to ``g_new`` directly (single-device semantics); with ``False`` the
-    caller owns them (the cross-device ``psum`` path, or the grid
-    backends' mask/delta accumulators above a warp-plane merge).
+    Only the arrays that have copies (``chunk_g``, with masks in
+    ``chunk_m``) or deltas (``chunk_d``) are merged; an array with
+    deltas and no copies — an atomic-only target in a vmap wave — keeps
+    the carry and takes the summed deltas, with no mask or select.
+
+    Returns ``(g_new, wrote_any, delta_sum)`` for the merged arrays:
+    ``wrote_any`` is the per-array union of the copies' write masks (for
+    arrays with copies) and ``delta_sum`` the per-array summed deltas
+    (numeric image; empty when the kernel has no atomics).  With
+    ``fold_deltas=True`` the summed deltas are applied to ``g_new``
+    directly (single-device semantics); with ``False`` the caller owns
+    them (the cross-device ``psum`` path, or the grid backends'
+    mask/delta accumulators above a warp-plane merge).
     """
     out: Dict[str, Any] = {}
     wrote: Dict[str, Any] = {}
     dsum: Dict[str, Any] = {}
     for k in g:
-        new, any_w = select_writer(g[k], chunk_g[k], chunk_m[k], axis=axis)
+        if k in chunk_g:
+            new, wrote[k] = select_writer(g[k], chunk_g[k], chunk_m[k],
+                                          axis=axis)
+        elif k in chunk_d:
+            new = g[k]
+        else:
+            continue
         if k in chunk_d:
             d = jnp.sum(num(chunk_d[k]), axis=axis)
             dsum[k] = d
             if fold_deltas:
                 new = denum(num(new) + d, g[k].dtype)
         out[k] = new
-        wrote[k] = any_w
     return out, wrote, dsum
 
 

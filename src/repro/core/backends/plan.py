@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import kernel_ir as K
-from ..execute import CompiledKernel, make_block_fn, walk_instrs
+from ..execute import CompiledKernel, make_block_fn, walk_instrs, wave_logged
 from ..types import (COOP_MAX_RESIDENT_BLOCKS, ArraySpec, CoxUnsupported,
                      Dim3, DType, GraphRef, as_dim3, check_launch_geometry)
 
@@ -213,6 +213,15 @@ class LaunchPlan:
                 f"backend's delta merge cannot reproduce — launch "
                 f"without a mesh and use backend='scan' (the "
                 f"single-device 'auto' heuristic picks it)")
+
+    @property
+    def wave_logged(self) -> Tuple[str, ...]:
+        """Globals a vmap wave of this plan carries as (index, value)
+        store logs instead of per-block copies (``execute.wave_logged``;
+        empty for cooperative launches, whose phase waves keep the copy
+        path)."""
+        return wave_logged(self.ck, n_warps=self.n_warps,
+                           warp_exec=self.warp_exec)
 
     # ---------------- phase staging (cooperative grid sync) ----------------
 

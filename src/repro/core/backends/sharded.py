@@ -69,8 +69,8 @@ def _build_strided_fn(plan: LaunchPlan, mesh, axis: str, block_fn):
     results match the chunked schedule bitwise) and loops its slice in
     waves of ``n_resident`` with ids computed from ``lax.axis_index``
     inside the staged program.  No ``(ndev, per)`` table is built or
-    shipped; the per-device working set is ``n_resident × |globals|``
-    regardless of grid size."""
+    shipped; the per-device working set is one wave's regardless of
+    grid size."""
     ndev = mesh.shape[axis]
     per = -(-plan.grid // ndev)
 

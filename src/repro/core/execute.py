@@ -690,6 +690,32 @@ def _pr_plan(ck: CompiledKernel, node: BlockPR) -> _PRPlan:
                    tuple(sorted(logged)))
 
 
+def wave_logged(ck: CompiledKernel, *, n_warps: int,
+                warp_exec: str) -> Tuple[str, ...]:
+    """Globals a vmap wave carries as (index, value) store logs instead
+    of per-block copies of global memory.
+
+    An array qualifies when every block-level PR that stores to it puts
+    it on the batched plane's store-log path (``_PRPlan.logged``), no PR
+    loads it and it is no atomic target: no block ever reads it back, so
+    the merged array is the carry with each block's logged stores
+    applied in program order.  The block-level machine must be linear —
+    log entries made inside its PC machine's ``lax.switch`` branches
+    cannot escape the while loop."""
+    if ck.phases or warp_exec != "batched" or n_warps <= 1:
+        return ()
+    if _try_linear_block(ck.machine) is None:
+        return ()
+    plans = [_pr_plan(ck, n) for n in ck.machine.nodes
+             if isinstance(n, BlockPR)]
+    logged = {a for p in plans for a in p.logged}
+    copied = {a for p in plans for a in p.masked}
+    loads: Set[str] = set()
+    for blk in ck.cfg.blocks.values():
+        loads |= _loaded_globals(blk.instrs)
+    return tuple(sorted(logged - copied - loads))
+
+
 def _exec_masked_while(ins: K.While, env: _Env, mask, *, jit_mode: bool):
     """Barrier-free loop with potentially lane-divergent trip counts:
     iterate while any lane is active, with per-lane masking (the
@@ -869,6 +895,14 @@ def make_block_fn(ck: CompiledKernel, *, n_warps: int, mode: str = "jit",
     per-warp copies of shared/global memory merged at each PR boundary
     (see the module docstring).  ``'serial'`` is the paper's Code 3
     inter-warp loop.
+
+    ``track_writes=True`` without ``persist`` builds a vmap grid
+    wave's block, which returns only what it can write: ``(globals,
+    masks, deltas, logs)`` with globals and masks for the arrays on the
+    copy path (plain stores, not wave-logged), deltas for the atomic
+    targets, and ``logs = {array: [(idx, val), ...]}`` — the store logs
+    of the :func:`wave_logged` arrays, in program order, left for the
+    wave to apply.  Read-only globals are no output at all.
     """
     if warp_exec not in ("serial", "batched"):
         raise ValueError(f"unknown warp_exec {warp_exec!r}; "
@@ -891,9 +925,17 @@ def make_block_fn(ck: CompiledKernel, *, n_warps: int, mode: str = "jit",
             "atomic old-value capture under warp-batched execution: "
             "captured old values are only unique under serial warp "
             "order — use warp_exec='serial'")
+    wave = track_writes and persist is None
     from .backends import merge  # deferred: backends imports execute
     pr_plans = ({n.id: _pr_plan(ck, n) for n in ck.machine.nodes
                  if isinstance(n, BlockPR)} if batch_warps else {})
+    wave_log = (frozenset(wave_logged(ck, n_warps=n_warps,
+                                      warp_exec=warp_exec))
+                if wave else frozenset())
+    copied = tuple(sorted(
+        {s.array for s in _all_instrs(ck) if isinstance(s, K.StoreGlobal)}
+        - wave_log))
+    atomic_targets = tuple(sorted({s.array for s in all_atomics}))
 
     def block_fn(uniforms: Dict[str, Any], globals_: Dict[str, Any],
                  store_masks=None, atomic_deltas=None, state=None):
@@ -917,6 +959,9 @@ def make_block_fn(ck: CompiledKernel, *, n_warps: int, mode: str = "jit",
                 if has_atomics else {})
         else:
             store_masks, atomic_deltas = {}, {}
+        # wave-logged arrays' store logs, filled in program order along
+        # the (linear) block machine and returned unreplayed
+        wlog: Dict[str, List[Tuple[Any, Any]]] = {k: [] for k in wave_log}
 
         def run_warp_plane(node: BlockPR, bv, sh, g, sm, ad):
             """All warps of one block-level PR as a single (n_warps, W)
@@ -1007,8 +1052,13 @@ def make_block_fn(ck: CompiledKernel, *, n_warps: int, mode: str = "jit",
             g2 = {**g, **g_new}
             # store-log replay: one flat scatter per logged store — the
             # single-writer contract makes cross-warp lanes disjoint
-            # (masked-off lanes carry the one-past-end index and drop)
+            # (masked-off lanes carry the one-past-end index and drop).
+            # Wave-logged arrays skip the block's copy: their entries
+            # go out with the block for the vmap wave to apply.
             for name, (idx, val) in zip(log_names, logs):
+                if name in wave_log:
+                    wlog[name].append((idx, val))
+                    continue
                 g2[name] = g2[name].at[idx.reshape(-1)].set(
                     val.reshape(-1), mode="drop")
                 if track_writes:
@@ -1053,6 +1103,10 @@ def make_block_fn(ck: CompiledKernel, *, n_warps: int, mode: str = "jit",
                 if node.succ_ids else jnp.int32(EXIT)
 
         def outputs(g, sm, ad, bv, sh):
+            if wave:
+                return ({k: g[k] for k in copied},
+                         {k: sm[k] for k in copied},
+                         {k: ad[k] for k in atomic_targets}, wlog)
             if persist is None:
                 return g, sm, ad
             return g, sm, ad, {"bv": {v: bv[v] for v in persist[0]},

@@ -538,3 +538,143 @@ def test_scalar_args_do_not_retrace():
     np.testing.assert_allclose(np.asarray(got["out"])[:100], want)
     np.testing.assert_array_equal(np.asarray(got["out"])[100:],
                                   np.zeros(412, np.float32))
+
+
+# ---------------------------------------------------------------------------
+# wave-logged stores: a vmap wave carries what its blocks write — store
+# logs, atomic deltas and copies of reloaded arrays — not a per-block
+# copy of every global
+# ---------------------------------------------------------------------------
+
+
+@cox.kernel
+def _k_store_twice(c, out: cox.Array(cox.f32), a: cox.Array(cox.f32)):
+    # one PR stores each element twice, a later PR stores some of them
+    # again from another thread: program order decides, the later wins
+    tid = c.thread_idx()
+    i = c.block_idx() * c.block_dim() + tid
+    v = a[i]
+    s = c.red_add(v)
+    out[i] = v
+    out[i] = v + s
+    c.syncthreads()
+    if tid % 3 == 0:
+        out[c.block_idx() * c.block_dim() + (c.block_dim() - 1 - tid)] = (
+            s * 2.0)
+
+
+@cox.kernel
+def _k_store_then_reload(c, out: cox.Array(cox.f32),
+                         tmp: cox.Array(cox.f32), a: cox.Array(cox.f32)):
+    # tmp is stored in one PR and loaded in the next, so it keeps the
+    # block's copy; out is stored once and never loaded, so it is logged
+    tid = c.thread_idx()
+    i = c.block_idx() * c.block_dim() + tid
+    v = a[i]
+    s = c.red_add(v)
+    tmp[i] = v + s
+    c.syncthreads()
+    out[i] = tmp[c.block_idx() * c.block_dim() + (c.block_dim() - 1 - tid)]
+
+
+def _wave_case(name):
+    """(kernel, grid, block, args, launch kwargs) of one wave-log case;
+    grids of 5, 16 and 32 blocks leave pad slots in waves of 3.  The
+    arrays of the two store kernels hold one block more than the grid
+    covers: a pad slot (bid -1) that kept its log entries would land
+    them there, where no block writes."""
+    rng = np.random.default_rng(18)
+    if name in ("store_twice", "store_then_reload"):
+        a = rng.integers(-8, 9, 6 * 64).astype(np.float32)
+        z = np.zeros(6 * 64, np.float32)
+        if name == "store_twice":
+            return _k_store_twice, 5, 64, (z, a), {}
+        return _k_store_then_reload, 5, 64, (z, z, a), {}
+    sk = next(k for k in all_kernels() if k.name == name)
+    # hierarchical collapsing gives the warp-feature-free kernels several
+    # warps a block, so the batched plane (and its store log) runs
+    return (sk.kernel, sk.grid, sk.block, sk.make_args(),
+            {} if name == "warpPrefixStats" else {"collapse": "hier"})
+
+
+WAVE_CASES = ["warpPrefixStats", "histogram64", "vectorAdd", "store_twice",
+              "store_then_reload"]
+WAVE_SCHEDULES = {
+    "vmap-chunked": {"backend": "vmap", "chunk": 3},
+    "vmap-stride": {"backend": "vmap", "schedule": "grid_stride",
+                    "n_resident": 3},
+    "sharded-chunked": {"mesh": True, "chunk": 3},
+    "sharded-stride": {"mesh": True, "schedule": "grid_stride",
+                       "n_resident": 3},
+}
+
+
+@pytest.mark.parametrize("warp_exec", ["serial", "batched"])
+@pytest.mark.parametrize("schedule", list(WAVE_SCHEDULES))
+@pytest.mark.parametrize("name", WAVE_CASES)
+def test_wave_merge_bitwise_matches_scan_and_serial_warps(name, schedule,
+                                                          warp_exec):
+    """Every vmap schedule, batched or serial warps, on one device or
+    the host mesh, equals scan with serial warps bitwise: logged stores
+    (repeated stores of one element included), reloaded stores, atomic
+    deltas, read-only globals and pad slots."""
+    from repro.launch.mesh import make_mesh
+    kern, grid, block, args, kw = _wave_case(name)
+    want = kern.launch(grid=grid, block=block, args=args, backend="scan",
+                       warp_exec="serial", **kw)
+    knobs = dict(WAVE_SCHEDULES[schedule])
+    if knobs.pop("mesh", False):
+        knobs["mesh"] = make_mesh((1,), ("data",))
+    got = kern.launch(grid=grid, block=block, args=args,
+                      warp_exec=warp_exec, **knobs, **kw)
+    for k in want:
+        np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want[k]),
+                                      err_msg=f"{name}.{k} [{schedule}]")
+
+
+@pytest.mark.parametrize("name,warp_exec,logged", [
+    ("warpPrefixStats", "batched", ("out",)),
+    ("warpPrefixStats", "serial", ()),
+    ("histogram64", "batched", ()),          # atomic target: deltas only
+    ("vectorAdd", "batched", ("out",)),
+    ("store_twice", "batched", ("out",)),
+    ("store_then_reload", "batched", ("out",)),   # tmp is reloaded
+])
+def test_wave_logged_set(name, warp_exec, logged):
+    from repro.core.runtime import build_traceable
+    kern, grid, block, args, kw = _wave_case(name)
+    req = kern.make_request(grid=grid, block=block, args=args,
+                            backend="vmap", warp_exec=warp_exec, **kw)
+    plan, _ = build_traceable(req.ck, req.rl)
+    assert plan.wave_logged == logged
+
+
+@pytest.mark.parametrize("name,logged,copies", [
+    ("warpPrefixStats", ("out",), ("f32", "pred", "u32")),
+    ("histogram64", (), ("s32",)),
+])
+def test_wave_carries_no_per_block_copy(name, logged, copies):
+    """At n = 65,536 (256 blocks, waves of 8) the compiled vmap launch
+    holds no [8, 65536] op: warpPrefixStats' out leaves each block as
+    store logs, histogram64's read-only data passes through the wave
+    and its hist merges by deltas alone."""
+    import re
+
+    import jax
+    from repro.core.runtime import build_traceable
+    sk = next(k for k in all_kernels() if k.name == name)
+    n = 65536
+    rng = np.random.default_rng(0)
+    if name == "warpPrefixStats":
+        args = (np.zeros(n, np.float32),
+                rng.integers(-6, 7, n).astype(np.float32))
+    else:
+        args = (np.zeros(64, np.float32),
+                rng.integers(0, 64, n).astype(np.int32), n)
+    req = sk.kernel.make_request(grid=n // 256, block=256, args=args)
+    assert req.rl.backend == "vmap" and req.rl.chunk == 8
+    plan, fn = build_traceable(req.ck, req.rl)
+    assert plan.wave_logged == logged
+    hlo = jax.jit(fn).lower(req.globals_, req.scalars).compile().as_text()
+    full = {t for t in copies if re.search(rf"\b{t}\[8,{n}\]", hlo)}
+    assert not full, f"{name}: per-block copies {sorted(full)} in the wave"
