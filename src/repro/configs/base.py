@@ -2,9 +2,20 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN scaling of RoPE (``layers.yarn_frequencies``), as a published
+    config's ``rope_scaling`` gives it."""
+    factor: float
+    original_max: int           # original_max_position_embeddings
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 0.0   # cos/sin scale; 0 = 0.1·ln(factor) + 1
 
 
 @dataclasses.dataclass
@@ -24,7 +35,10 @@ class ModelConfig:
     tie_embeddings: bool = True
     norm_eps: float = 1e-6
     rope: bool = True           # False: no position embedding (NoPE)
-    # per-layer mixer of a dense/moe stack, "attention" | "mamba", read for
+    rope_theta: float = 10000.0
+    yarn: Optional[Yarn] = None  # YaRN on the full-attention layers' RoPE
+    # per-layer mixer of a dense/moe stack, "attention" (full) |
+    # "sliding_attention" (keys i - window < j <= i) | "mamba", read for
     # the first n_layers; () = attention in every layer
     layer_types: Tuple[str, ...] = ()
     # --- MoE ---
@@ -53,7 +67,8 @@ class ModelConfig:
     # --- vlm/audio stubs ---
     n_frontend_tokens: int = 0  # precomputed patch/frame embeddings
     # --- execution ---
-    window: int = 0             # sliding-window attention (0 = full)
+    window: int = 0             # the sliding_attention layers' window, and
+                                # the hybrid family's shared attention's
     remat: str = "full"         # none | full
     param_dtype: object = jnp.bfloat16
     tp_pad: int = 0             # runtime: pad q-heads to this TP degree
@@ -222,7 +237,7 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         attn_every=2 if cfg.attn_every else 0,
         enc_layers=2 if cfg.enc_layers else 0,
         n_frontend_tokens=8 if cfg.n_frontend_tokens else 0,
-        window=min(cfg.window, 64) if cfg.window else 0,
+        window=min(cfg.window, 16) if cfg.window else 0,
         remat="none",
         param_dtype=jnp.float32,
     )

@@ -263,7 +263,7 @@ def _cache_kernel(layer_ref, pos_ref, len_ref, q_ref, kn_ref, vn_ref, k_ref,
 
 def decode_attn_cache(layer, pos, kv_len, q, k_new, v_new, k_stack, v_stack,
                       *, minor: bool = False, scale=None, bk: int = 512,
-                      interpret: bool = False):
+                      interpret: bool = False, name: str = "decode_attn_cache"):
     """One decode step's attention of every sequence on the whole K/V
     stacks of a run of layers, with the token's K and V written in place.
 
@@ -271,7 +271,10 @@ def decode_attn_cache(layer, pos, kv_len, q, k_new, v_new, k_stack, v_stack,
     writes and how many rows it attends (pos < kv_len); q: (B, H, D);
     k_new, v_new: (B, Hkv, D); stacks: (n, B, S, Hkv, D) -> (out (B, H,
     D), k_stack, v_stack), the stacks aliased to their inputs and changed
-    only at ``(layer, b, pos[b])``.
+    only at ``(layer, b, pos[b])``.  A sliding layer's ring of W rows is
+    such a stack too: its caller passes the ring slot as ``pos`` and
+    min(position + 1, W) as ``kv_len``, and ``name`` tells its calls
+    apart in a trace (``decode_attn_ring``).
 
     ``minor`` says how the device stores the stacks: with the positions
     minor, (n, B, Hkv, D, S) in memory (the TPU's layout for D = 64, which
@@ -341,7 +344,7 @@ def decode_attn_cache(layer, pos, kv_len, q, k_new, v_new, k_stack, v_stack,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name="decode_attn_cache",
+        name=name,
     )(layer.astype(jnp.int32), pos, kv_len, q.reshape(Bt, Hkv, g, D), *new,
       *stacks)
     if minor:

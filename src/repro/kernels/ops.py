@@ -81,9 +81,11 @@ def decode_attention(q, k_cache, v_cache, kv_len, backend: str = "auto"):
 
 
 def decode_attn_cache(layer, pos, kv_len, q, k_new, v_new, k_stack, v_stack,
-                      minor: bool = False, backend: str = "auto"):
+                      minor: bool = False, backend: str = "auto",
+                      name: str = "decode_attn_cache"):
     return _dispatch(backend,
-                     functools.partial(_fa.decode_attn_cache, minor=minor),
+                     functools.partial(_fa.decode_attn_cache, minor=minor,
+                                       name=name),
                      _ref.decode_attn_cache,
                      layer, pos, kv_len, q, k_new, v_new, k_stack, v_stack)
 

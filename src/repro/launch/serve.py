@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import functools
 import time
 from typing import Any, Dict, List, Optional
 
@@ -30,6 +31,7 @@ from jax.profiler import TraceAnnotation
 from ..configs import registry
 from ..configs.base import ShapeConfig
 from ..core import cox
+from ..models import lm
 from ..models.params import init_params
 from ..parallel import steps as steps_mod
 from .mesh import make_host_mesh
@@ -219,6 +221,24 @@ def prefill_program(raw_step):
     return prefill
 
 
+def prefix_snapshot(cache, slot, kept):
+    """Slot ``slot``'s rows (batch axis 1) of the cache leaves ``kept``
+    marks, None for the others."""
+    return [jax.lax.dynamic_index_in_dim(a, slot, 1, keepdims=False)
+            if k else None
+            for a, k in zip(jax.tree_util.tree_leaves(cache), kept)]
+
+
+def prefix_restore(cache, rows, slot):
+    """The cache with slot ``slot``'s rows of each leaf that ``rows``
+    holds (``prefix_snapshot``'s) put back; the other leaves as they
+    are.  Jitted with the cache donated, so it writes in place."""
+    leaves, tree = jax.tree_util.tree_flatten(cache)
+    return jax.tree_util.tree_unflatten(tree, [
+        a if r is None else jax.lax.dynamic_update_index_in_dim(a, r, slot, 1)
+        for a, r in zip(leaves, rows)])
+
+
 class BatchedServer:
     def __init__(self, arch: str, *, batch: int = 4, ctx: int = 128,
                  mesh=None, seed: int = 0, params=None):
@@ -235,6 +255,7 @@ class BatchedServer:
         self.ctx = ctx
         # one batched-prefill executable per prompt length (shapes differ)
         self._prefill_cache: Dict[int, Any] = {}
+        self._snapshot = self._restore = None
         self.reset()
 
     def reset(self):
@@ -247,6 +268,8 @@ class BatchedServer:
         self.tokens = np.zeros((self.batch,), np.int32)
         self.active = np.zeros((self.batch,), bool)
         self.outputs: List[List[int]] = [[] for _ in range(self.batch)]
+        # slots resumed from a snapshot: their next prompt appends
+        self.resumed = np.zeros((self.batch,), bool)
         # the decode step dispatched ahead of its turn, and the decode
         # call's token limit while it may dispatch ahead (see _step_all)
         self._ahead = None
@@ -260,11 +283,44 @@ class BatchedServer:
         return jax.jit(prefill_program(self.bundle["raw_step"]),
                        donate_argnums=(1,))
 
+    def snapshot(self, slot: int) -> Dict[str, Any]:
+        """What ``resume`` needs to put slot ``slot`` back at its position:
+        the position, the slot's last token, and a copy of the slot's rows
+        of the cache leaves later tokens overwrite (``lm.rewritten``: a
+        sliding run's ring, a Mamba run's state and conv tail).  A full
+        attention run's K/V is restored by the position alone."""
+        if self._snapshot is None:
+            kept = lm.rewritten(self.cfg, self.cache)
+            self._snapshot = jax.jit(
+                functools.partial(prefix_snapshot, kept=kept))
+            self._restore = jax.jit(prefix_restore, donate_argnums=(0,))
+        with TraceAnnotation("serve.prefix.snapshot"):
+            rows = self._snapshot(self.cache, jnp.int32(slot))
+        return {"pos": int(self.pos[slot]), "token": int(self.tokens[slot]),
+                "rows": rows}
+
+    def resume(self, slot: int, snap: Dict[str, Any]):
+        """Put slot ``slot`` back where ``snap`` (``snapshot``'s) found a
+        slot: the next ``prefill_prompt`` appends at its position, as if
+        the tokens before it had just been prefilled.  The copy is one
+        jitted call (``prefix_restore``) on the donated cache."""
+        with TraceAnnotation("serve.prefix.restore"):
+            self.cache = self._restore(self.cache, snap["rows"],
+                                       jnp.int32(slot))
+        self.pos[slot] = snap["pos"]
+        self.tokens[slot] = snap["token"]
+        self.resumed[slot] = True
+        return self
+
     def prefill_prompt(self, slot: int, prompt: List[int]):
         """Feed a prompt through the decode path in ONE step per slot: a
         single scanned+jitted call consumes the whole prompt (same
-        per-token math as the decode loop, batched on device)."""
-        self.pos[slot] = 0
+        per-token math as the decode loop, batched on device).  The
+        prompt starts at position 0, or, after ``resume``, at the
+        snapshot's position."""
+        if not self.resumed[slot]:
+            self.pos[slot] = 0
+        self.resumed[slot] = False
         self.outputs[slot] = []
         self.active[slot] = True
         T = len(prompt)

@@ -7,10 +7,12 @@ CPU dry-run.  Activation shardings are expressed with
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental.layout import Layout
 
@@ -39,18 +41,60 @@ def round_up(a: int, b: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-def rope(x, positions, *, base: float = 10000.0):
-    """x: (..., S, H, D) or (..., H, D) with positions broadcastable."""
+def yarn_frequencies(dim: int, base: float, yarn):
+    """(inverse frequencies (dim/2,) f32, cos/sin scale) of YaRN, as
+    transformers' ``_compute_yarn_parameters`` computes them: frequencies
+    below the ``beta_slow`` rotations over ``original_max`` positions are
+    interpolated (÷ factor), those above ``beta_fast`` kept, with a linear
+    ramp between (bounds floored and ceiled), and the scale
+    ``attention_factor`` (0 → 0.1·ln(factor) + 1)."""
+    half = dim // 2
+    kept = base ** -(np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def bound(rotations):
+        return dim * math.log(yarn.original_max / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(bound(yarn.beta_fast)), 0)
+    high = min(math.ceil(bound(yarn.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(half) - low) / (high - low or 0.001), 0, 1)
+    inv = kept / yarn.factor * ramp + kept * (1 - ramp)
+    scale = yarn.attention_factor or 0.1 * math.log(yarn.factor) + 1
+    return inv.astype(np.float32), float(scale)
+
+
+def rope(x, positions, *, base: float = 10000.0, yarn=None):
+    """x: (..., S, H, D) or (..., H, D) with positions broadcastable;
+    rotate-half RoPE of base ``base``, YaRN-scaled where ``yarn`` is
+    given."""
     D = x.shape[-1]
     half = D // 2
-    freqs = jnp.exp(-jnp.log(base) * jnp.arange(half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs = jnp.exp(-jnp.log(base) * jnp.arange(half, dtype=jnp.float32)
+                        / half)
+    else:
+        inv, scale = yarn_frequencies(D, base, yarn)
+        freqs = jnp.asarray(inv)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., S, half)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        cos, sin = cos * scale, sin * scale
     cos = cos[..., None, :]  # broadcast over heads
     sin = sin[..., None, :]
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
+
+
+def rotate(cfg, kind: str, x, positions):
+    """The position embedding of an attention layer of ``kind``: none
+    (NoPE), or RoPE of base ``cfg.rope_theta``, YaRN-scaled on full
+    attention where ``cfg.yarn`` is set (sliding layers keep plain
+    RoPE)."""
+    if not cfg.rope:
+        return x
+    yarn = cfg.yarn if kind == "attention" else None
+    return rope(x, positions, base=cfg.rope_theta, yarn=yarn)
 
 
 # ---------------------------------------------------------------------------
@@ -112,8 +156,10 @@ def _head_mask(cfg):
 
 
 def attention_apply(p, x, positions, *, cfg, rules=None, causal=True,
-                    window: int = 0, backend="auto"):
-    """x: (B, S, d) -> (B, S, d)."""
+                    window: int = 0, backend="auto", kind="attention"):
+    """x: (B, S, d) -> (B, S, d); ``kind`` (``"attention"`` or
+    ``"sliding_attention"``) picks the position embedding
+    (``rotate``)."""
     B, S, d = x.shape
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
@@ -122,9 +168,8 @@ def attention_apply(p, x, positions, *, cfg, rules=None, causal=True,
         q = q + p["bq"]
         k = k + p["bk"]
         v = v + p["bv"]
-    if cfg.rope:
-        q = rope(q, positions)
-        k = rope(k, positions)
+    q = rotate(cfg, kind, q, positions)
+    k = rotate(cfg, kind, k, positions)
     q = constrain(q, rules, ("batch", None, "heads", None))
     k = constrain(k, rules, ("batch", None, "kv_heads", None))
     att = jax.vmap(lambda qq, kk, vv: ops.attention(
@@ -167,14 +212,15 @@ def cache_positions_minor(rules, shape, dtype) -> bool:
 
 
 def attention_decode(p, x, cache, pos, *, cfg, rules=None, backend="auto",
-                     slot=None, kv_len=None, layer=None):
+                     slot=None, kv_len=None, layer=None, kind="attention"):
     """One-token decode.  x: (B, d); cache: {k: (B, S, Hkv, Dh), v: ...};
     pos: (B,) absolute positions (for RoPE); slot: (B,) cache write slots
     (rolling-buffer windows; defaults to pos); kv_len: (B,) valid cache
     length (defaults to pos+1).  With ``layer`` (where
     ``decode_attn_in_place`` holds), the cache holds the (L, B, S, Hkv,
     Dh) stacks of a run of layers, and layer ``layer``'s rows are written
-    and read in place."""
+    and read in place: ``decode_attn_cache``, or ``decode_attn_ring`` on
+    a sliding layer's ring.  ``kind`` as ``attention_apply``'s."""
     B, d = x.shape
     slot = pos if slot is None else slot
     kv_len = pos + 1 if kv_len is None else kv_len
@@ -185,8 +231,8 @@ def attention_decode(p, x, cache, pos, *, cfg, rules=None, backend="auto",
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
     if cfg.rope:
         # rope wants (..., S, H, D): add a singleton S axis
-        q = rope(q[:, None], pos[:, None])[:, 0]
-        k = rope(k[:, None], pos[:, None])[:, 0]
+        q = rotate(cfg, kind, q[:, None], pos[:, None])[:, 0]
+        k = rotate(cfg, kind, k[:, None], pos[:, None])[:, 0]
     if layer is None:
         kc = _scatter_token(cache["k"], k, slot)
         vc = _scatter_token(cache["v"], v, slot)
@@ -197,7 +243,8 @@ def attention_decode(p, x, cache, pos, *, cfg, rules=None, backend="auto",
         out, kc, vc = ops.decode_attn_cache(
             jnp.asarray(layer, jnp.int32).reshape(1), slot, kv_len, q, k, v,
             kc, vc, minor=cache_positions_minor(rules, kc.shape, kc.dtype),
-            backend=backend)
+            backend=backend, name="decode_attn_ring"
+            if kind == "sliding_attention" else "decode_attn_cache")
     hm = _head_mask(cfg)
     if hm is not None:
         out = out * hm[None, :, None].astype(out.dtype)

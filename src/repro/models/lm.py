@@ -4,11 +4,13 @@ Layers are scanned (stacked params) to keep HLO size and compile time
 independent of depth — essential for the 512-device dry-runs.
 
 A dense/MoE stack may change its mixer by layer (``cfg.layer_types``:
-attention or Mamba2, each followed by the layer's MLP or MoE).  Each run
-of same-mixer layers (``cfg.layer_runs``) is one scanned stack, and its
-cache holds K/V for an attention run, the f32 SSM state and the conv
-tail for a Mamba run.  A stack of one run keeps ``params["layers"]`` and
-the cache as that run's; several runs make both lists, one entry a run.
+full attention, sliding-window attention or Mamba2, each followed by the
+layer's MLP or MoE).  Each run of same-mixer layers (``cfg.layer_runs``)
+is one scanned stack, and its cache holds K/V for a full-attention run
+(every position), a ring of ``cfg.window`` K/V rows for a sliding run
+(position p in row p mod W), the f32 SSM state and the conv tail for a
+Mamba run.  A stack of one run keeps ``params["layers"]`` and the cache
+as that run's; several runs make both lists, one entry a run.
 """
 from __future__ import annotations
 
@@ -114,7 +116,8 @@ def _dense_layer_apply(cfg, rules, window, backend, mixer, lp, x, positions):
                            backend=backend)
     else:
         h = L.attention_apply(lp["attn"], h, positions, cfg=cfg, rules=rules,
-                              causal=True, window=window, backend=backend)
+                              causal=True, window=window, backend=backend,
+                              kind=mixer)
     x = x + h
     h = _norm(cfg, lp, "ln2", x, backend)
     if cfg.family == "moe":
@@ -164,8 +167,10 @@ def hidden_states(cfg, params, x, positions, *, rules=None, backend="auto"):
     remat = cfg.remat == "full"
     if cfg.family in ("dense", "moe", "vlm"):
         for kind, stack in _runs(cfg, params["layers"]):
+            # only a sliding layer's keys are bounded by the window
+            window = cfg.window if kind == "sliding_attention" else 0
             fn = functools.partial(_dense_layer_apply, cfg, rules,
-                                   cfg.window, backend, kind)
+                                   window, backend, kind)
             x = _scan_layers(lambda lp, h, fn=fn: fn(lp, h, positions),
                              stack, x, remat, rules=rules)
     elif cfg.family == "ssm":
@@ -232,8 +237,8 @@ def cache_specs(cfg, batch: int, seq_len: int) -> Dict[str, Any]:
 
     Dense/MoE/VLM: per-layer KV (L, B, S, Hkv, Dh) — S sharded over
     'model' (seq_kv) so 32k×128 caches fit HBM; with a layer pattern, one
-    entry per run: KV for an attention run, SSM state and conv tail for
-    a Mamba run.
+    entry per run: KV for an attention run, a KV ring of min(S, window)
+    rows for a sliding run, SSM state and conv tail for a Mamba run.
     SSM: recurrent state (L, B, H, P, N) + conv tail.
     Hybrid: SSM states + one KV cache per shared-attention application
     (window-bounded for long contexts)."""
@@ -246,7 +251,8 @@ def cache_specs(cfg, batch: int, seq_len: int) -> Dict[str, Any]:
             if kind == "mamba":
                 runs.append(_ssm_cache_specs(cfg, n, B))
                 continue
-            kv = ParamSpec((n, B, S, Hkv, Dh), dt,
+            rows = min(S, cfg.window) if kind == "sliding_attention" else S
+            kv = ParamSpec((n, B, rows, Hkv, Dh), dt,
                            (None, "batch", "seq_kv", "kv_heads", None),
                            init="zeros")
             runs.append({"k": kv, "v": kv})
@@ -262,6 +268,18 @@ def cache_specs(cfg, batch: int, seq_len: int) -> Dict[str, Any]:
                    (None, "batch", "seq_kv", "kv_heads", None), init="zeros")
     ssm.update({"k": kv, "v": kv})
     return ssm
+
+
+def rewritten(cfg, cache):
+    """Per leaf of ``cache`` (``tree_leaves`` order), whether a token at a
+    later position overwrites what the leaf holds of earlier ones: a
+    sliding run's ring, a Mamba run's state and conv tail.  A full
+    attention run's rows below a position are never written again, so
+    the position alone restores them.  Other families: every leaf."""
+    if cfg.family not in ("dense", "moe", "vlm"):
+        return [True] * len(jax.tree_util.tree_leaves(cache))
+    return [kind != "attention" for kind, run in _runs(cfg, cache)
+            for _ in jax.tree_util.tree_leaves(run)]
 
 
 def decode_step(cfg, params, cache, tokens, pos, *, rules=None,
@@ -359,7 +377,8 @@ def _decode_run(cfg, mixer, layers, cache, h, pos, fresh, *, rules, backend):
     layer's state updated in place (``ssd_decode``); so does an attention
     run its K/V stacks where ``decode_attn_in_place`` holds
     (``decode_attn_cache``).  The rest of the cache goes in and out as
-    the scan's per-layer slices."""
+    the scan's per-layer slices.  A sliding run writes ring row pos mod W
+    and attends the min(pos + 1, W) rows written."""
     stacks = None
     if cfg.family == "moe" and L.moe_reads_chosen(cfg, rules, h.shape[0],
                                                   backend):
@@ -374,6 +393,10 @@ def _decode_run(cfg, mixer, layers, cache, h, pos, fresh, *, rules, backend):
         carried, names = ("k", "v"), ()
     else:
         carried, names = (), ("k", "v")
+    ring = {}
+    if mixer == "sliding_attention":
+        W = cache["k"].shape[2]
+        ring = {"slot": pos % W, "kv_len": jnp.minimum(pos + 1, W)}
 
     def body(carry, inp):
         x, i, *whole = carry
@@ -386,7 +409,8 @@ def _decode_run(cfg, mixer, layers, cache, h, pos, fresh, *, rules, backend):
         else:
             y, new = L.attention_decode(lp["attn"], hn, state, pos, cfg=cfg,
                                         rules=rules, backend=backend,
-                                        layer=i if carried else None)
+                                        layer=i if carried else None,
+                                        kind=mixer, **ring)
         x = x + y
         hn = _norm(cfg, lp, "ln2", x, backend)
         if cfg.family == "moe":

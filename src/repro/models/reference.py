@@ -20,6 +20,14 @@ Attention is GQA with no position embedding (NoPE).  The MoE routes
 top-k over all the router's experts with a softmax over the k chosen
 logits; a SwiGLU expert's output is weighted by its gate.
 
+Mellum2 (``mellum`` model type) has the same layer with the multipliers
+at 1 and no Mamba2: its mixers are full and sliding-window GQA
+attention, with rotate-half RoPE of base ``cfg.rope_theta`` on the
+queries and keys, YaRN-scaled on the full layers (the frequencies and
+the cos/sin scale of transformers' ``_compute_yarn_parameters``), scores
+``q·k/sqrt(head_dim)``, causal, and on a sliding layer only the keys
+``i - window < j <= i``; the head is untied.
+
 Departures, each shared with the program:
 
 * weights come in the program's parameter tree (``models/lm.py``); the
@@ -32,10 +40,12 @@ Departures, each shared with the program:
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def _rmsnorm(x, w, eps):
@@ -77,14 +87,65 @@ def _mamba(cfg, p, x):
     return _rmsnorm(y, p["norm"], cfg.norm_eps) @ p["w_out"]
 
 
-def _attention(cfg, p, x, scale):
+def yarn_inv_freq(dim, base, factor, original_max, beta_fast, beta_slow,
+                  attention_factor):
+    """transformers' ``_compute_yarn_parameters``, line by line: (inverse
+    frequencies (dim/2,), cos/sin scale)."""
+    def find_correction_dim(num_rotations):
+        return (dim * math.log(original_max / (num_rotations * 2 * math.pi))) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(find_correction_dim(beta_fast)), 0)
+    high = min(math.ceil(find_correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0, 1)
+    pos_freqs = base ** (np.arange(0, dim, 2, dtype=np.float32) / dim)
+    extrapolation = 1.0 / pos_freqs
+    interpolation = 1.0 / (factor * pos_freqs)
+    extrapolation_factor = 1 - ramp
+    inv_freq = interpolation * (1 - extrapolation_factor) + \
+        extrapolation * extrapolation_factor
+    if not attention_factor:
+        attention_factor = 0.1 * math.log(factor) + 1.0
+    return inv_freq.astype(np.float32), attention_factor
+
+
+def _rope(cfg, kind, x, positions):
+    """Rotate-half RoPE of x (B, S, H, D) at ``positions`` (S,)."""
+    D = x.shape[-1]
+    y = cfg.yarn if kind == "attention" else None
+    if y is None:
+        inv = 1.0 / cfg.rope_theta ** (np.arange(0, D, 2, dtype=np.float32) / D)
+        scale = 1.0
+    else:
+        inv, scale = yarn_inv_freq(D, cfg.rope_theta, y.factor, y.original_max,
+                                   y.beta_fast, y.beta_slow,
+                                   y.attention_factor)
+    ang = positions[:, None].astype(jnp.float32) * jnp.asarray(inv)
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[:, None] * scale
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[:, None] * scale
+    half = jnp.concatenate([-x[..., D // 2:], x[..., :D // 2]], -1)
+    return x * cos + half * sin
+
+
+def _attention(cfg, p, x, scale, kind="attention"):
     S = x.shape[1]
     g = cfg.n_heads // cfg.n_kv
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
-    k = jnp.repeat(jnp.einsum("bsd,dhk->bshk", x, p["wk"]), g, axis=2)
+    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
+    if cfg.rope:
+        q = _rope(cfg, kind, q, jnp.arange(S))
+        k = _rope(cfg, kind, k, jnp.arange(S))
+    k = jnp.repeat(k, g, axis=2)
     v = jnp.repeat(jnp.einsum("bsd,dhk->bshk", x, p["wv"]), g, axis=2)
     s = jnp.einsum("bqhk,bshk->bhqs", q, k) * scale
-    s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None]
+    seen = j <= i
+    if kind == "sliding_attention":
+        seen = seen & (j > i - cfg.window)
+    s = jnp.where(seen, s, -jnp.inf)
     o = jnp.einsum("bhqs,bshk->bqhk", jax.nn.softmax(s, -1), v)
     return jnp.einsum("bqhk,hkd->bqd", o, p["wo"])
 
@@ -124,9 +185,10 @@ def forward(cfg, params, tokens, mult: Optional[Dict[str, float]] = None):
                 if kind == "mamba":
                     h = _mamba(cfg, lp["mamba"], h)
                 else:
-                    h = _attention(cfg, lp["attn"], h, m["attention"])
+                    h = _attention(cfg, lp["attn"], h, m["attention"], kind)
                 x = x + m["residual"] * h
                 h = _rmsnorm(x, lp["ln2"], eps)
                 x = x + m["residual"] * _moe(cfg, lp["moe"], h)
         x = _rmsnorm(x, f32["final_norm"], eps)
-        return x @ table.T / m["logits"]
+        head = f32["embed"].get("unembed", table.T)
+        return x @ head / m["logits"]

@@ -308,3 +308,29 @@ def test_cox_sharded_program_compiles_on_four_chips(topo, name):
 
     text = _compile_text(fn, shapes(req.globals_), shapes(req.scalars))
     assert "all-reduce" in text
+
+
+def test_mellum2_decode_works_on_rings_and_full_stacks_in_place(topo):
+    """Mellum2-12B-A2.5B at its served context, 16,384: the serve step and
+    a prefill program call ``decode_attn_ring`` on each sliding run's
+    whole (3, 1, 1024, 4, 128) ring stacks (7 runs, 21 layers) and
+    ``decode_attn_cache`` on each full run's whole (1, 1, 16384, 4, 128)
+    stacks (7 layers), and no copy, slice, update, transpose or fusion
+    makes a ring or full stack, one layer of either, or one sequence's
+    heads-major cache."""
+    ring, full = "3,1,1024,4,128", "1,1,16384,4,128"
+    cuts = ["1,1024,4,128", "4,1024,128", "1,16384,4,128", "4,16384,128"]
+    made = re.compile(r"= bf16\[({})\]\S* ({})\(".format(
+        "|".join([ring, full] + cuts),
+        "copy|copy-start|copy-done|dynamic-slice|dynamic-update-slice|"
+        "transpose|fusion"))
+    for text in _serve_programs(topo, "mellum2-12b-a2.5b", 16384, 16):
+        lines = text.splitlines()
+        for name, stack, count in (("decode_attn_ring", ring, 7),
+                                   ("decode_attn_cache", full, 7)):
+            calls = [line for line in lines
+                     if re.match(rf"\s*%{name}(\.\d+)? = ", line)]
+            assert len(calls) == count
+            for line in calls:
+                assert line.count(f"bf16[{stack}]") >= 4
+        assert not [line for line in lines if made.search(line)]
